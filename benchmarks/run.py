@@ -11,11 +11,10 @@ produce its lines), timed AFTER all modules are imported.
 
 Set BENCH_QUICK=1 to trim the slowest sweeps (used by scripts/verify.sh).
 
-Per-module failures are swallowed (the sweep must finish and report every
-module it can) but never lost: each run writes ``BENCH_run.json`` -- the
-manifest of which modules succeeded and which failed, with the error
-string -- and ``scripts/verify.sh`` gates on that manifest BY NAME
-instead of inferring health from output-file timestamps.
+A module's failure does not stop the sweep (it must finish and report
+every module it can) but is never lost: each run writes
+``BENCH_run.json`` -- the manifest of which modules succeeded and which
+failed, with the error string -- and exits 1 when any module failed.
 """
 from __future__ import annotations
 
@@ -30,7 +29,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 MANIFEST_PATH = "BENCH_run.json"
 
 
-def main() -> None:
+def main() -> int:
+    from repro.core.envutil import init_compile_cache
+    init_compile_cache()
     # Import everything up front: module import cost must never leak into
     # any timed region.
     from benchmarks import (fig10, fig16, halo, scaling, table2, table3,
@@ -68,7 +69,8 @@ def main() -> None:
             "failed": [m["module"] for m in modules if not m["ok"]],
             "succeeded": [m["module"] for m in modules if m["ok"]],
         }, f, indent=1)
+    return 1 if any(not m["ok"] for m in modules) else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

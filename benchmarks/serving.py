@@ -259,6 +259,8 @@ def main(argv=None) -> None:
                     default=bool(os.environ.get("BENCH_QUICK")),
                     help="trimmed sweep (also via BENCH_QUICK=1)")
     args = ap.parse_args(argv)
+    from repro.core.envutil import init_compile_cache
+    init_compile_cache()
     try:
         with case_budget():
             lines = run(args.quick)

@@ -71,7 +71,6 @@ import json
 import os
 import subprocess
 import sys
-import textwrap
 
 import jax.numpy as jnp
 import numpy as np
@@ -247,7 +246,7 @@ def _case3d(shape: str, r: int, t: int, x3) -> dict:
     w = make_weights(spec, seed=r)
     halo = r * t
     hb = choose_hblock(STRIP3, halo)
-    zb = choose_hblock(SLAB3, halo)
+    zb = choose_hblock(SLAB3, halo, 1)          # z is a leading axis
     sub = SubstrateGeom(dim=3, strip_m=STRIP3, h_block=hb,
                         z_slab=SLAB3, z_block=zb)
     whole = SubstrateGeom(dim=3, strip_m=STRIP3, h_block=0,
@@ -418,82 +417,94 @@ def _case_boundary(mode, x) -> dict:
     return row
 
 
-def _case_halo_overlap() -> dict:
-    """Distributed overlap-vs-serialized timing pair (2 host devices).
+def _halo_overlap_row(devices) -> dict:
+    """Distributed overlap-vs-serialized timing pair on ``devices``.
 
     The serialized-exchange foil executes each step as two dispatches
     with a host sync between them -- the exchange must COMPLETE before
     the compute launches, which is exactly what a runtime without
     overlap pays.  The overlap stepper is one dispatch for all t steps
     with the interior scheduled against the in-flight ppermute pair.
-    Runs in a subprocess because the host-device count pins at first
-    jax init (the benchmark process itself must stay single-device).
     """
-    code = textwrap.dedent("""
-        import json, time
-        import jax, numpy as np, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from repro.stencil import StencilSpec, make_weights
-        from repro.stencil.distributed import (
-            _extend, apply_stencil_valid, make_distributed_stepper,
-            overlap_stats, reset_overlap_stats)
+    import time
 
-        (h, wdt), t, r = %(grid)s, %(t)d, 1
-        mesh = Mesh(np.array(jax.devices()), ("i",))
-        dims = ("i", None)
-        w = make_weights(StencilSpec("box", 2, r), seed=0)
-        x = np.random.default_rng(0).normal(size=(h, wdt)) \\
-              .astype(np.float32)
-        xd = jax.device_put(jnp.asarray(x), NamedSharding(mesh,
-                                                          P("i", None)))
-        spec = P("i", None)
-        wj = jnp.asarray(w)
-        ext = jax.jit(shard_map(lambda a: _extend(a, r, dims), mesh=mesh,
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.stencil.distributed import (
+        _extend, apply_stencil_valid, make_distributed_stepper,
+        overlap_stats, reset_overlap_stats)
+
+    (h, wdt), t, r = OVERLAP_GRID, OVERLAP_T, 1
+    mesh = Mesh(np.array(devices), ("i",))
+    dims = ("i", None)
+    w = make_weights(StencilSpec("box", 2, r), seed=0)
+    x = np.random.default_rng(0).normal(size=(h, wdt)).astype(np.float32)
+    spec = P("i", None)
+    xd = jax.device_put(jnp.asarray(x), NamedSharding(mesh, spec))
+    wj = jnp.asarray(w)
+    ext = jax.jit(jax.shard_map(lambda a: _extend(a, r, dims), mesh=mesh,
                                 in_specs=(spec,), out_specs=spec,
-                                check_rep=False))
-        comp = jax.jit(shard_map(lambda a: apply_stencil_valid(a, wj),
+                                check_vma=False))
+    comp = jax.jit(jax.shard_map(lambda a: apply_stencil_valid(a, wj),
                                  mesh=mesh, in_specs=(spec,),
-                                 out_specs=spec, check_rep=False))
+                                 out_specs=spec, check_vma=False))
 
-        def serialized(a):
-            for _ in range(t):
-                e = ext(a)
-                e.block_until_ready()      # exchange completes first
-                a = comp(e)
-            return a.block_until_ready()
+    def serialized(a):
+        for _ in range(t):
+            e = ext(a)
+            e.block_until_ready()      # exchange completes first
+            a = comp(e)
+        return a.block_until_ready()
 
-        reset_overlap_stats()
-        overlap = jax.jit(make_distributed_stepper(mesh, dims, w, t=t,
-                                                   mode="overlap"))
-        y_ser = serialized(xd)                       # warmup + reference
-        y_ov = overlap(xd).block_until_ready()       # traces counters
-        stats = overlap_stats()
+    reset_overlap_stats()
+    overlap = jax.jit(make_distributed_stepper(mesh, dims, w, t=t,
+                                               mode="overlap"))
+    y_ser = serialized(xd)                       # warmup + reference
+    y_ov = overlap(xd).block_until_ready()       # traces counters
+    stats = overlap_stats()
 
-        def best_us(fn, iters=5):
-            best = float("inf")
-            for _ in range(iters):
-                t0 = time.perf_counter()
-                fn()
-                best = min(best, time.perf_counter() - t0)
-            return best * 1e6
+    def best_us(fn, iters=5):
+        best = float("inf")
+        for _ in range(iters):
+            t0 = time.perf_counter()
+            fn()
+            best = min(best, time.perf_counter() - t0)
+        return best * 1e6
 
-        us_ser = best_us(lambda: serialized(xd)) / t
-        us_ov = best_us(lambda: overlap(xd).block_until_ready()) / t
-        print(json.dumps({
-            "devices": len(jax.devices()), "grid": [h, wdt], "t": t,
-            "r": r, "us_step_serialized": us_ser,
-            "us_step_overlap": us_ov,
-            "overlap_faster": us_ov < us_ser,
-            "bitwise_equal": bool(jnp.all(y_ser == y_ov)),
-            "interleave_counters": stats,
-        }))
-    """) % {"grid": OVERLAP_GRID, "t": OVERLAP_T}
+    us_ser = best_us(lambda: serialized(xd)) / t
+    us_ov = best_us(lambda: overlap(xd).block_until_ready()) / t
+    return {
+        "devices": len(devices), "platform": devices[0].platform,
+        "grid": [h, wdt], "t": t, "r": r, "us_step_serialized": us_ser,
+        "us_step_overlap": us_ov,
+        "overlap_faster": us_ov < us_ser,
+        "bitwise_equal": bool(jnp.all(y_ser == y_ov)),
+        "interleave_counters": stats,
+    }
+
+
+def _case_halo_overlap() -> dict:
+    """The overlap-vs-serialized pair (``_halo_overlap_row``).  On a TPU
+    it runs in this process on its devices -- the process holds the
+    chips, so a child could not reach them -- and is skipped, explicitly,
+    with fewer than 2.  On the CPU it runs in a subprocess with 2 forced
+    host devices, because the host-device count pins at first jax init
+    (the benchmark process itself must stay single-device)."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        devices = jax.devices()
+        if len(devices) < 2:
+            return {"case": "halo-overlap", "skipped": "needs >= 2 devices"}
+        return {"case": "halo-overlap", **_halo_overlap_row(devices)}
+    code = ("import json, jax\n"
+            "from benchmarks.traffic import _halo_overlap_row\n"
+            "print(json.dumps(_halo_overlap_row(jax.devices())))")
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    env["PYTHONPATH"] = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "..", "src") \
-        + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=560)
     if r.returncode != 0:

@@ -34,8 +34,8 @@ export BENCH_STAMP
 
 python benchmarks/run.py
 
-# The harness swallows per-module failures so the sweep always finishes;
-# the manifest it writes names every failed module.  Gate on it directly.
+# The harness finishes the sweep past a failed module, then exits 1 (which
+# stops this script); the manifest it writes names every failed module.
 python - <<'EOF'
 import json
 with open("BENCH_run.json") as f:

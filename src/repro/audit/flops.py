@@ -118,7 +118,7 @@ def _walk(jaxpr, mult, ring, launches, state):
 
 def _jaxprs_in(v):
     """Jaxpr-valued params (pjit bodies etc.), unwrapped."""
-    import jax.core as jcore
+    from jax.extend import core as jcore
     vals = v if isinstance(v, (tuple, list)) else (v,)
     out = []
     for item in vals:

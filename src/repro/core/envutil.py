@@ -94,6 +94,29 @@ def env_int_list(name: str, default: Sequence[int],
     return tuple(out)
 
 
+#: Where :func:`init_compile_cache` keeps JAX's persistent compilation
+#: cache when ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed path in the
+#: checkout (the path is part of the cache key, so it must not move).
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache")
+
+
+def init_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; entry points call this at
+    start (never at import).  With ``JAX_COMPILATION_CACHE_DIR`` set, JAX
+    reads it itself and nothing here overrides it; otherwise the cache
+    goes to :data:`CHECKOUT_CACHE_DIR`.  Returns the directory in use."""
+    import jax
+
+    placed = env_str("JAX_COMPILATION_CACHE_DIR")
+    if placed is not None:
+        return placed
+    path = os.path.normpath(CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def env_flag(name: str, default: bool = False) -> bool:
     """Boolean knob: ``1/true/yes/on`` enable, ``0/false/no/off`` disable
     (case-insensitive); anything else is a configuration error."""

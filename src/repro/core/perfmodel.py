@@ -97,6 +97,26 @@ TPU_V5E_INT8_CEILING = dataclasses.replace(
     TPU_V5E_BF16, name="TPU v5e (bf16 + int8 ceiling)", p_sparse=394e12
 )
 
+#: ``jax.Device.device_kind`` -> (spec, published source of its peaks).
+HARDWARE_BY_DEVICE_KIND = {
+    "TPU v5 lite": (TPU_V5E_BF16,
+                    "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                    "bf16, 16 GB HBM at 819 GB/s per chip"),
+}
+
+
+def hardware_for(device) -> HardwareSpec:
+    """The spec of the chip ``device`` (a ``jax.Device``) reports, keyed
+    by its ``device_kind``.  A kind not in :data:`HARDWARE_BY_DEVICE_KIND`
+    raises: pricing an unknown chip with another chip's peaks would make
+    every selector decision on it a guess."""
+    kind = device.device_kind
+    if kind not in HARDWARE_BY_DEVICE_KIND:
+        raise ValueError(
+            f"no hardware spec for device_kind {kind!r}; known kinds: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)}")
+    return HARDWARE_BY_DEVICE_KIND[kind][0]
+
 
 # ---------------------------------------------------------------------------
 # Workload formulation (paper §3.2)

@@ -152,13 +152,64 @@ NEIGHBOR_OFFSETS_STRIP = (-1, 0, 1)
 #: sub-blocked substrate issues ``strip_m/h_block + 2`` h-row blocks.
 STRIP_NEIGHBOR_LOADS = len(NEIGHBOR_OFFSETS_STRIP)
 
-#: Default VMEM working-set budget for strip sizing (bytes).  TPU v4/v5
-#: cores have ~16 MB of VMEM; this budget is deliberately HALF of that
-#: (8 MB) so the other half stays free for Mosaic's double buffering and
-#: pipeline slack.  Override per process with the REPRO_VMEM_BUDGET
+#: Default VMEM working-set budget for strip sizing (bytes).  A kernel
+#: compiled for a TPU v5e gets a 16 MiB scoped VMEM limit by default (the
+#: compiler's own overflow message states it; the core has 128 MiB); this
+#: budget is deliberately HALF of that default (8 MiB) so the other half
+#: stays free for Mosaic's double buffering and pipeline slack.  Compiled
+#: kernels request ``VMEM_LIMIT_BYTES`` instead of the default, because
+#: the budget does not price the tap-sum intermediates (below).
+#: Override per process with the REPRO_VMEM_BUDGET
 #: environment variable (``vmem_budget_bytes``), validated like
 #: REPRO_PLAN_CACHE_SIZE.
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+
+#: Scoped VMEM limit the compiled kernels request.  The budget above
+#: prices the blocks and one copy of the compute region, but Mosaic also
+#: keeps every tap-sum intermediate in VMEM: compiled for a TPU v5e
+#: (whose default scoped limit is 16 MiB), the 10240-wide VPU kernels
+#: sized at the 8 MiB budget allocate 20-35 MiB and are refused.  The
+#: v5e core has 128 MiB of VMEM, so half of it leaves those a 2x margin.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+#: Mosaic's block tiling rule: the last two dims of every BlockSpec block
+#: must be multiples of (sublane tile, LANE) or span the whole array dim.
+#: The sublane tile is 8 rows of 32-bit words, so packed dtypes need
+#: proportionally more rows (``sublane_tile``).
+SUBLANE = 8
+LANE = 128
+
+
+def sublane_tile(dtype_bytes: int) -> int:
+    """Rows per native (sublane, lane) tile: 8 for 32-bit, 16 for 16-bit,
+    32 for 8-bit dtypes."""
+    return max(SUBLANE, 32 // dtype_bytes)
+
+
+def check_tpu_tiling(grid_shape, geom: "SubstrateGeom",
+                     dtype_bytes: int) -> None:
+    """Raise ``ValueError`` when ``geom`` would launch a block Mosaic
+    refuses: a second-minor block (``strip_m``, ``h_block``) that is
+    neither a multiple of the sublane tile nor the grid's row count, or a
+    lane-axis block (``w_tile``, ``w_block``) that is not a multiple of
+    128.  Auto sizing never produces one; only explicit pins can, and
+    interpret mode accepts them, so plans check only when compiling."""
+    if geom.dim == 1:
+        return          # the lifted (1, N) blocks span the whole array
+    rows, sub = grid_shape[-2], sublane_tile(dtype_bytes)
+    bad = [f"{name}={v} (needs a multiple of {sub} or {rows})"
+           for name, v in (("strip_m", geom.strip_m),
+                           ("h_block", geom.h_block))
+           if v and v % sub and v != rows]
+    if geom.w_tile:
+        bad += [f"{name}={v} (needs a multiple of {LANE})"
+                for name, v in (("w_tile", geom.w_tile),
+                                ("w_block", geom.w_block)) if v % LANE]
+    if bad:
+        raise ValueError(
+            f"geometry for grid {tuple(grid_shape)} breaks the TPU block "
+            f"tiling rule: {', '.join(bad)}; drop the pins to auto-size, "
+            "or pass interpret=True")
 
 
 def vmem_budget_bytes() -> int:
@@ -271,8 +322,9 @@ def extend_columns(x: jax.Array, halo: int, mode: str = "periodic",
         lo = jnp.tile(x[..., :1], reps)
         hi = jnp.tile(x[..., -1:], reps)
     elif mode == "reflect":
-        lo = jnp.flip(x[..., 1:h + 1], axis=-1)
-        hi = jnp.flip(x[..., -h - 1:-1], axis=-1)
+        n = x.shape[-1]
+        lo = _mirror(x, 1, h + 1, x.ndim - 1)
+        hi = _mirror(x, n - h - 1, n - 1, x.ndim - 1)
     else:
         raise ValueError(f"unknown boundary mode {mode!r}")
     if lo_edge is not True:
@@ -280,6 +332,15 @@ def extend_columns(x: jax.Array, halo: int, mode: str = "periodic",
     if hi_edge is not True:
         hi = jnp.where(hi_edge, hi, wrap_hi)
     return jnp.concatenate([lo, x, hi], axis=-1)
+
+
+def _mirror(x: jax.Array, lo: int, hi: int, axis: int) -> jax.Array:
+    """``x[lo:hi]`` along ``axis`` in reverse order, i.e. ``jnp.flip`` of
+    that static slice, built from single-index slices: Mosaic has no
+    lowering for ``rev``, the primitive behind ``jnp.flip``."""
+    parts = [jax.lax.slice_in_dim(x, i, i + 1, axis=axis)
+             for i in range(hi - 1, lo - 1, -1)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
 
 
 def _reflect_block(idx, total: int):
@@ -357,9 +418,8 @@ def apply_boundary_fills(cur, modes, edges, halo: int, x_pad: int = 0,
             lo_fill = jnp.tile(cur[sl(ax, o, o + 1)], reps)
             hi_fill = jnp.tile(cur[sl(ax, valid + o - 1, valid + o)], reps)
         elif mode == "reflect":
-            lo_fill = jnp.flip(cur[sl(ax, o + 1, 2 * o + 1)], axis=ax)
-            hi_fill = jnp.flip(cur[sl(ax, valid - 1, valid + o - 1)],
-                               axis=ax)
+            lo_fill = _mirror(cur, o + 1, 2 * o + 1, ax)
+            hi_fill = _mirror(cur, valid - 1, valid + o - 1, ax)
         else:
             raise ValueError(f"unknown boundary mode {mode!r}")
         lo = lo_fill if lo_flag is True \
@@ -391,21 +451,24 @@ def choose_tile(n: int, preferred: int = 128) -> int:
     return min(n, preferred)
 
 
-def choose_hblock(strip_m: int, halo: int) -> int:
-    """Halo-block height: smallest divisor of strip_m >= max(halo, strip/16).
+def choose_hblock(strip_m: int, halo: int, align: int = SUBLANE) -> int:
+    """Halo-block height: smallest multiple of ``align`` dividing strip_m
+    and >= max(halo, strip/16); strip_m itself when there is none.
 
     ``h_block`` must cover the halo in one neighbor block (>= halo) and
     divide the strip.  Smaller blocks cut traffic (amplification is
-    1 + 2h/strip_m) but multiply grid cells and shrink below the TPU
-    sublane tile for thin strips, so we floor at ceil(strip_m/16) --
-    amplification lands at ~1.125 whenever the halo allows, and degrades
-    gracefully toward the whole-strip 3x as the halo forces h_block up
-    (h_block = strip_m whenever no proper divisor reaches the halo).
+    1 + 2h/strip_m) but multiply grid cells, so we floor at
+    ceil(strip_m/16) -- amplification lands at ~1.125 whenever the halo
+    allows, and degrades gracefully toward the whole-strip 3x as the halo
+    forces h_block up.  ``align`` is the tiling rule of the axis the
+    block sits on: the sublane tile for the row axis (8 for 32-bit,
+    ``sublane_tile``), ``LANE`` for the lane axis, 1 for a leading axis
+    (z), which Mosaic does not tile.
     """
     if strip_m <= 0:
         raise ValueError(f"strip height must be positive, got {strip_m}")
     floor = max(halo, -(-strip_m // 16))      # integer ceil division
-    cands = [d for d in range(1, strip_m + 1)
+    cands = [d for d in range(align, strip_m + 1, align)
              if strip_m % d == 0 and d >= floor]
     return min(cands) if cands else strip_m
 
@@ -430,23 +493,19 @@ def _col_working_set_2d(sm: int, hb: int, wt: int, wb: int, halo: int,
     return (scratch + compute + sm * wt) * dtype_bytes
 
 
-def _wtile_candidates(w: int, x_halo: int, preferred: int = 128) -> list:
-    """Column-tile widths worth considering for a width-``w`` grid.
-
-    Divisors of ``w`` (the aligned path: pure modulo-wrap column walk,
-    zero host traffic) that can hold the x-halo, plus the caps
-    ``min(w-1, k*preferred)`` for k in (1, 2, 4) -- non-divisor caps run
-    the edge-tile remainder path, so prime and awkward widths still get
-    a full-size tile instead of a degenerate divisor.  ``w`` itself is
-    excluded: that is the full-width fast path, not a column tiling.
+def _wtile_candidates(w: int, x_halo: int) -> list:
+    """Column-tile widths worth considering for a width-``w`` grid: the
+    multiples of ``LANE`` below ``w`` (a lane-axis block must be one)
+    that divide ``w`` (the aligned path: pure modulo-wrap column walk,
+    zero host traffic) and hold the x-halo, plus ``k*LANE`` for k in
+    (1, 2, 4) -- non-divisor tiles run the edge-tile remainder path, so
+    prime and awkward widths still get a full-size tile.  ``w`` itself
+    is excluded: that is the full-width fast path, not a column tiling.
+    Empty when ``w <= LANE``: such a grid cannot be column-tiled.
     """
-    lo = max(x_halo, 1)
-    cands = {d for d in range(lo, w) if w % d == 0}
-    for k in (1, 2, 4):
-        cap = min(w - 1, k * preferred)
-        if cap >= lo:
-            cands.add(cap)
-    return sorted(cands) or [max(w - 1, 1)]
+    cands = {d for d in range(LANE, w, LANE) if w % d == 0 and d >= x_halo}
+    cands.update(k * LANE for k in (1, 2, 4) if k * LANE < w)
+    return sorted(cands)
 
 
 def choose_strip_blocks(
@@ -459,9 +518,11 @@ def choose_strip_blocks(
 ) -> tuple:
     """Jointly size the full-width (strip_m, h_block) under the VMEM budget.
 
-    ``strip_m``: a divisor of ``h``, >= halo, fitting VMEM; among fitting
-    divisors prefer the largest <= ``preferred`` (taller strips both
-    amortize per-cell cost and shrink the halo read factor 1 + 2h/strip_m).
+    ``strip_m``: a divisor of ``h``, >= halo, fitting VMEM, and a multiple
+    of the dtype's sublane tile unless it is ``h`` itself (the tiling
+    rule); among fitting divisors prefer the largest <= ``preferred``
+    (taller strips both amortize per-cell cost and shrink the halo read
+    factor 1 + 2h/strip_m).
     ``h_block``: ``choose_hblock`` of the chosen strip.  The input-side
     working set is priced at the worse of the two substrates
     (``_strip_working_set``), so a strip that fits the budget fits
@@ -473,18 +534,18 @@ def choose_strip_blocks(
     """
     if vmem_budget is None:
         vmem_budget = vmem_budget_bytes()
+    sub = sublane_tile(dtype_bytes)
 
     def working_set(d: int) -> int:
-        return _strip_working_set(d, choose_hblock(d, halo), n, halo,
+        return _strip_working_set(d, choose_hblock(d, halo, sub), n, halo,
                                   dtype_bytes)
 
-    divisors = [d for d in range(1, h + 1) if h % d == 0]
-    viable = [d for d in divisors if d >= halo] or [h]
+    viable = _axis_candidates(h, halo, None, h, sub)
     fitting = [d for d in viable if working_set(d) <= vmem_budget]
     pool = fitting or [min(viable)]
     under = [d for d in pool if d <= preferred]
     strip_m = max(under) if under else min(pool)
-    return strip_m, choose_hblock(strip_m, halo)
+    return strip_m, choose_hblock(strip_m, halo, sub)
 
 
 def choose_strip(
@@ -501,13 +562,15 @@ def choose_strip(
 
 
 def _axis_candidates(extent: int, halo: int, pin: int,
-                     preferred: int = 128) -> list:
-    """Leading-axis tile candidates: divisors >= halo capped at
+                     preferred: int = 128, align: int = 1) -> list:
+    """Tile candidates along one axis: divisors >= halo that are
+    multiples of ``align`` (or the whole extent), capped at
     ``preferred`` (pins pass through verbatim)."""
     if pin is not None:
         return [pin]
     cands = [d for d in range(1, extent + 1)
-             if extent % d == 0 and d >= halo] or [extent]
+             if extent % d == 0 and d >= halo
+             and (d % align == 0 or d == extent)] or [extent]
     capped = [d for d in cands if d <= preferred]
     return capped or [min(cands)]
 
@@ -540,26 +603,33 @@ def choose_col_blocks(
     if vmem_budget is None:
         vmem_budget = vmem_budget_bytes()
     xh = halo if x_halo is None else x_halo
+    sub = sublane_tile(dtype_bytes)
+
+    def hb_of(sm: int) -> int:
+        return choose_hblock(sm, halo, sub)
 
     def wb_of(wt: int) -> int:
-        return choose_hblock(wt, max(xh, 1))
+        return choose_hblock(wt, max(xh, 1), LANE)
 
     def ws(sm: int, wt: int) -> int:
-        return _col_working_set_2d(sm, choose_hblock(sm, halo), wt,
-                                   wb_of(wt), halo, xh, dtype_bytes)
+        return _col_working_set_2d(sm, hb_of(sm), wt, wb_of(wt), halo, xh,
+                                   dtype_bytes)
 
     def amp(sm: int, wt: int) -> float:
-        return (substrate_read_amp(sm, choose_hblock(sm, halo))
+        return (substrate_read_amp(sm, hb_of(sm))
                 * substrate_read_amp(wt, wb_of(wt)))
 
-    pairs = [(sm, wt)
-             for sm in _axis_candidates(h, halo, m_pin, preferred)
-             for wt in ([w_pin] if w_pin else _wtile_candidates(w, xh,
-                                                                preferred))]
+    strips = _axis_candidates(h, halo, m_pin, preferred, sub)
+    w_cands = [w_pin] if w_pin else _wtile_candidates(w, xh)
+    if not w_cands:
+        # Too narrow to column-tile (w <= LANE): the thinnest full-width
+        # strip is the only legal geometry left.
+        return min(strips), hb_of(min(strips)), 0, 0
+    pairs = [(sm, wt) for sm in strips for wt in w_cands]
     fitting = [p for p in pairs if ws(*p) <= vmem_budget]
     pool = fitting or [min(pairs, key=lambda p: ws(*p))]
     sm, wt = min(pool, key=lambda p: (amp(*p), -p[0] * p[1]))
-    return sm, choose_hblock(sm, halo), wt, wb_of(wt)
+    return sm, hb_of(sm), wt, wb_of(wt)
 
 
 def choose_slab_blocks(
@@ -607,12 +677,13 @@ def choose_slab_blocks(
     if vmem_budget is None:
         vmem_budget = vmem_budget_bytes()
     xh = halo if x_halo is None else x_halo
+    sub = sublane_tile(dtype_bytes)
 
     def blocks(zs: int, sm: int) -> tuple:
-        return choose_hblock(zs, halo), choose_hblock(sm, halo)
+        return choose_hblock(zs, halo, 1), choose_hblock(sm, halo, sub)
 
     def wb_of(wt: int) -> int:
-        return choose_hblock(wt, max(xh, 1))
+        return choose_hblock(wt, max(xh, 1), LANE)
 
     def working_set(zs: int, sm: int) -> int:
         zb, hb = blocks(zs, sm)
@@ -635,16 +706,16 @@ def choose_slab_blocks(
         return substrate_read_amp(sm, hb) * substrate_read_amp(zs, zb)
 
     pairs = [(zs, sm) for zs in _axis_candidates(z, halo, z_pin, preferred)
-             for sm in _axis_candidates(h, halo, m_pin, preferred)]
+             for sm in _axis_candidates(h, halo, m_pin, preferred, sub)]
+    w_cands = [w_pin] if w_pin else _wtile_candidates(n, xh)
     if not w_pin:
         fitting = [p for p in pairs if working_set(*p) <= vmem_budget]
-        if fitting or w_pin == 0:
+        if fitting or w_pin == 0 or not w_cands:
             pool = fitting or [min(pairs, key=lambda p: working_set(*p))]
             zs, sm = min(pool, key=lambda p: (amp(*p), -p[0] * p[1]))
             zb, hb = blocks(zs, sm)
             return zs, zb, sm, hb, 0, 0
 
-    w_cands = [w_pin] if w_pin else _wtile_candidates(n, xh, preferred)
     triples = [(zs, sm, wt) for zs, sm in pairs for wt in w_cands]
     fitting = [t for t in triples if working_set_col(*t) <= vmem_budget]
     pool = fitting or [min(triples, key=lambda t: working_set_col(*t))]
@@ -725,7 +796,7 @@ def _resolve_z_block(h_block: int, z_block: int, z_slab: int,
         raise ValueError(
             "z_block=0 (whole-slab) is only valid together with "
             "h_block=0 (the whole-slab foil substrate)")
-    return z_block if z_block is not None else choose_hblock(z_slab, halo)
+    return z_block if z_block is not None else choose_hblock(z_slab, halo, 1)
 
 
 def _resolve_w_block(w_tile: int, w_block: int, h_block: int,
@@ -751,7 +822,7 @@ def _resolve_w_block(w_tile: int, w_block: int, h_block: int,
             "the full width; column tiling (w_tile > 0) requires the "
             "sub-blocked substrate")
     if w_block is None or w_block == 0:
-        return w_tile, choose_hblock(w_tile, max(x_halo, 1))
+        return w_tile, choose_hblock(w_tile, max(x_halo, 1), LANE)
     return w_tile, w_block
 
 
@@ -1370,6 +1441,18 @@ def _edge_flags(lg: LaunchGeometry):
     return tuple(flags)
 
 
+def _pin_region(cur: jax.Array, interpret: bool) -> jax.Array:
+    """Interpret mode only: an ``optimization_barrier`` between region
+    assembly and compute.  The interpreter runs the kernel body on
+    XLA:CPU, which would otherwise fuse the assembly (a concat for the
+    foils, a scratch slice for the ring) into the tap sums and form FMAs
+    differently per substrate -- the 3D sub-blocked and whole-slab
+    kernels then differ in the last ulp, where the tests assert them
+    bitwise equal.  Mosaic has no lowering for the barrier, so compiled
+    kernels go without it."""
+    return jax.lax.optimization_barrier(cur) if interpret else cur
+
+
 def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
             consts=()) -> jax.Array:
     """Execute one launch geometry: THE place every substrate kind lowers
@@ -1403,7 +1486,8 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
             ins = refs[:n_in]
             *const_refs, out_ref = refs[n_in:]
             edges = _edge_flags(lg) if edged else None
-            cur = _assemble_foil(lg, ins).astype(jnp.float32)
+            cur = _pin_region(_assemble_foil(lg, ins).astype(jnp.float32),
+                              interpret)
             out_ref[...] = compute(cur, edges, *const_refs).astype(out_dtype)
 
         extra = {}
@@ -1424,7 +1508,8 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
 
             @pl.when(j == fire)
             def _compute():
-                cur = scratch_ref[read_ix].astype(jnp.float32)
+                cur = _pin_region(scratch_ref[read_ix].astype(jnp.float32),
+                                  interpret)
                 out_ref[...] = compute(cur, edges,
                                        *const_refs).astype(out_dtype)
 
@@ -1437,6 +1522,8 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
         out_specs=pl.BlockSpec(lg.out_block, lg.out_index_map),
         out_shape=jax.ShapeDtypeStruct(lg.out_shape, x.dtype),
         interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         **extra,
     )(*((src,) * n_in), *consts)
     if lg.out_shape != x.shape:
@@ -1542,8 +1629,8 @@ def slab_substrate_call(compute, x: jax.Array, geom: SubstrateGeom,
     plus the single neighbor blocks that can contain halo planes/rows --
     into a VMEM scratch of (z_slab + 2*z_block, strip_m + 2*h_block, W);
     compute fires on the ring's final block (``pl.when``).  Both paths
-    assemble byte-identical extended slabs, so (with the kernels'
-    optimization_barrier between assembly and compute) their outputs are
+    assemble byte-identical extended slabs, so (with ``_pin_region``
+    between assembly and compute in interpret mode) their outputs are
     bit-for-bit equal.
 
     ``geom.w_tile`` > 0 selects the column-tiled scheme (DESIGN.md §10):
@@ -1635,6 +1722,7 @@ def resolve_strip_blocks(grid_shape, halo: int, dtype_bytes: int,
         # with the VMEM budget (the auto w_tile need not be divisible).
         _resolve_w_block(0, w_block, h_block, xh)
     budget = vmem_budget_bytes()
+    sub = sublane_tile(dtype_bytes)
 
     def fullwidth() -> tuple:
         if tile_m is None:
@@ -1644,7 +1732,8 @@ def resolve_strip_blocks(grid_shape, halo: int, dtype_bytes: int,
             strip_m, auto_hb = min(tile_m, h), None
         hb = h_block
         if hb is None:
-            hb = choose_hblock(strip_m, halo) if auto_hb is None else auto_hb
+            hb = choose_hblock(strip_m, halo, sub) if auto_hb is None \
+                else auto_hb
         return strip_m, hb
 
     if w_tile == 0 or h_block == 0:
@@ -1655,7 +1744,7 @@ def resolve_strip_blocks(grid_shape, halo: int, dtype_bytes: int,
         return sm, hb, 0, 0
     if w_tile is None:
         sm, hb = fullwidth()
-        ws_hb = hb if hb else choose_hblock(sm, halo)
+        ws_hb = hb if hb else choose_hblock(sm, halo, sub)
         if _strip_working_set(sm, ws_hb, wid, halo, dtype_bytes) <= budget:
             _resolve_w_block(0, w_block, hb, xh)
             return sm, hb, 0, 0

@@ -36,8 +36,9 @@ from repro.core import perfmodel as pm
 from repro.stencil.boundary import is_periodic, resolve_boundary
 from repro.stencil.spec import StencilSpec
 from repro.stencil.weights import fuse_weights
-from .common import (SubstrateGeom, choose_tile, launch_geometry,
-                     resolve_substrate_geom, validate_tiling)
+from .common import (SubstrateGeom, check_tpu_tiling, choose_tile,
+                     launch_geometry, resolve_substrate_geom,
+                     validate_tiling)
 from . import legacy as _legacy
 from . import ref as _ref
 from .stencil_direct import stencil_direct
@@ -113,6 +114,9 @@ class PlanContext:
                         geom.z_slab if geom.dim == 3 else None, geom.z_block,
                         geom.w_tile, geom.w_block, halo,
                         boundary=self.boundary)
+        if not self.interpret:
+            check_tpu_tiling(self.grid_shape, geom,
+                             np.dtype(self.dtype).itemsize)
 
 
 # ---------------------------------------------------------------------------

@@ -66,14 +66,7 @@ def _stencil_steps(cur: jax.Array, edges, weights, t: int, radius: int,
     re-pads the *updated* field every step (DESIGN.md §15); ``x_pad``
     is the remainder path's right-padding column count, shifting the
     last tile's x fill (the pad tail only feeds sliced-off columns).
-
-    The barrier keeps XLA from fusing the region assembly (refs
-    concatenated by the whole substrates, a scratch slice for the
-    sub-blocked ones) into the tap sum -- assembly-dependent FMA
-    formation would otherwise perturb the last ulp, and the substrates
-    are asserted BIT-for-bit equal (tests/test_substrate_strips.py).
     """
-    cur = jax.lax.optimization_barrier(cur)
     wshape = weights.shape
     for k in range(t):
         if edges is not None:

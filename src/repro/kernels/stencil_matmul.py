@@ -125,6 +125,17 @@ def band_sparsity(weights: np.ndarray, tile_n: int) -> float:
     return float(per_row.sum()) / (per_row.size * (tile_n + 2 * radius))
 
 
+def banded_dot(a: jax.Array, b: jax.Array, compute_dtype) -> jax.Array:
+    """One banded MXU contraction, accumulated in f32.  An f32 contraction
+    asks for full f32 precision: Mosaic's default for f32 operands is not
+    specified to be exact, and the plans are held to the f32 oracle."""
+    a, b = a.astype(compute_dtype), b.astype(compute_dtype)
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot(a, b, precision=precision,
+                       preferred_element_type=jnp.float32)
+
+
 def _banded_step(z: jax.Array, bands_ref, offsets, lead_extents,
                  radius: int, tile_n: int, compute_dtype,
                  wrap_x: bool = True, mode_x: str = "periodic") -> jax.Array:
@@ -172,9 +183,7 @@ def _banded_step(z: jax.Array, bands_ref, offsets, lead_extents,
             b = bands_ref[p]                  # (bands_w + 2r, bands_w)
             if wcur != bands_w:
                 b = b[:wcur + 2 * radius, :wcur]
-            acc = acc + jax.lax.dot(a.astype(compute_dtype),
-                                    b.astype(compute_dtype),
-                                    preferred_element_type=jnp.float32)
+            acc = acc + banded_dot(a, b, compute_dtype)
         cols.append(acc)
         start += wcur
     out = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
@@ -184,12 +193,9 @@ def _banded_step(z: jax.Array, bands_ref, offsets, lead_extents,
 def _banded_steps(cur: jax.Array, edges, bands_ref, offsets, lead_extents,
                   t: int, radius: int, tile_n: int, compute_dtype, modes,
                   wrap_x: bool = True, x_pad: int = 0) -> jax.Array:
-    # Barrier between region assembly and contraction: keeps the
-    # substrates' compute graphs identical so their outputs stay bit-for-bit
-    # equal (see stencil_direct._stencil_steps).  Non-periodic launches
-    # re-impose the boundary on the shrinking out-of-domain halo before
-    # every step, exactly like the VPU kernel (DESIGN.md §15).
-    cur = jax.lax.optimization_barrier(cur)
+    # Non-periodic launches re-impose the boundary on the shrinking
+    # out-of-domain halo before every step, exactly like the VPU kernel
+    # (DESIGN.md §15).
     for k in range(t):
         if edges is not None:
             cur = apply_boundary_fills(cur, modes, edges, (t - k) * radius,

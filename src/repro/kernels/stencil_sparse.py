@@ -47,7 +47,7 @@ from .common import (apply_boundary_fills, choose_tile, extend_columns,
                      lift_boundary_1d, resolve_substrate_geom,
                      slab_substrate_call, strip_substrate_call,
                      validate_tiling)
-from .stencil_matmul import build_bands_nd
+from .stencil_matmul import banded_dot, build_bands_nd
 from repro.stencil.boundary import resolve_boundary
 
 
@@ -169,9 +169,7 @@ def _sparse_banded_step(z: jax.Array, packed_ref, offsets, row_meta,
                 a = a.reshape(m, wcur + 2 * radius)
                 kept = packed_ref[rs:rs + wcur + span, :wcur]
                 b = jnp.pad(kept, ((lo, 2 * radius - span - lo), (0, 0)))
-            acc = acc + jax.lax.dot(a.astype(compute_dtype),
-                                    b.astype(compute_dtype),
-                                    preferred_element_type=jnp.float32)
+            acc = acc + banded_dot(a, b, compute_dtype)
         cols.append(acc)
         start += wcur
     out = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
@@ -182,12 +180,9 @@ def _sparse_banded_steps(cur: jax.Array, edges, packed_ref, offsets,
                          row_meta, lead_extents, t: int, radius: int,
                          tile_n: int, compute_dtype, modes,
                          wrap_x: bool = True, x_pad: int = 0) -> jax.Array:
-    # Same assembly/compute barrier as the dense banded kernel: keeps the
-    # substrates' compute graphs identical so outputs stay bit-for-bit
-    # equal across substrate choices.  Non-periodic launches re-impose
-    # the boundary on the shrinking out-of-domain halo before every
-    # step, exactly like the dense kernels (DESIGN.md §15).
-    cur = jax.lax.optimization_barrier(cur)
+    # Non-periodic launches re-impose the boundary on the shrinking
+    # out-of-domain halo before every step, exactly like the dense
+    # kernels (DESIGN.md §15).
     for k in range(t):
         if edges is not None:
             cur = apply_boundary_fills(cur, modes, edges, (t - k) * radius,

@@ -156,7 +156,10 @@ def serve_stencil(args) -> dict:
 
 
 def main(argv=None):
+    from repro.core.envutil import init_compile_cache
+
     args = parse_args(argv)
+    init_compile_cache()
     if getattr(args, "cmd", None) == "stencil":
         serve_stencil(args)
         return

@@ -20,7 +20,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -106,8 +105,8 @@ def make_pipelined_step(layer_fn, n_layers: int, mesh: Mesh,
 
     in_specs = (P(axis), P())        # params layer-sharded; x replicated
     out_specs = P()
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

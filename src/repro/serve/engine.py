@@ -176,6 +176,12 @@ class StencilServer:
         self.shutdown()
         return False
 
+    def engine_plans(self) -> tuple:
+        """The batched plans the dispatcher built, one per (signature,
+        bucket).  Read it after :meth:`shutdown`: while the server runs,
+        the dispatcher thread owns the table."""
+        return tuple(self._plans.values())
+
     def stats(self) -> dict:
         """Metrics snapshot plus plan bookkeeping (engine table size and
         the process-wide plan-cache counters)."""

@@ -45,7 +45,6 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .boundary import PAD_MODE, is_periodic, resolve_boundary
@@ -410,8 +409,8 @@ def make_distributed_stepper(
     else:
         raise ValueError(f"unknown mode {mode!r}")
 
-    return shard_map(shard_fn, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=(spec,),
+                         out_specs=spec, check_vma=False)
 
 
 def pallas_local_apply(
@@ -440,9 +439,12 @@ def pallas_local_apply(
     shard pays HBM traffic once per exchange, not per step.  Execution goes
     through the plan cache (``repro.kernels.plan``): the per-shard plan is
     built once per (block shape, depth) signature and reused across steps
-    and traces.  By default the whole extended block is one strip / one
-    z-slab (``tile_m=None`` / ``z_slab=None``); pass explicit tiles to
-    exercise the multi-cell path.  ``h_block``/``z_block`` select the halo
+    and traces.  By default (``tile_m=None``) the extended block's row
+    count is zero-padded up to the sublane tile and the geometry is
+    auto-sized like any plan's: an extended extent (local + 2*halo) is
+    rarely tile-aligned, and the padding rows only feed the discarded
+    halo ring.  Pass explicit tiles to pin the geometry instead (the
+    block is then used as is).  ``h_block``/``z_block`` select the halo
     block heights of the substrate (``None`` = auto, ``h_block=0`` =
     whole-strip/whole-slab foil) -- the modulo wrap of either substrate is
     equally harmless here.  ``w_tile``/``w_block`` select the column-tiled
@@ -462,33 +464,36 @@ def pallas_local_apply(
     import numpy as _np
 
     def local_apply(xe, w, steps):
+        from repro.kernels.common import sublane_tile
         from repro.kernels.plan import stencil_plan  # deferred: avoid cycle
 
         wn = _np.asarray(w)
         radius = (wn.shape[0] - 1) // 2
         h = steps * radius
-        kw = dict(
-            tile_m=tile_m if tile_m is not None else xe.shape[-2],
-            tile_n=tile_n if tile_n is not None else xe.shape[-1],
-            h_block=h_block, w_tile=w_tile, w_block=w_block,
-        ) if xe.ndim >= 2 else dict(tile_n=tile_n)
+        kw = dict(tile_n=tile_n)
+        xp = xe
+        if xe.ndim >= 2:
+            kw.update(tile_m=tile_m, h_block=h_block, w_tile=w_tile,
+                      w_block=w_block)
+            pad = -xe.shape[-2] % sublane_tile(xe.dtype.itemsize)
+            if tile_m is None and pad:
+                rows = [(0, 0)] * xe.ndim
+                rows[-2] = (0, pad)
+                xp = jnp.pad(xe, rows)
         if xe.ndim == 3:
-            kw.update(z_slab=z_slab if z_slab is not None else xe.shape[0],
-                      z_block=z_block)
+            kw.update(z_slab=z_slab, z_block=z_block)
         if guard:
             from repro.kernels.guard import guarded_stencil_plan
             plan = guarded_stencil_plan(
-                wn, xe.shape, xe.dtype, steps, backend=backend,
+                wn, xp.shape, xp.dtype, steps, backend=backend,
                 interpret=interpret, **kw)
         else:
             plan = stencil_plan(
-                wn, xe.shape, xe.dtype, steps, backend=backend,
+                wn, xp.shape, xp.dtype, steps, backend=backend,
                 interpret=interpret, **kw,
             )
-        full = plan(xe)
-        if not h:
-            return full
-        return full[tuple(slice(h, -h) for _ in range(xe.ndim))]
+        full = plan(xp)
+        return full[tuple(slice(h, n - h) for n in xe.shape)]
 
     return local_apply
 

@@ -163,7 +163,10 @@ class TestShardedTraining:
             from repro.optim import adamw
             from repro.parallel import sharding
             from repro.train.steps import make_train_step
-            mesh = jax.make_mesh((2,2), ("data","model"))
+            # the sharding rules are constraints on Auto axes (JAX 0.9's
+            # make_mesh defaults to Explicit axes)
+            mesh = jax.make_mesh((2,2), ("data","model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             cfg = SMOKE["llama3.2-1b"]
             model = get_model(cfg)
             defs = model.param_defs()

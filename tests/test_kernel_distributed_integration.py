@@ -241,3 +241,31 @@ class TestDistributedPlan:
             print("OK")
         """)
         assert "OK" in out
+
+
+class TestLocalApplyGeometry:
+    def test_unaligned_extended_block_pads_to_a_legal_plan(self):
+        """An extended block whose row count is off the 8-row sublane
+        tile (local + 2*halo) is padded and auto-sized -- a geometry a
+        compiled plan accepts -- and still returns the valid interior."""
+        import jax.numpy as jnp
+        import numpy as np
+        from repro.kernels import plan_cache_stats, stencil_plan
+        from repro.stencil import StencilSpec, make_weights
+        from repro.stencil.distributed import (apply_stencil_valid,
+                                               pallas_local_apply)
+
+        w = make_weights(StencilSpec("box", 2, 1), seed=3)
+        xe = jnp.asarray(np.random.default_rng(0).normal(size=(34, 40))
+                         .astype(np.float32))
+        for backend in ("fused_direct", "fused_matmul_reuse"):
+            la = pallas_local_apply(backend, interpret=True)
+            y = la(xe, jnp.asarray(w), 1)
+            ref = apply_stencil_valid(xe, jnp.asarray(w))
+            assert y.shape == (32, 38)
+            np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
+                                       atol=1e-5)
+            plan = stencil_plan(w, (40, 40), np.float32, 1, backend=backend,
+                                interpret=True, tile_n=None)
+            assert plan.grid_shape == (40, 40)   # the padded plan exists
+        assert plan_cache_stats()["hits"] >= 2

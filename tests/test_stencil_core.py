@@ -1,4 +1,5 @@
 """Stencil domain: specs, weights, fusion composition, references."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -106,9 +107,7 @@ class TestReference:
     def test_roll_vs_conv_cross_check_f64(self, d, shape):
         """Same cross-check at f64: tolerances tighten by ~8 orders of
         magnitude, catching any dtype-dependent path divergence."""
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             spec = StencilSpec(shape, d, 1)
             w = make_weights(spec, seed=5, dtype=np.float64)
             x = jnp.asarray(np.random.default_rng(6).normal(size=(10,) * d))

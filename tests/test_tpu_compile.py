@@ -1,0 +1,180 @@
+"""Compile the main path's kernels for a TPU v5e that is described, not
+attached: the chip's own compiler (Mosaic) refuses what interpret mode
+accepts -- blocks off the (sublane, 128) tiling, primitives without a
+TPU lowering, scoped-VMEM overflow -- so these tests guard every plan
+shape the engine runs at real widths without any chip time.  Nothing
+executes; a compile that passes is not a chip run.
+
+The topology is described inside a fixture (never at import): only one
+process may load the TPU library, and the test workers each import this
+file.  The persistent compilation cache stays off around the compiles --
+an entry written for a described chip cannot be read back here.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import stencil_plan
+from repro.kernels.common import check_tpu_tiling, resolve_substrate_geom
+from repro.stencil import StencilSpec, make_weights
+
+STRIP_BACKENDS = ("direct", "fused_direct", "matmul", "fused_matmul",
+                  "fused_matmul_reuse", "sparse_matmul",
+                  "fused_sparse_matmul")
+FOILS = tuple(f"{b}_wholestrip" for b in STRIP_BACKENDS[:5])
+
+GRID_2D = (10240, 10240)
+GRID_3D = (512, 512, 512)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means no topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _depth(backend: str) -> int:
+    return 2 if backend.startswith("fused") else 1
+
+
+def _compile(one_chip, spec, grid, dtype, t, **kw):
+    """Build a compiled-mode plan (which checks the tiling rule at build)
+    and compile it for one described v5e chip; returns (plan, HLO text)."""
+    w = make_weights(spec, seed=0)
+    plan = stencil_plan(w, grid, dtype, t, interpret=False, use_cache=False,
+                        **kw)
+    arg = jax.ShapeDtypeStruct(plan.input_shape, plan.dtype,
+                               sharding=one_chip)
+    text = plan.fn.lower(arg).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return plan, text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("backend", STRIP_BACKENDS)
+def test_2d_strip_backends_compile(one_chip, backend, dtype):
+    plan, _ = _compile(one_chip, StencilSpec("box", 2, 1), GRID_2D, dtype,
+                       _depth(backend), backend=backend)
+    assert plan.backend == backend and plan.interpret is False
+
+
+@pytest.mark.parametrize("backend", FOILS)
+def test_2d_wholestrip_foils_compile(one_chip, backend):
+    _compile(one_chip, StencilSpec("box", 2, 1), GRID_2D, jnp.float32,
+             _depth(backend), backend=backend)
+
+
+@pytest.mark.parametrize("backend", STRIP_BACKENDS)
+def test_3d_strip_backends_compile(one_chip, backend):
+    _compile(one_chip, StencilSpec("star", 3, 1), GRID_3D, jnp.float32,
+             _depth(backend), backend=backend)
+
+
+@pytest.mark.parametrize("backend", STRIP_BACKENDS)
+def test_remainder_width_compiles(one_chip, backend):
+    """W=300: a final column chunk narrower than the 128-wide band."""
+    _compile(one_chip, StencilSpec("star", 2, 1), (2048, 300), jnp.float32,
+             _depth(backend), backend=backend)
+
+
+@pytest.mark.parametrize("backend", ["direct", "fused_matmul_reuse"])
+def test_auto_column_tiled_compiles(one_chip, backend):
+    """64x65536: full-width strips exceed the VMEM budget, so auto sizing
+    column-tiles, with lane-aligned tiles and blocks."""
+    halo = _depth(backend)
+    g = resolve_substrate_geom((64, 65536), halo, 4)
+    assert g.w_tile % 128 == 0 and g.w_block % 128 == 0 and g.w_tile > 0
+    _compile(one_chip, StencilSpec("star", 2, 1), (64, 65536), jnp.float32,
+             halo, backend=backend)
+
+
+@pytest.mark.parametrize("backend", ["fused_direct", "fused_matmul_reuse"])
+def test_reflect_plan_compiles(one_chip, backend):
+    """Reflect fills mirror static slices in kernel (no ``rev``)."""
+    _compile(one_chip, StencilSpec("box", 2, 1), (2048, 2048), jnp.float32,
+             2, backend=backend, boundary="reflect")
+
+
+@pytest.mark.parametrize("backend", ["fused_direct", "fused_matmul_reuse"])
+def test_vmap_batch_fold_compiles(one_chip, backend):
+    plan, _ = _compile(one_chip, StencilSpec("star", 2, 1), (256, 256),
+                       jnp.float32, 2, backend=backend, batch=8,
+                       batch_mode="vmap")
+    assert plan.batch_mode == "vmap"
+
+
+@pytest.mark.parametrize("grid,dtype_bytes,halo", [
+    ((10240, 10240), 4, 1), ((10240, 10240), 4, 4), ((10240, 10240), 2, 2),
+    ((512, 512, 512), 4, 2), ((2048, 300), 4, 2), ((64, 65536), 4, 1),
+    ((20, 64), 4, 1), ((10, 20, 24), 4, 2), ((10248, 10242), 4, 1),
+])
+def test_auto_geometry_obeys_tiling_rule(grid, dtype_bytes, halo):
+    """Auto sizing never emits a block Mosaic refuses (no topology
+    needed: the rule is checked on the resolved geometry)."""
+    g = resolve_substrate_geom(grid, halo, dtype_bytes)
+    check_tpu_tiling(grid, g, dtype_bytes)
+
+
+def test_pinned_illegal_geometry_raises_only_when_compiling():
+    w = make_weights(StencilSpec("box", 2, 1), seed=0)
+    with pytest.raises(ValueError, match="tiling rule.*h_block=2"):
+        stencil_plan(w, (64, 64), np.float32, 1, backend="direct",
+                     tile_m=16, h_block=2, interpret=False, use_cache=False)
+    with pytest.raises(ValueError, match="w_tile=32"):
+        stencil_plan(w, (64, 512), np.float32, 1, backend="direct",
+                     tile_m=16, h_block=8, w_tile=32, interpret=False,
+                     use_cache=False)
+    plan = stencil_plan(w, (64, 64), np.float32, 1, backend="direct",
+                        tile_m=16, h_block=2, interpret=True, use_cache=False)
+    assert plan.interpret is True
+
+
+def _primitives(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for item in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("backend", STRIP_BACKENDS)
+def test_compiled_kernels_trace_no_unlowerable_primitive(backend):
+    """Traced for the chip (interpret=False), no kernel body holds a
+    primitive Mosaic cannot lower: ``rev`` (reflect fills) or
+    ``optimization_barrier`` (kept for interpret mode only)."""
+    w = make_weights(StencilSpec("box", 2, 1), seed=0)
+    plan = stencil_plan(w, (64, 256), np.float32, _depth(backend),
+                        backend=backend, boundary="reflect"
+                        if backend != "fused_matmul" else None,
+                        interpret=False, use_cache=False)
+    closed = jax.make_jaxpr(plan.fn)(
+        jax.ShapeDtypeStruct((64, 256), jnp.float32))
+    prims = _primitives(closed.jaxpr, set())
+    assert "pallas_call" in prims
+    assert not prims & {"rev", "optimization_barrier"}, prims
