@@ -34,12 +34,12 @@ def main() -> int:
     init_compile_cache()
     # Import everything up front: module import cost must never leak into
     # any timed region.
-    from benchmarks import (fig10, fig16, halo, scaling, table2, table3,
-                            table4, traffic)
+    from benchmarks import (fig10, fig16, halo, table2, table3, table4,
+                            traffic)
     from repro.kernels import plan_cache_stats
 
     modules = []
-    for mod in (table2, table3, table4, fig10, fig16, halo, scaling, traffic):
+    for mod in (table2, table3, table4, fig10, fig16, halo, traffic):
         name = mod.__name__.split(".")[-1]
         t0 = time.perf_counter()
         try:

@@ -99,8 +99,7 @@ _reg(
     ModelConfig("internvl2-2b", "vlm", 2, 64, 4, 2, 128, 256, n_img_patches=16),
 )
 _reg(
-    # pure_dp: d=512 is far too narrow for 16-way TP (§Perf D1: 5.9x);
-    # the batch>=chips policy in dryrun falls back to TP for small-batch cells.
+    # pure_dp: d=512 is far too narrow for 16-way TP (§Perf D1: 5.9x).
     ModelConfig("whisper-base", "whisper", 6, 512, 8, 8, 2048, 51865,
                 mlp_act="gelu", dec_layers=6, pure_dp=True, fsdp=True),
     ModelConfig("whisper-base", "whisper", 2, 64, 4, 4, 128, 256,

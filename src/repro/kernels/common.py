@@ -141,6 +141,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import trace
 from repro.stencil.boundary import PAD_MODE, resolve_boundary
 
 #: Vertical neighbor offsets of the whole-strip scheme (up, center, down) --
@@ -1454,7 +1455,7 @@ def _pin_region(cur: jax.Array, interpret: bool) -> jax.Array:
 
 
 def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
-            consts=()) -> jax.Array:
+            consts=(), name: str = None, scopes: bool = False) -> jax.Array:
     """Execute one launch geometry: THE place every substrate kind lowers
     through.  Grid, BlockSpecs, scratch, ring slots, fire step and read
     window all come from ``lg`` -- the kernel body only dispatches on
@@ -1463,7 +1464,23 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
     ``compute(cur, edges, *const_refs)`` receives the f32 halo-extended
     region and the per-axis domain-edge flags (``None`` on all-periodic
     launches, where no fill can ever fire -- keeping the default jaxpr
-    bit-identical to the historical substrate)."""
+    bit-identical to the historical substrate).
+
+    ``name`` names the kernel in the compiled program and the profiler
+    trace (the plan passes its backend's name).  ``scopes`` compiles two
+    in-kernel trace scopes in (``repro.core.trace``), each a
+    ``tpu.trace_start``/``trace_stop`` pair:
+
+      * ``repro.substrate.assemble`` -- scratch kinds: every grid step's
+        store of its fetched block into its ring slot; scratch-free kinds:
+        the foil's concat of the fetched neighbor blocks (and its cast);
+      * ``repro.kernel.compute`` -- the fire step: the read of the
+        assembled region, ``compute`` (every fused step's fills and tap
+        sums or contractions) and the store to the output block.
+
+    Outside both lies what the BlockSpec pipeline does around the body:
+    DMA waits and per-grid-step overhead.  ``scopes=False`` traces no
+    scope at all, so the kernel is byte-identical to an unscoped one."""
     out_dtype = x.dtype
     rank = len(lg.grid)
     zero_map = _ZERO_INDEX_MAPS[rank]
@@ -1486,9 +1503,12 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
             ins = refs[:n_in]
             *const_refs, out_ref = refs[n_in:]
             edges = _edge_flags(lg) if edged else None
-            cur = _pin_region(_assemble_foil(lg, ins).astype(jnp.float32),
-                              interpret)
-            out_ref[...] = compute(cur, edges, *const_refs).astype(out_dtype)
+            with trace.scope(trace.SUBSTRATE_ASSEMBLE, scopes):
+                cur = _pin_region(
+                    _assemble_foil(lg, ins).astype(jnp.float32), interpret)
+            with trace.scope(trace.KERNEL_COMPUTE, scopes):
+                out_ref[...] = compute(cur, edges,
+                                       *const_refs).astype(out_dtype)
 
         extra = {}
     else:
@@ -1501,17 +1521,19 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
             *const_refs, out_ref, scratch_ref = rest
             j = pl.program_id(ring_axis)
             slot = tuple(pl.ds(s, b) for s, b in lg.scratch_slot(j))
-            scratch_ref[slot + full] = blk_ref[...]
+            with trace.scope(trace.SUBSTRATE_ASSEMBLE, scopes):
+                scratch_ref[slot + full] = blk_ref[...]
             # program_id must be read at kernel top level: the interpret
             # path only substitutes it outside pl.when bodies.
             edges = _edge_flags(lg) if edged else None
 
             @pl.when(j == fire)
             def _compute():
-                cur = _pin_region(scratch_ref[read_ix].astype(jnp.float32),
-                                  interpret)
-                out_ref[...] = compute(cur, edges,
-                                       *const_refs).astype(out_dtype)
+                with trace.scope(trace.KERNEL_COMPUTE, scopes):
+                    cur = _pin_region(
+                        scratch_ref[read_ix].astype(jnp.float32), interpret)
+                    out_ref[...] = compute(cur, edges,
+                                           *const_refs).astype(out_dtype)
 
         extra = {"scratch_shapes": [pltpu.VMEM(lg.scratch_shape, x.dtype)]}
 
@@ -1524,6 +1546,7 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name=name,
         **extra,
     )(*((src,) * n_in), *consts)
     if lg.out_shape != x.shape:
@@ -1534,7 +1557,8 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
 def strip_substrate_call(compute, x: jax.Array, strip_m: int, h_block: int,
                          halo: int, interpret: bool, consts=(),
                          w_tile: int = 0, w_block: int = 0,
-                         x_halo: int = 0, boundary=None) -> jax.Array:
+                         x_halo: int = 0, boundary=None, name: str = None,
+                         scopes: bool = False) -> jax.Array:
     """Launch ``compute`` over every output strip, on any halo substrate.
 
     The ONE place both strip kernels lower through -- substrate changes
@@ -1573,7 +1597,7 @@ def strip_substrate_call(compute, x: jax.Array, strip_m: int, h_block: int,
 
     lg = strip_launch_geometry(x.shape, strip_m, h_block, halo,
                                w_tile, w_block, x_halo, boundary=boundary)
-    return _launch(lg, compute, x, interpret, consts)
+    return _launch(lg, compute, x, interpret, consts, name, scopes)
 
 
 def _extend_columns_for_tiling(x: jax.Array, w_block: int, gw: int,
@@ -1611,7 +1635,8 @@ def _extend_columns_for_tiling(x: jax.Array, w_block: int, gw: int,
 
 def slab_substrate_call(compute, x: jax.Array, geom: SubstrateGeom,
                         halo: int, interpret: bool, consts=(),
-                        x_halo: int = 0, boundary=None) -> jax.Array:
+                        x_halo: int = 0, boundary=None, name: str = None,
+                        scopes: bool = False) -> jax.Array:
     """Launch ``compute`` over every (z-slab, strip) output cell of a 3D
     grid, on either halo-plane substrate (module docstring, DESIGN.md §9).
 
@@ -1648,7 +1673,7 @@ def slab_substrate_call(compute, x: jax.Array, geom: SubstrateGeom,
 
     lg = slab_launch_geometry(x.shape, geom, halo, x_halo,
                               boundary=boundary)
-    return _launch(lg, compute, x, interpret, consts)
+    return _launch(lg, compute, x, interpret, consts, name, scopes)
 
 
 def fold_batch(run, mode: str):

@@ -42,6 +42,7 @@ import jax
 import numpy as np
 
 from repro.core import perfmodel as pm
+from repro.core import trace
 from repro.core.selector import Decision, select_backend
 from repro.stencil.boundary import (BoundaryLike, boundary_label,
                                     is_periodic, resolve_boundary)
@@ -108,7 +109,14 @@ class StencilPlan:
       * ``build_time_s`` -- host seconds spent building (selection, sizing,
         weight composition; excludes XLA compilation, which happens on the
         first call);
+      * ``compiles`` / ``compile_s`` -- executables made inside this plan's
+        calls (XLA compiles and persistent-cache loads) and their seconds,
+        counted by a ``jax.monitoring`` listener (:func:`plan_cache_stats`
+        sums them over all plans);
       * ``fn``        -- the underlying jitted callable.
+
+    Each call runs inside a ``repro.plan.call`` profiler span
+    (``repro.core.trace``).
     """
 
     def __init__(self, *, spec, weights, grid_shape, dtype, t, hw, backend,
@@ -148,6 +156,8 @@ class StencilPlan:
         #: is enabled (``stencil_plan(..., audit=True)`` / REPRO_AUDIT=1);
         #: None otherwise.  Cached plans keep the report of their build.
         self.audit_report = None
+        self.compiles = 0
+        self.compile_s = 0.0
 
     # -- execution ------------------------------------------------------
     @property
@@ -164,7 +174,13 @@ class StencilPlan:
                 f"plan was built for input {self.input_shape} "
                 f"(grid {self.grid_shape}, batch {self.batch}), got "
                 f"{x.shape}; build a new plan for a new geometry")
-        return self.fn(x)
+        with jax.profiler.TraceAnnotation(trace.PLAN_CALL):
+            outer = getattr(_CALLING, "plan", None)
+            _CALLING.plan = self
+            try:
+                return self.fn(x)
+            finally:
+                _CALLING.plan = outer
 
     def step(self, x: jax.Array) -> jax.Array:
         """Alias for ``plan(x)``: one invocation = ``t`` time steps."""
@@ -252,7 +268,37 @@ _STATS = {"hits": 0, "misses": 0,
           # and total check violations found there.  Violations never
           # block the build -- they count, attach, and surface through
           # plan_cache_stats so CI and the serving loop can gate on them.
-          "audits_run": 0, "audit_violations": 0}
+          "audits_run": 0, "audit_violations": 0,
+          # executables made inside plan calls (compiles and persistent-
+          # cache loads) and their seconds; per plan on StencilPlan.
+          "compiles": 0, "compile_s": 0.0}
+
+#: The ``jax.monitoring`` event JAX records around making an executable:
+#: an XLA compile or a load from the persistent compilation cache.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+#: The plan whose call is running on this thread (innermost first: a
+#: sharded plan's per-shard plans are called while it traces).
+_CALLING = threading.local()
+
+
+def _on_event_duration(event: str, secs: float, **_kw) -> None:
+    """Attribute an executable made during a plan's call to that plan.
+    JAX compiles synchronously on the calling thread, so the plan on
+    this thread is the one whose call caused it."""
+    if event != COMPILE_EVENT:
+        return
+    plan = getattr(_CALLING, "plan", None)
+    if plan is None:
+        return
+    with _LOCK:
+        plan.compiles += 1
+        plan.compile_s += secs
+        _STATS["compiles"] += 1
+        _STATS["compile_s"] += secs
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
 
 #: Negative-result registry: signature key -> {"cause", "backend", "stamp"}.
 #: A signature lands here when its build/execution failed, so the guard
@@ -273,8 +319,9 @@ def plan_cache_max() -> int:
 
 def plan_cache_stats() -> dict:
     """Cache + guard counters: hits/misses/size plus ``build_failures``,
-    ``exec_failures``, ``fallbacks``, ``negative_hits``, ``negative_size``.
-    The snapshot is atomic -- taken under the cache lock."""
+    ``exec_failures``, ``fallbacks``, ``negative_hits``, ``negative_size``,
+    and ``compiles``/``compile_s`` made inside plan calls.  The snapshot
+    is atomic -- taken under the cache lock."""
     with _LOCK:
         out = dict(_STATS)
         out["size"] = len(_CACHE)
@@ -480,12 +527,15 @@ def plan_signature(
     # backend must win future auto plans, not be masked by the cache.
     # The effective VMEM budget is part of the key: auto geometry depends
     # on it, so retuning REPRO_VMEM_BUDGET must never serve stale plans.
+    # So is the in-kernel scope switch: a scoped plan compiles other
+    # kernels than an unscoped one.
     from .common import vmem_budget_bytes
     key = (_weights_key(weights), grid_shape, _dtype_key(dtype), t, hw,
            shard_key, backend, tile_m, tile_n, h_block, z_slab, z_block,
            w_tile, w_block, batch_key, vmem_budget_bytes(), interpret,
            None if compute_dtype is None else _dtype_key(compute_dtype),
-           bool(use_sparse_unit), boundary_key, registry.generation())
+           bool(use_sparse_unit), boundary_key, registry.generation(),
+           trace.kernel_scopes())
     return key, weights, grid_shape, interpret
 
 
@@ -586,63 +636,66 @@ def stencil_plan(
             return _CACHE[key]
         _STATS["misses"] += 1
 
-    t0 = time.perf_counter()
-    spec = spec_from_weights(weights)
-    # Selection prices the geometry the kernels will actually resolve for
-    # this grid (fused-regime halo t*r), so the read-amplification term in
-    # the decision matches the substrate that runs; tile_n keeps its
-    # historical 128 pricing default when unpinned.
-    from .common import resolve_substrate_geom
-    geom_px = resolve_substrate_geom(
-        grid_shape, t * spec.radius, np.dtype(dtype).itemsize,
-        tile_m, h_block, z_slab, z_block, w_tile, w_block)
-    decision = decide(
-        spec, t, dtype_bytes=np.dtype(dtype).itemsize, hw=hw,
-        tile_n=tile_n if tile_n is not None else 128,
-        strip_m=geom_px.strip_m, h_block=geom_px.h_block,
-        z_slab=geom_px.z_slab if geom_px.dim == 3 else None,
-        z_block=geom_px.z_block if geom_px.dim == 3 else None,
-        w_tile=geom_px.w_tile if geom_px.dim >= 2 else None,
-        w_block=geom_px.w_block if geom_px.dim >= 2 else None,
-        use_sparse_unit=use_sparse_unit,
-        boundary=modes,
-    )
-    exec_backend = backend if backend is not None else decision.backend
+    with jax.profiler.TraceAnnotation(trace.PLAN_BUILD):
+        t0 = time.perf_counter()
+        spec = spec_from_weights(weights)
+        # Selection prices the geometry the kernels will actually resolve for
+        # this grid (fused-regime halo t*r), so the read-amplification term in
+        # the decision matches the substrate that runs; tile_n keeps its
+        # historical 128 pricing default when unpinned.
+        from .common import resolve_substrate_geom
+        geom_px = resolve_substrate_geom(
+            grid_shape, t * spec.radius, np.dtype(dtype).itemsize,
+            tile_m, h_block, z_slab, z_block, w_tile, w_block)
+        decision = decide(
+            spec, t, dtype_bytes=np.dtype(dtype).itemsize, hw=hw,
+            tile_n=tile_n if tile_n is not None else 128,
+            strip_m=geom_px.strip_m, h_block=geom_px.h_block,
+            z_slab=geom_px.z_slab if geom_px.dim == 3 else None,
+            z_block=geom_px.z_block if geom_px.dim == 3 else None,
+            w_tile=geom_px.w_tile if geom_px.dim >= 2 else None,
+            w_block=geom_px.w_block if geom_px.dim >= 2 else None,
+            use_sparse_unit=use_sparse_unit,
+            boundary=modes,
+        )
+        exec_backend = backend if backend is not None else decision.backend
 
-    ctx = registry.PlanContext(
-        spec=spec, weights=weights, grid_shape=grid_shape,
-        dtype=np.dtype(dtype), t=t, tile_m=tile_m, tile_n=tile_n,
-        interpret=interpret, compute_dtype=compute_dtype, h_block=h_block,
-        z_slab=z_slab, z_block=z_block, w_tile=w_tile, w_block=w_block,
-        boundary=modes,
-    )
+        ctx = registry.PlanContext(
+            spec=spec, weights=weights, grid_shape=grid_shape,
+            dtype=np.dtype(dtype), t=t, tile_m=tile_m, tile_n=tile_n,
+            interpret=interpret, compute_dtype=compute_dtype, h_block=h_block,
+            z_slab=z_slab, z_block=z_block, w_tile=w_tile, w_block=w_block,
+            boundary=modes, name=exec_backend,
+            scopes=trace.kernel_scopes(),
+        )
 
-    halo_plan = None
-    resolved_mode = None
-    if mesh is None:
-        run = registry.get_backend(exec_backend).build(ctx)
-        if batch is not None:
-            from .common import fold_batch
-            resolved_mode = _resolve_batch_mode(batch_mode, interpret)
-            run = fold_batch(run, resolved_mode)
-        fn = jax.jit(run)
-    else:
-        fn, halo_plan = _build_distributed(
-            mesh, tuple(shard_spec), dist_mode, ctx, exec_backend)
+        halo_plan = None
+        resolved_mode = None
+        if mesh is None:
+            run = registry.get_backend(exec_backend).build(ctx)
+            if batch is not None:
+                from .common import fold_batch
+                resolved_mode = _resolve_batch_mode(batch_mode, interpret)
+                run = fold_batch(run, resolved_mode)
+            fn = jax.jit(run, compiler_options=trace.compiler_options(
+                ctx.scopes, interpret))
+        else:
+            fn, halo_plan = _build_distributed(
+                mesh, tuple(shard_spec), dist_mode, ctx, exec_backend)
 
-    plan = StencilPlan(
-        spec=spec, weights=weights, grid_shape=grid_shape,
-        dtype=np.dtype(dtype), t=t, hw=hw, backend=exec_backend,
-        decision=decision, fn=fn, tile_m=tile_m, tile_n=tile_n,
-        interpret=interpret, compute_dtype=compute_dtype, mesh=mesh,
-        shard_spec=None if shard_spec is None else tuple(shard_spec),
-        dist_mode=dist_mode if mesh is not None else None,
-        halo_plan=halo_plan, key=key,
-        build_time_s=time.perf_counter() - t0,
-        batch=None if batch is None else int(batch),
-        batch_mode=resolved_mode,
-        ctx=ctx, boundary=modes,
-    )
+        plan = StencilPlan(
+            spec=spec, weights=weights, grid_shape=grid_shape,
+            dtype=np.dtype(dtype), t=t, hw=hw, backend=exec_backend,
+            decision=decision, fn=fn, tile_m=tile_m, tile_n=tile_n,
+            interpret=interpret, compute_dtype=compute_dtype, mesh=mesh,
+            shard_spec=None if shard_spec is None else tuple(shard_spec),
+            dist_mode=dist_mode if mesh is not None else None,
+            halo_plan=halo_plan, key=key,
+            build_time_s=time.perf_counter() - t0,
+            batch=None if batch is None else int(batch),
+            batch_mode=resolved_mode,
+            ctx=ctx, boundary=modes,
+        )
     from repro.core.envutil import env_flag
     if audit if audit is not None else env_flag("REPRO_AUDIT"):
         _attach_audit(plan, ctx, exec_backend, decision, geom_px,
@@ -731,7 +784,9 @@ def _build_distributed(mesh, axis_names, dist_mode, ctx, exec_backend):
         mesh, axis_names, ctx.weights, t=ctx.t, mode=dist_mode,
         local_apply=local, boundary=ctx.boundary)
     sharding = NamedSharding(mesh, P(*axis_names))
-    fn = jax.jit(stepper, in_shardings=sharding, out_shardings=sharding)
+    fn = jax.jit(stepper, in_shardings=sharding, out_shardings=sharding,
+                 compiler_options=trace.compiler_options(ctx.scopes,
+                                                         ctx.interpret))
 
     r = ctx.radius
     halo_plan = {

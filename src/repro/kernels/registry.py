@@ -70,6 +70,11 @@ class PlanContext:
     #: Per-axis boundary spec (DESIGN.md §15), resolved by the plan layer
     #: to one mode per grid axis; ``None`` = all periodic (historical).
     boundary: Optional[Tuple[str, ...]] = None
+    #: The name the plan's kernels carry in the compiled program and the
+    #: profiler trace (the backend's); ``None`` leaves Pallas's default.
+    name: Optional[str] = None
+    #: Compile the in-kernel trace scopes in (``repro.core.trace``).
+    scopes: bool = False
 
     @property
     def radius(self) -> int:
@@ -98,9 +103,11 @@ class PlanContext:
         return choose_tile(wid) if self.tile_n is None else min(self.tile_n, wid)
 
     def kernel_kwargs(self, geom: SubstrateGeom) -> dict:
-        """The substrate-geometry kwargs both strip kernels accept."""
+        """The substrate-geometry and trace kwargs the strip kernels
+        accept."""
         kw = dict(tile_m=geom.strip_m, h_block=geom.h_block,
-                  boundary=self.boundary)
+                  boundary=self.boundary, name=self.name,
+                  scopes=self.scopes)
         if geom.dim >= 2:
             kw.update(w_tile=geom.w_tile, w_block=geom.w_block)
         if geom.dim == 3:

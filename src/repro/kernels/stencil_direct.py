@@ -105,12 +105,15 @@ def stencil_direct(
     w_block: int = None,
     interpret: bool = False,
     boundary=None,
+    name: str = None,
+    scopes: bool = False,
 ) -> jax.Array:
     """``t`` fused time steps of an N-D stencil, per-axis boundaries.
 
     ``boundary`` is a per-axis mode spec (DESIGN.md §15: ``periodic`` |
     ``zero`` | ``reflect`` | ``replicate``; ``None`` = all periodic,
-    the historical behavior bit for bit).
+    the historical behavior bit for bit).  ``name`` and ``scopes`` are
+    the launch's trace name and in-kernel scopes (``common._launch``).
     ``weights``: host-side (2r+1)^d ndarray (zeros outside support); the
     grid rank must match ``weights.ndim`` (1, 2 or 3).  ``tile_m`` is the
     strip height and ``h_block`` the halo sub-block height; 3D grids add
@@ -138,7 +141,8 @@ def stencil_direct(
         hb = h_block if h_block in (None, 0) else 1
         y = stencil_direct(x[None, :], w[None, :], t=t, tile_m=1,
                            h_block=hb, w_tile=0, interpret=interpret,
-                           boundary=lift_boundary_1d(boundary))
+                           boundary=lift_boundary_1d(boundary),
+                           name=name, scopes=scopes)
         return y[0]
 
     modes = resolve_boundary(boundary, x.ndim)
@@ -161,9 +165,9 @@ def stencil_direct(
     if x.ndim == 3:
         return slab_substrate_call(compute, x, geom, halo, interpret,
                                    x_halo=x_halo if geom.w_tile else 0,
-                                   boundary=modes)
+                                   boundary=modes, name=name, scopes=scopes)
     return strip_substrate_call(compute, x, geom.strip_m, geom.h_block,
                                 halo, interpret, w_tile=geom.w_tile,
                                 w_block=geom.w_block,
                                 x_halo=x_halo if geom.w_tile else 0,
-                                boundary=modes)
+                                boundary=modes, name=name, scopes=scopes)

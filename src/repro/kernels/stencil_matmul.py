@@ -219,11 +219,15 @@ def stencil_matmul(
     interpret: bool = False,
     compute_dtype=None,
     boundary=None,
+    name: str = None,
+    scopes: bool = False,
 ) -> jax.Array:
     """``t`` stencil steps via banded MXU contractions, per-axis boundaries.
 
     ``boundary`` is a per-axis mode spec (DESIGN.md §15; ``None`` = all
-    periodic, the historical behavior bit for bit).
+    periodic, the historical behavior bit for bit).  ``name`` and
+    ``scopes`` are the launch's trace name and in-kernel scopes
+    (``common._launch``).
 
     N-D: 2D and 3D grids contract their flattened leading shift tuples
     against per-row banded operands; 1D grids route through the 2D
@@ -258,7 +262,8 @@ def stencil_matmul(
         y = stencil_matmul(x[None, :], w[None, :], t=t, tile_m=1,
                            tile_n=tile_n, h_block=hb, w_tile=0,
                            interpret=interpret, compute_dtype=compute_dtype,
-                           boundary=lift_boundary_1d(boundary))
+                           boundary=lift_boundary_1d(boundary),
+                           name=name, scopes=scopes)
         return y[0]
 
     modes = resolve_boundary(boundary, x.ndim)
@@ -291,9 +296,9 @@ def stencil_matmul(
         return slab_substrate_call(compute, x, geom, halo, interpret,
                                    consts=(bands,),
                                    x_halo=x_halo if geom.w_tile else 0,
-                                   boundary=modes)
+                                   boundary=modes, name=name, scopes=scopes)
     return strip_substrate_call(compute, x, geom.strip_m, geom.h_block,
                                 halo, interpret, consts=(bands,),
                                 w_tile=geom.w_tile, w_block=geom.w_block,
                                 x_halo=x_halo if geom.w_tile else 0,
-                                boundary=modes)
+                                boundary=modes, name=name, scopes=scopes)

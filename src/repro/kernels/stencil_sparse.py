@@ -207,6 +207,8 @@ def stencil_sparse_matmul(
     interpret: bool = False,
     compute_dtype=None,
     boundary=None,
+    name: str = None,
+    scopes: bool = False,
 ) -> jax.Array:
     """``t`` stencil steps via sparse-compacted MXU contractions.
 
@@ -227,7 +229,8 @@ def stencil_sparse_matmul(
                                   tile_n=tile_n, h_block=hb, w_tile=0,
                                   interpret=interpret,
                                   compute_dtype=compute_dtype,
-                                  boundary=lift_boundary_1d(boundary))
+                                  boundary=lift_boundary_1d(boundary),
+                                  name=name, scopes=scopes)
         return y[0]
 
     modes = resolve_boundary(boundary, x.ndim)
@@ -263,9 +266,9 @@ def stencil_sparse_matmul(
         return slab_substrate_call(compute, x, geom, halo, interpret,
                                    consts=(packed,),
                                    x_halo=x_halo if geom.w_tile else 0,
-                                   boundary=modes)
+                                   boundary=modes, name=name, scopes=scopes)
     return strip_substrate_call(compute, x, geom.strip_m, geom.h_block,
                                 halo, interpret, consts=(packed,),
                                 w_tile=geom.w_tile, w_block=geom.w_block,
                                 x_halo=x_halo if geom.w_tile else 0,
-                                boundary=modes)
+                                boundary=modes, name=name, scopes=scopes)

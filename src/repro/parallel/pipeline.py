@@ -11,7 +11,7 @@ Cost model (surfaces in the §Roofline collective term): per tick one
 boundary activation crosses the pod link; bubble fraction = (S-1)/(M+S-1).
 
 This is the optional large-scale alternative to folding ``pod`` into data
-parallelism; ``launch/dryrun.py --arch glm4-9b-pp`` exercises it.
+parallelism.
 """
 from __future__ import annotations
 

@@ -37,6 +37,11 @@ rejects non-periodic specs.
 ``local_apply`` is pluggable so the local update can run on the Pallas VPU
 or MXU kernels (see repro.kernels.ops) -- the selector chooses per the
 paper's criteria.
+
+Trace scopes (``repro.core.trace``): the halo exchange and its assembly
+(:func:`_extend`) run under ``repro.dist.exchange`` and the Pallas local
+apply (row pad, kernel call, interior slice) under ``repro.dist.local``;
+both reach the ``op_name`` of every device op they cover.
 """
 from __future__ import annotations
 
@@ -46,6 +51,8 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+
+from repro.core import trace
 
 from .boundary import PAD_MODE, is_periodic, resolve_boundary
 from .reference import _offsets
@@ -157,15 +164,17 @@ def _extend(x: jax.Array, radius: int, dim_axis_names: Sequence[Optional[str]],
     maybe_fail("halo")
     if modes is None:
         modes = ("periodic",) * len(dim_axis_names)
-    for dim, axis_name in enumerate(dim_axis_names):
-        if axis_name is None:
-            pad = [(0, 0)] * x.ndim
-            pad[dim] = (radius, radius)
-            x = jnp.pad(x, pad, mode=PAD_MODE[modes[dim]])
-        else:
-            x = _halo_exchange_dim(x, dim, radius, axis_name)
-            if modes[dim] != "periodic":
-                x = _mask_edge_shards(x, dim, radius, modes[dim], axis_name)
+    with jax.named_scope(trace.DIST_EXCHANGE):
+        for dim, axis_name in enumerate(dim_axis_names):
+            if axis_name is None:
+                pad = [(0, 0)] * x.ndim
+                pad[dim] = (radius, radius)
+                x = jnp.pad(x, pad, mode=PAD_MODE[modes[dim]])
+            else:
+                x = _halo_exchange_dim(x, dim, radius, axis_name)
+                if modes[dim] != "periodic":
+                    x = _mask_edge_shards(x, dim, radius, modes[dim],
+                                          axis_name)
     return x
 
 
@@ -464,6 +473,10 @@ def pallas_local_apply(
     import numpy as _np
 
     def local_apply(xe, w, steps):
+        with jax.named_scope(trace.DIST_LOCAL):
+            return _apply(xe, w, steps)
+
+    def _apply(xe, w, steps):
         from repro.kernels.common import sublane_tile
         from repro.kernels.plan import stencil_plan  # deferred: avoid cycle
 

@@ -10,7 +10,10 @@ process may load the TPU library, and the test workers each import this
 file.  The persistent compilation cache stays off around the compiles --
 an entry written for a described chip cannot be read back here.
 """
+import base64
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import trace
 from repro.kernels import stencil_plan
 from repro.kernels.common import check_tpu_tiling, resolve_substrate_geom
 from repro.stencil import StencilSpec, make_weights
@@ -178,3 +182,65 @@ def test_compiled_kernels_trace_no_unlowerable_primitive(backend):
     prims = _primitives(closed.jaxpr, set())
     assert "pallas_call" in prims
     assert not prims & {"rev", "optimization_barrier"}, prims
+
+
+@pytest.mark.parametrize("backend", STRIP_BACKENDS + FOILS)
+def test_kernel_is_named_after_its_backend(one_chip, backend):
+    """Every custom call of the compiled plan carries the backend's name
+    (``%fused_direct.1 = ... custom-call``), so the profiler trace and
+    the benchmark's breakdown name each kernel by its backend."""
+    _, text = _compile(one_chip, StencilSpec("box", 2, 1), (512, 1024),
+                       jnp.float32, _depth(backend), backend=backend)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{backend}(\.\d+)? = ", line), line
+
+
+#: The serialized Mosaic body of each kernel in a lowered program.
+_BODY = re.compile(r"body\\22: \\22([A-Za-z0-9+/=]+)\\22")
+SCOPES = (trace.SUBSTRATE_ASSEMBLE.encode(), trace.KERNEL_COMPUTE.encode())
+
+
+def _scoped_plan(one_chip, backend, spec, grid):
+    """A compiled-mode plan and the Mosaic bodies of its kernels."""
+    w = make_weights(spec, seed=0)
+    plan = stencil_plan(w, grid, jnp.float32, 2, backend=backend,
+                        interpret=False, use_cache=False)
+    arg = jax.ShapeDtypeStruct(grid, jnp.float32, sharding=one_chip)
+    lowered = plan.fn.lower(arg)
+    bodies = [base64.b64decode(b) for b in _BODY.findall(lowered.as_text())]
+    assert bodies, "no Mosaic body in the lowered program"
+    return plan, bodies, lowered
+
+
+@pytest.mark.parametrize("backend, spec, grid", [
+    ("fused_direct", StencilSpec("box", 2, 1), (512, 1024)),
+    ("fused_direct_wholestrip", StencilSpec("box", 2, 1), (512, 1024)),
+    ("fused_matmul_reuse", StencilSpec("star", 3, 1), (32, 64, 256)),
+])
+def test_kernel_scopes_compile_in_only_when_on(one_chip, backend, spec,
+                                               grid):
+    """Off, the kernel is byte-identical to one built before the switch
+    was touched and holds no trace op; on, it holds both scopes (as
+    ``tpu.trace_start``) and compiles; the two plans never share a cache
+    key."""
+    assert trace.kernel_scopes() is False
+    built = []
+    # One call site for all three builds: the bodies carry source
+    # locations, which must not differ for another reason.
+    for switch in (contextlib.nullcontext(), trace.kernel_scopes_on(),
+                   contextlib.nullcontext()):
+        with switch:
+            built.append(_scoped_plan(one_chip, backend, spec, grid))
+    (plain, before, _), (scoped, on, lowered), (again, after, _) = built
+    assert after == before
+    for body in before:
+        assert b"trace_start" not in body
+        assert not any(name in body for name in SCOPES)
+    for body in on:
+        assert b"trace_start" in body
+        assert all(name in body for name in SCOPES)
+    assert scoped.key != plain.key and again.key == plain.key
+    assert "tpu_custom_call" in lowered.compile().as_text()
