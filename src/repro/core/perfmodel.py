@@ -46,6 +46,9 @@ class HardwareSpec:
     ``p_matrix``  -- matrix unit (Tensor Core / TPU MXU)
     ``p_sparse``  -- sparse matrix unit ceiling (SpTC); None if absent
     ``bandwidth`` -- main-memory (HBM) bandwidth
+    ``f32_matrix_passes`` -- passes of the matrix unit one f32 contraction
+    takes (1 where ``p_matrix`` is already the f32 peak); pricing reads
+    the matrix peak through :func:`matrix_peak`
     """
 
     name: str
@@ -53,6 +56,7 @@ class HardwareSpec:
     p_matrix: float
     bandwidth: float
     p_sparse: Optional[float] = None
+    f32_matrix_passes: int = 1
 
     @property
     def ridge_vector(self) -> float:
@@ -61,6 +65,8 @@ class HardwareSpec:
 
     @property
     def ridge_matrix(self) -> float:
+        """Ridge point at the nominal ``p_matrix`` (the paper's Table 3);
+        the pricers take theirs at :func:`matrix_peak` of the dtype."""
         return self.p_matrix / self.bandwidth
 
     @property
@@ -87,11 +93,16 @@ A100_FLOAT = HardwareSpec(
 # throughput is not separately published; 197/16 ~= 12.3 TFLOP/s is the
 # vector-lane estimate we expose as a *parameter* (it plays the paper's
 # P_CU role, and every criterion below takes it from the HardwareSpec).
+# The MXU multiplies bf16: an f32 contraction at the Precision.HIGHEST the
+# banded kernels ask for (stencil_matmul.banded_dot) "performs float32
+# computations in 6 bfloat16" passes (jax.lax.Precision docs), so f32
+# operands see 197/6 TFLOP/s.
 TPU_V5E_BF16 = HardwareSpec(
     "TPU v5e (bf16)", p_vector=197e12 / 16, p_matrix=197e12, bandwidth=819e9,
     # No sparse MXU.  The int8 MXU ceiling (394 TOP/s) answers the same
     # "raised ceiling" design question for quantized stencils (DESIGN.md §8).
     p_sparse=None,
+    f32_matrix_passes=6,
 )
 TPU_V5E_INT8_CEILING = dataclasses.replace(
     TPU_V5E_BF16, name="TPU v5e (bf16 + int8 ceiling)", p_sparse=394e12
@@ -103,6 +114,15 @@ HARDWARE_BY_DEVICE_KIND = {
                     "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
                     "bf16, 16 GB HBM at 819 GB/s per chip"),
 }
+
+
+def matrix_peak(hw: HardwareSpec, dtype_bytes: int) -> float:
+    """Matrix-unit peak (FLOP/s) for contractions on ``dtype_bytes``-wide
+    operands: ``p_matrix`` divided by the passes one f32 contraction
+    takes where the unit runs f32 as multi-pass bf16, else ``p_matrix``.
+    Every pricing of the matrix unit reads its peak here."""
+    passes = hw.f32_matrix_passes if dtype_bytes == 4 else 1
+    return hw.p_matrix / passes
 
 
 def hardware_for(device) -> HardwareSpec:
@@ -390,11 +410,12 @@ def perf_vector(w: StencilWorkload, hw: HardwareSpec) -> UnitPerf:
 
 
 def perf_matrix(w: StencilWorkload, hw: HardwareSpec, sparsity: float) -> UnitPerf:
+    peak = matrix_peak(hw, w.dtype_bytes)
     i = w.intensity_matrix(sparsity)
-    raw = attainable(hw.p_matrix, hw.bandwidth, i)
+    raw = attainable(peak, hw.bandwidth, i)
     actual = (sparsity / w.alpha) * raw
     return UnitPerf("matrix", i, raw, actual,
-                    bound_state(hw.p_matrix, hw.bandwidth, i), hw.ridge_matrix)
+                    bound_state(peak, hw.bandwidth, i), peak / hw.bandwidth)
 
 
 def perf_matrix_reuse(w: StencilWorkload, hw: HardwareSpec, sparsity: float,
@@ -408,12 +429,13 @@ def perf_matrix_reuse(w: StencilWorkload, hw: HardwareSpec, sparsity: float,
     ``sparsity`` is the scheme's S at the base radius r (the per-step banded
     operand), NOT the monolithic S at radius t*r.
     """
+    peak = matrix_peak(hw, w.dtype_bytes)
     i = w.intensity_matrix_reuse(sparsity, strip_m, z_slab, w_tile)
-    raw = attainable(hw.p_matrix, hw.bandwidth, i)
+    raw = attainable(peak, hw.bandwidth, i)
     beta = reuse_beta(w.spec, w.t, strip_m, z_slab, w_tile)
     actual = (sparsity / beta) * raw
     return UnitPerf("matrix_reuse", i, raw, actual,
-                    bound_state(hw.p_matrix, hw.bandwidth, i), hw.ridge_matrix)
+                    bound_state(peak, hw.bandwidth, i), peak / hw.bandwidth)
 
 
 def perf_sparse_matrix(w: StencilWorkload, hw: HardwareSpec, sparsity: float) -> UnitPerf:
@@ -427,12 +449,14 @@ def perf_sparse_matrix(w: StencilWorkload, hw: HardwareSpec, sparsity: float) ->
                     bound_state(hw.p_sparse, hw.bandwidth, i), hw.ridge_sparse)
 
 
-def _sparse_peak(hw: HardwareSpec) -> float:
+def _sparse_peak(hw: HardwareSpec, dtype_bytes: int) -> float:
     """Ceiling of the band-compacted contraction: the sparse unit where
-    one exists (A100 SpTC), else the plain MXU -- compaction's
-    effective-FLOP reduction is real on any matrix unit (it shrinks the
-    executed K-dimension; no special hardware required)."""
-    return hw.p_matrix if hw.p_sparse is None else hw.p_sparse
+    one exists (A100 SpTC), else the plain MXU at the operands' width --
+    compaction's effective-FLOP reduction is real on any matrix unit (it
+    shrinks the executed K-dimension; no special hardware required)."""
+    if hw.p_sparse is None:
+        return matrix_peak(hw, dtype_bytes)
+    return hw.p_sparse
 
 
 def perf_sparse_banded(w: StencilWorkload, hw: HardwareSpec, sparsity: float,
@@ -449,7 +473,7 @@ def perf_sparse_banded(w: StencilWorkload, hw: HardwareSpec, sparsity: float,
     box kernels compact to kept = 1 and never profit).
     """
     _check_kept(kept)
-    peak = _sparse_peak(hw)
+    peak = _sparse_peak(hw, w.dtype_bytes)
     i = w.intensity_sparse_matrix(sparsity, kept, overhead)
     raw = attainable(peak, hw.bandwidth, i)
     actual = (sparsity / (w.alpha * kept * (1.0 + overhead))) * raw
@@ -466,7 +490,7 @@ def perf_sparse_banded_reuse(w: StencilWorkload, hw: HardwareSpec,
     reuse pipeline (alpha=1, dim-aware beta) on the compacted operand.
     ``sparsity`` and ``kept`` are both at the BASE radius r."""
     _check_kept(kept)
-    peak = _sparse_peak(hw)
+    peak = _sparse_peak(hw, w.dtype_bytes)
     i = w.intensity_sparse_matrix_reuse(sparsity, kept, overhead,
                                         strip_m, z_slab, w_tile)
     raw = attainable(peak, hw.bandwidth, i)
@@ -517,7 +541,7 @@ def compare(
         (Bound.COMPUTE, Bound.COMPUTE): Scenario.CB_CB,
     }[(v.bound, m.bound)]
     speedup = m.actual_flops / v.actual_flops
-    p_mat = hw.p_sparse if use_sparse_unit else hw.p_matrix
+    p_mat = hw.p_sparse if use_sparse_unit else matrix_peak(hw, w.dtype_bytes)
     limit = sparsity * p_mat / hw.p_vector
     return Comparison(
         workload=w, hardware=hw, sparsity=sparsity, vector=v, matrix=m,

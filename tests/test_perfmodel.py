@@ -1,5 +1,6 @@
 """Validate the performance model against the paper's own numbers
 (Tables 2/3/4, §2.2.3, §4.1 scenario theorems)."""
+import dataclasses
 import math
 
 import pytest
@@ -238,3 +239,84 @@ class TestReuseRegime:
         w = pm.StencilWorkload(B21, 1, 4)
         S = pm.sparsity_banded(1, 128)
         assert w.flops_matrix_reuse(S) == pytest.approx(w.flops_matrix(S))
+
+
+class TestMatrixPeakByDtype:
+    """The matrix unit is priced at the peak of the dtype it contracts:
+    the v5e's MXU runs an f32 contraction at Precision.HIGHEST as six
+    bf16 passes, so f32 sees 197/6 TFLOP/s; bf16 and the A100 specs
+    (whose peaks are already their float peaks) price as before."""
+
+    V5E = pm.TPU_V5E_BF16
+
+    @staticmethod
+    def _decide(spec, grid, t, dtype_bytes, hw=pm.TPU_V5E_BF16):
+        from repro.kernels.common import resolve_substrate_geom
+        from repro.kernels.plan import decide
+        g = resolve_substrate_geom(grid, t * spec.radius, dtype_bytes)
+        three = spec.dim == 3
+        return decide(spec, t, dtype_bytes, hw, strip_m=g.strip_m,
+                      h_block=g.h_block,
+                      z_slab=g.z_slab if three else None,
+                      z_block=g.z_block if three else None,
+                      w_tile=g.w_tile, w_block=g.w_block)
+
+    def test_matrix_peak_per_dtype(self):
+        assert self.V5E.f32_matrix_passes == 6
+        assert pm.matrix_peak(self.V5E, 2) == 197e12
+        assert pm.matrix_peak(self.V5E, 4) == 197e12 / 6
+        assert pm.matrix_peak(pm.A100_FLOAT, 4) == 156e12
+        assert pm.matrix_peak(pm.A100_DOUBLE, 8) == 19.5e12
+
+    def test_star3d_f32_leaves_the_mxu_by_a_margin(self):
+        """Star-3D1R f32 1024^3 t=2 at its resolved geometry: every unit
+        was memory-bound at the bf16 peak and the MXU won a rounding tie;
+        at six passes the VPU wins by more than 2x."""
+        d = self._decide(StencilSpec("star", 3, 1), (1024,) * 3, 2, 4)
+        assert d.backend == "fused_direct"
+        assert d.candidates["fused_matmul_reuse"] * 2 < \
+            d.candidates["fused_direct"]
+        assert d.scenario is pm.Scenario.MB_CB
+        assert "strictly worse (Eq. 16)" in d.reason
+        assert "read_amp=3.00" in d.reason
+
+    @pytest.mark.parametrize("grid", [(10240, 10240), (10248, 10248),
+                                      (256, 256)])
+    def test_box2d_f32_picks_unchanged(self, grid):
+        d = self._decide(B21, grid, 4, 4)
+        assert d.backend == "fused_direct"
+
+    @pytest.mark.parametrize("spec,grid,t", [
+        (B21, (10240, 10240), 4), (B21, (256, 256), 1),
+        (S21, (2048, 300), 2), (StencilSpec("star", 3, 1), (1024,) * 3, 2),
+        (B31, (64, 64, 256), 3), (StencilSpec("box", 1, 2), (4096,), 4),
+    ])
+    def test_bf16_pricing_is_the_single_pass_pricing(self, spec, grid, t):
+        """bf16 decisions are byte-identical to those of a spec whose f32
+        contractions took one pass (the pricing before the pass count)."""
+        one_pass = dataclasses.replace(self.V5E, f32_matrix_passes=1)
+        new = self._decide(spec, grid, t, 2)
+        old = self._decide(spec, grid, t, 2, hw=one_pass)
+        assert new.candidates == old.candidates
+        assert new.backend == old.backend and new.reason == old.reason
+        w = pm.StencilWorkload(spec, t, 2)
+        s = pm.sparsity_banded(spec.radius, 128)
+        assert pm.perf_matrix(w, self.V5E, s) == \
+            pm.perf_matrix(w, one_pass, s)
+        assert pm.perf_matrix_reuse(w, self.V5E, s) == \
+            pm.perf_matrix_reuse(w, one_pass, s)
+
+    @pytest.mark.parametrize("hw,D", [(pm.A100_FLOAT, 4),
+                                      (pm.A100_DOUBLE, 8)])
+    @pytest.mark.parametrize("spec,t", [(B21, 1), (B21, 7), (B31, 3),
+                                        (S21, 5)])
+    def test_a100_prices_unchanged(self, hw, D, spec, t):
+        w = pm.StencilWorkload(spec, t, D)
+        s = 0.47
+        for p, i in ((pm.perf_matrix(w, hw, s), w.intensity_matrix(s)),
+                     (pm.perf_matrix_reuse(w, hw, s),
+                      w.intensity_matrix_reuse(s))):
+            assert p.raw_flops == min(hw.p_matrix, hw.bandwidth * i)
+            assert p.ridge == hw.ridge_matrix
+        c = pm.compare(w, hw, s)
+        assert c.sweet_spot_alpha_limit == s * hw.p_matrix / hw.p_vector

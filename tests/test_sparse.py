@@ -319,5 +319,8 @@ class TestSparsePerfModel:
         dn = pm.perf_matrix(w, hw, 0.5)
         if sp.bound is pm.Bound.COMPUTE and dn.bound is pm.Bound.COMPUTE:
             assert sp.actual_flops > dn.actual_flops
-        assert pm._sparse_peak(hw) == hw.p_sparse
-        assert pm._sparse_peak(pm.TPU_V5E_BF16) == pm.TPU_V5E_BF16.p_matrix
+        assert pm._sparse_peak(hw, 4) == hw.p_sparse
+        # MXU-only parts price the compaction at the operands' matrix peak
+        v5e = pm.TPU_V5E_BF16
+        assert pm._sparse_peak(v5e, 2) == v5e.p_matrix
+        assert pm._sparse_peak(v5e, 4) == pm.matrix_peak(v5e, 4)

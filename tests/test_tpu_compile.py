@@ -244,3 +244,17 @@ def test_kernel_scopes_compile_in_only_when_on(one_chip, backend, spec,
         assert all(name in body for name in SCOPES)
     assert scoped.key != plain.key and again.key == plain.key
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("grid", [GRID_3D, (1024, 1024, 1024)],
+                         ids=["512^3", "1024^3"])
+def test_star3d_f32_auto_plan_compiles_on_the_vpu(one_chip, grid):
+    """Star-3D1R f32 at t=2 (the 3D step cell's shape): an f32 contraction
+    takes six bf16 MXU passes, so the auto plan runs the VPU's
+    ``fused_direct`` -- by a margin, not a rounding tie -- and compiles."""
+    plan, text = _compile(one_chip, StencilSpec("star", 3, 1), grid,
+                          jnp.float32, 2)
+    assert plan.backend == "fused_direct"
+    assert plan.decision.candidates["fused_matmul_reuse"] * 2 < \
+        plan.decision.candidates["fused_direct"]
+    assert "fused_direct" in text
