@@ -258,3 +258,15 @@ def test_star3d_f32_auto_plan_compiles_on_the_vpu(one_chip, grid):
     assert plan.decision.candidates["fused_matmul_reuse"] * 2 < \
         plan.decision.candidates["fused_direct"]
     assert "fused_direct" in text
+
+
+def test_box2d3r_f32_auto_plan_compiles_direct(one_chip):
+    """Box-2D3R (49 taps) f32 at 10240^2, t=1 (the high-radius box cell's
+    shape): the auto plan runs the unfused VPU kernel ``direct`` on the
+    2D strip substrate and compiles at the published width."""
+    plan, text = _compile(one_chip, StencilSpec("box", 2, 3), GRID_2D,
+                          jnp.float32, 1)
+    assert plan.backend == "direct"
+    assert "Eq. 16" in plan.decision.reason
+    assert "strip_m=32, h_block=8" in plan.decision.reason
+    assert re.search(r"%direct(\.\d+)? = ", text)
