@@ -92,6 +92,18 @@ def _geometry(plan) -> str:
                  if "substrate" in part), "")
 
 
+def _column_shifts(plan) -> str:
+    """The column-shift form (``roll`` or ``slice``) the plan's VPU tap
+    sums trace, as its decision record states it; ``-`` where the plan
+    runs no VPU tap sum, or its record is of another unit's pick."""
+    from repro.kernels.registry import candidate_units
+    if candidate_units().get(plan.backend) != "vector":
+        return "-"
+    return next((part.split("=")[1].strip() for part in
+                 plan.decision.reason.split("|")
+                 if "column shifts=" in part), "-")
+
+
 def _check_plan(plan, requested, interpret) -> None:
     """Plans must resolve the expected interpret mode (compiled on the
     chip) and run the backend asked for, or the one auto selected."""
@@ -133,6 +145,7 @@ def _step_plans(name, spec, shape, t, n_steps, backends, interpret=None,
         err = _max_err(y, ref)
         rows.append({
             "phase": name, "backend": plan.backend,
+            "column_shifts": _column_shifts(plan),
             "requested": backend or "auto", "interpret": plan.interpret,
             "grid": list(shape), "t": t, "steps": t * n_steps,
             "boundary": "periodic" if boundary is None else boundary,
@@ -173,7 +186,9 @@ def phase_substrates(n: int = 2048, t: int = 4, interpret=None, hw=None):
     err = _max_err(a, b)
     _check(err <= F32_TOL, f"sub-blocked vs whole-strip max diff {err}")
     return [{"phase": "b.substrates", "backend": "fused_direct",
-             "foil": "fused_direct_wholestrip", "interpret": plan.interpret,
+             "foil": "fused_direct_wholestrip",
+             "column_shifts": _column_shifts(plan),
+             "interpret": plan.interpret,
              "grid": [n, n], "t": t, "max_err": err, "tol": F32_TOL,
              "bitwise": bool((a == b).all()),
              "setup_s": time.perf_counter() - t0}]
@@ -220,6 +235,7 @@ def phase_serving(n: int = 256, requests: int = 256, window: int = 8,
            f"{stats['failed']} failed")
     _check(err <= F32_TOL, f"serving max err {err} > {F32_TOL}")
     return [{"phase": "e.serving", "backend": plans[0].backend,
+             "column_shifts": _column_shifts(plans[0]),
              "batch_mode": plans[0].batch_mode,
              "interpret": plans[0].interpret, "grid": [n, n], "t": t,
              "requests": requests, "responded": stats["responded"],
@@ -266,7 +282,9 @@ def phase_four_chips(n: int = 20480, t: int = 4, n_steps: int = 1,
         err = _max_err(y, jax.device_put(ref, y.sharding))
         rows.append({"phase": "f.four_chips", "mode": mode,
                      "mesh": list(mesh_shape), "shard_spec": list(spec),
-                     "backend": plan.backend, "interpret": plan.interpret,
+                     "backend": plan.backend,
+                     "column_shifts": _column_shifts(plan),
+                     "interpret": plan.interpret,
                      "grid": [n, n], "t": t, "steps": t * n_steps,
                      "local": list(plan.halo_plan["local_shape"]),
                      "max_err": err, "tol": F32_TOL,

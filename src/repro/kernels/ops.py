@@ -150,4 +150,5 @@ def explain(
                   tile_n=tile_n, strip_m=strip_m, h_block=h_block,
                   z_slab=z_slab, z_block=z_block,
                   w_tile=w_tile, w_block=w_block,
-                  use_sparse_unit=use_sparse_unit, boundary=boundary)
+                  use_sparse_unit=use_sparse_unit, boundary=boundary,
+                  weights=weights)

@@ -33,6 +33,7 @@ engine builds and fetches plans from worker threads.
 """
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import threading
 import time
@@ -77,6 +78,7 @@ def decide(
     w_block: Optional[int] = None,
     use_sparse_unit: bool = False,
     boundary: BoundaryLike = None,
+    weights=None,
 ) -> Decision:
     """THE decision path: plan building, ``stencil_apply(backend="auto")``
     and ``ops.explain`` all consult this one function, so they can never
@@ -86,13 +88,27 @@ def decide(
     (DESIGN.md §10; ``None``/0 = full width); ``use_sparse_unit`` admits
     the sparse-compacted backends as priced candidates (DESIGN.md §14);
     ``boundary`` (DESIGN.md §15) is recorded in the decision's reason --
-    the in-kernel fills are FLOP-free, so it never moves the pricing."""
-    return select_backend(spec, t, dtype_bytes=dtype_bytes, hw=hw,
-                          tile_n=tile_n, strip_m=strip_m, h_block=h_block,
-                          z_slab=z_slab, z_block=z_block,
-                          w_tile=w_tile, w_block=w_block,
-                          use_sparse_unit=use_sparse_unit,
-                          boundary=boundary)
+    the in-kernel fills are FLOP-free, so it never moves the pricing.
+    A VPU tap-sum pick also records the column-shift form its kernel
+    traces (``stencil_direct.column_shift_form`` of ``weights``, else of
+    the spec's own Jacobi weights): ``| column shifts=roll`` or
+    ``slice``."""
+    decision = select_backend(spec, t, dtype_bytes=dtype_bytes, hw=hw,
+                              tile_n=tile_n, strip_m=strip_m,
+                              h_block=h_block, z_slab=z_slab,
+                              z_block=z_block, w_tile=w_tile,
+                              w_block=w_block,
+                              use_sparse_unit=use_sparse_unit,
+                              boundary=boundary)
+    if decision.backend not in ("direct", "fused_direct"):
+        return decision
+    from .stencil_direct import column_shift_form
+    mode_x = resolve_boundary(boundary, spec.dim)[-1]
+    form = column_shift_form(
+        jacobi_weights(spec) if weights is None else weights, mode_x,
+        wrap_x=not w_tile)
+    return dataclasses.replace(
+        decision, reason=f"{decision.reason} | column shifts={form}")
 
 
 class StencilPlan:
@@ -656,7 +672,7 @@ def stencil_plan(
             w_tile=geom_px.w_tile if geom_px.dim >= 2 else None,
             w_block=geom_px.w_block if geom_px.dim >= 2 else None,
             use_sparse_unit=use_sparse_unit,
-            boundary=modes,
+            boundary=modes, weights=weights,
         )
         exec_backend = backend if backend is not None else decision.backend
 

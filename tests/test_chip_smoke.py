@@ -30,6 +30,7 @@ def test_phase_2d_tiny():
     rows = _ok(chip_smoke.phase_2d(n=64, t=2, n_steps=2, interpret=True), 3)
     assert [r["requested"] for r in rows] == \
         ["auto", "fused_direct", "fused_matmul_reuse"]
+    assert [r["column_shifts"] for r in rows] == ["roll", "roll", "-"]
 
 
 def test_phase_substrates_tiny():
@@ -38,7 +39,8 @@ def test_phase_substrates_tiny():
 
 
 def test_phase_3d_tiny():
-    _ok(chip_smoke.phase_3d(n=16, interpret=True), 1)
+    (row,) = _ok(chip_smoke.phase_3d(n=16, interpret=True), 1)
+    assert row["column_shifts"] == "slice"
 
 
 def test_phase_boundary_tiny():

@@ -156,14 +156,20 @@ def test_pinned_illegal_geometry_raises_only_when_compiling():
     assert plan.interpret is True
 
 
-def _primitives(jaxpr, out):
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in its
+    equations' parameters (kernel bodies, branches), depth first."""
     for eqn in jaxpr.eqns:
-        out.add(eqn.primitive.name)
+        yield eqn
         for v in eqn.params.values():
             for item in v if isinstance(v, (tuple, list)) else (v,):
                 inner = getattr(item, "jaxpr", item)
                 if hasattr(inner, "eqns"):
-                    _primitives(inner, out)
+                    yield from _eqns(inner)
+
+
+def _primitives(jaxpr, out):
+    out.update(eqn.primitive.name for eqn in _eqns(jaxpr))
     return out
 
 
@@ -203,10 +209,10 @@ _BODY = re.compile(r"body\\22: \\22([A-Za-z0-9+/=]+)\\22")
 SCOPES = (trace.SUBSTRATE_ASSEMBLE.encode(), trace.KERNEL_COMPUTE.encode())
 
 
-def _scoped_plan(one_chip, backend, spec, grid):
+def _scoped_plan(one_chip, backend, spec, grid, t=2):
     """A compiled-mode plan and the Mosaic bodies of its kernels."""
     w = make_weights(spec, seed=0)
-    plan = stencil_plan(w, grid, jnp.float32, 2, backend=backend,
+    plan = stencil_plan(w, grid, jnp.float32, t, backend=backend,
                         interpret=False, use_cache=False)
     arg = jax.ShapeDtypeStruct(grid, jnp.float32, sharding=one_chip)
     lowered = plan.fn.lower(arg)
@@ -270,3 +276,34 @@ def test_box2d3r_f32_auto_plan_compiles_direct(one_chip):
     assert "Eq. 16" in plan.decision.reason
     assert "strip_m=32, h_block=8" in plan.decision.reason
     assert re.search(r"%direct(\.\d+)? = ", text)
+
+
+@pytest.mark.parametrize("spec, t", [(StencilSpec("box", 2, 1), 4),
+                                     (StencilSpec("box", 2, 3), 1)],
+                         ids=["box2d1r-t4", "box2d3r-t1"])
+def test_box_auto_plans_lower_with_a_lane_rotate(one_chip, spec, t):
+    """The box cells' auto plans at 10240^2 form their column operands by
+    lane rotates: the Mosaic module holds a rotate, and the decision
+    record says ``roll``."""
+    plan, bodies, _ = _scoped_plan(one_chip, None, spec, GRID_2D, t=t)
+    assert plan.backend in ("direct", "fused_direct")
+    assert plan.decision.reason.endswith("| column shifts=roll")
+    assert all(b"rotate" in body for body in bodies)
+
+
+def test_2x2_local_block_compiles_rolled(one_chip):
+    """The 2x2 cell's local kernel: a 10248^2 halo-extended block (not a
+    multiple of 128 wide), ``fused_direct`` at t=4, rolled, compiles."""
+    plan, text = _compile(one_chip, StencilSpec("box", 2, 1), (10248, 10248),
+                          jnp.float32, 4, backend="fused_direct")
+    assert plan.decision.reason.endswith("| column shifts=roll")
+    assert re.search(r"%fused_direct(\.\d+)? = ", text)
+
+
+def test_vmap_batch_fold_compiles_rolled(one_chip):
+    """A box batch fold under ``vmap``: the rolled kernel body compiles
+    with the batch grid axis in front."""
+    plan, _ = _compile(one_chip, StencilSpec("box", 2, 1), (256, 256),
+                       jnp.float32, 2, backend="fused_direct", batch=8,
+                       batch_mode="vmap")
+    assert plan.batch_mode == "vmap"
