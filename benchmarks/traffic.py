@@ -1,34 +1,36 @@
-"""Substrate HBM-traffic benchmark: seed 9-neighbor scheme vs whole-strip
-pipeline vs halo-row sub-blocked strips.
+"""Substrate HBM-traffic benchmark: the block-ring substrate at its auto
+halo blocks vs the same ring pinned to whole neighbor strips.
 
 The paper's whole argument is that stencils are memory-bound (I = K/D,
-Eq. 6), so the substrate's HBM traffic model IS the experiment: the seed
-scheme streamed nine full (tile, tile) blocks per output tile (9x read
-amplification); the whole-strip scheme loads three full-width strips (3x);
-the sub-blocked scheme (DESIGN.md §3) loads each strip's own h-row blocks
-plus ONE h-block per vertical neighbor (1 + 2h/strip_m, ~1.1-1.25x at the
-benchmark strips), with the horizontal periodic halo materialized in-VMEM
-for free in all strip schemes.
+Eq. 6), so the substrate's HBM traffic model IS the experiment.  Every
+kernel runs on one substrate (DESIGN.md §3): a ring of blocks per output
+strip, each strip's own h-row blocks plus ONE h-block per vertical
+neighbor (1 + 2h/strip_m, ~1.1-1.25x at the benchmark strips), with the
+horizontal periodic halo materialized in-VMEM for free.  Pinned to
+``h_block = strip_m`` the same ring reads three full strips per strip
+(3x, the whole-strip read pattern), and in 3D with ``z_block = z_slab``
+too, nine full slabs (9x, the whole-slab pattern); the
+``*_wholestrip`` columns are that pin's analytic read model.
 
-For Box/Star x r in {1,2,3} x t in {1,2,4,8} this emits, per substrate:
-  * neighbor-block loads issued per output tile/strip (9 vs 3 vs
-    strip_m/h + 2, analytic from the BlockSpec structure),
+For Box/Star x r in {1,2,3} x t in {1,2,4,8} this emits:
+  * block loads issued per output strip (strip_m/h + 2 vs 3 for the
+    pin, analytic from the BlockSpec structure),
   * per-step HBM read bytes (analytic, including the banded operand on the
     MXU paths) -- the ``read_bytes_step_*_subblocked`` columns show the
-    amplification falling from 3.0x to 1.125-1.25x for shallow halos
-    (halo <= strip_m/8, the whole BENCH_QUICK sweep), climbing back toward
-    3.0x only where t*r approaches the 32-row strip height,
+    amplification at 1.125-1.25x for shallow halos (halo <= strip_m/8,
+    the whole BENCH_QUICK sweep), climbing toward the pin's 3.0x only
+    where t*r approaches the 32-row strip height,
   * measured us/step of the Pallas kernels (interpret mode on CPU -- honest
-    relative numbers, labeled as such), VPU path and MXU path (seed
-    monolithic vs strip ``fused_matmul_reuse``), executed through compiled
-    ``stencil_plan`` objects so per-trial timing excludes selection, tile
-    sizing and weight composition -- plan-build time is recorded separately
+    relative numbers, labeled as such), VPU ``fused_direct`` and MXU
+    ``fused_matmul_reuse``, executed through compiled ``stencil_plan``
+    objects so per-trial timing excludes selection, tile sizing and
+    weight composition -- plan-build time is recorded separately
     (``plan_build_us_*`` in the JSON).
 
 The 3D halo-plane substrate (DESIGN.md §9) gets its own sweep
 (``cases_3d``): Box/Star-3D x r{1,2} x t{1,2} at fixed benchmark slab
-sizes, whole-slab foil (9x) vs sub-blocked halo planes
-((1 + 2h/strip_m)(1 + 2z_block/z_slab)x), with analytic
+sizes, the auto halo-plane blocks ((1 + 2h/strip_m)(1 + 2z_block/z_slab)x)
+vs the whole-slab pin (9x), with analytic
 ``read_bytes_step_*_{wholestrip,subblocked}`` columns and plan-timed
 us/step for the VPU and intermediate-reuse MXU paths.
 
@@ -44,13 +46,14 @@ dense reuse plan -- ``scripts/verify.sh`` gates on both.
 The column-tiled W substrate (DESIGN.md §10) gets the wide-grid sweep
 (``cases_wide``): a grid whose FULL-WIDTH strips exceed the VMEM budget
 (REPRO_VMEM_BUDGET pinned for the case, so the auto sizing genuinely
-escalates), whole-width 3-load foil (3x) vs the column-tiled substrate
-((1 + 2h/strip_m)(1 + 2w_block/w_tile)x), with the resolved
-(w_tile, w_block) recorded and ``scripts/verify.sh`` asserting the
-column-tiled amplification stays below the whole-width foil.
+escalates), the column-tiled substrate
+((1 + 2h/strip_m)(1 + 2w_block/w_tile)x) against the whole-width
+whole-strip pin's analytic 3x, with the resolved (w_tile, w_block)
+recorded and ``scripts/verify.sh`` asserting the column-tiled
+amplification stays below the pin's.
 
 Per-axis boundary modes (DESIGN.md §15) ride the sweep two ways: every
-row carries a ``boundary`` column (the legacy sweeps are all-periodic,
+row carries a ``boundary`` column (the other sweeps are all-periodic,
 the ``cases_boundary`` sweep times the sub-blocked VPU/MXU plans under
 zero/reflect/replicate/mixed specs with a mode-matched oracle check),
 and ``halo_overlap`` records the distributed overlap-vs-serialized
@@ -79,7 +82,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 from benchmarks.timing import CaseTimeout, case_budget, time_us
 from repro.core import events as guard_events
-from repro.kernels import common, legacy, plan_cache_stats, stencil_plan
+from repro.kernels import common, plan_cache_stats, stencil_plan
 from repro.kernels.common import (SubstrateGeom, choose_hblock,
                                   hbm_read_bytes_per_step_3d,
                                   resolve_substrate_geom,
@@ -87,12 +90,12 @@ from repro.kernels.common import (SubstrateGeom, choose_hblock,
 from repro.kernels.stencil_matmul import (band_sparsity, build_bands,
                                           build_bands_nd)
 from repro.kernels.stencil_sparse import compact_bands, kept_row_fraction
-from repro.stencil import StencilSpec, fuse_weights, make_weights
+from repro.stencil import StencilSpec, make_weights
 from repro.stencil.boundary import boundary_label, resolve_boundary
 from repro.stencil.reference import apply_stencil_steps
 
 N = 128            # grid edge (small: interpret-mode kernels on CPU)
-TILE = 32          # seed tile edge == strip height (fair per-cell VMEM)
+TILE = 32          # strip height of the 2D sweep
 SHAPES = ("box", "star")
 RADII = (1, 2, 3)
 DEPTHS = (1, 2, 4, 8)
@@ -102,10 +105,11 @@ QUICK_RADII = (1,)
 QUICK_DEPTHS = (1, 4)
 DTYPE_BYTES = 4
 #: 3D halo-plane substrate sweep (DESIGN.md §9): Box/Star-3D at the
-#: paper's Table 3 workloads, measured whole-slab foil (9x) vs sub-blocked
-#: ((1 + 2h/strip_m)(1 + 2z_block/z_slab)x).  Small grid + fixed
-#: (z_slab, strip_m) so interpret-mode timing stays honest and the
-#: analytic amplification is exact at the benchmark slab sizes.
+#: paper's Table 3 workloads, the whole-slab pin's analytic 9x vs the
+#: auto halo-plane blocks ((1 + 2h/strip_m)(1 + 2z_block/z_slab)x).
+#: Small grid + fixed (z_slab, strip_m) so interpret-mode timing stays
+#: honest and the analytic amplification is exact at the benchmark slab
+#: sizes.
 N3 = (16, 32, 32)      # (Z, H, W)
 SLAB3, STRIP3, TILE3 = 8, 16, 32
 CASES_3D = [(s, r, t) for s in SHAPES for r in (1, 2) for t in (1, 2)]
@@ -154,35 +158,28 @@ def _mxu_step_flops(w, tile_n: int, width: int, m_rows: int):
 def _case(shape: str, r: int, t: int, x) -> dict:
     spec = StencilSpec(shape, 2, r)
     w = make_weights(spec, seed=r)
-    wf = fuse_weights(w, t)
     halo = r * t                      # fused-regime vertical halo at TILE strips
     hb = choose_hblock(TILE, halo)
+    bands = build_bands(w.astype(np.float32), TILE).shape
 
-    bands_new = build_bands(w.astype(np.float32), TILE).shape
-    bands_old = build_bands(wf.astype(np.float32), TILE).shape
+    def reads(h_block, bands_shape=None):
+        # one fused launch advances t steps: per-step read traffic
+        return common.hbm_read_bytes_per_step(
+            (N, N), TILE, DTYPE_BYTES, bands_shape=bands_shape,
+            h_block=h_block) / t
 
     row = {
         "case": f"{spec.name}-t{t}", "shape": shape, "r": r, "t": t,
         "boundary": "periodic",
-        "loads_per_tile_old": len(legacy.NEIGHBOR_OFFSETS_2D),
-        "loads_per_tile_new": common.STRIP_NEIGHBOR_LOADS,
+        "loads_per_tile_wholestrip": TILE // TILE + 2,
         "loads_per_tile_subblocked": TILE // hb + 2,
         "h_block": hb,
+        "read_amp_wholestrip": substrate_read_amp(TILE, TILE),
         "read_amp_subblocked": substrate_read_amp(TILE, hb),
-        # one fused launch advances t steps: per-step read traffic
-        "read_bytes_step_direct_old": legacy.hbm_read_bytes_per_step(
-            (N, N), TILE, TILE, DTYPE_BYTES) / t,
-        "read_bytes_step_direct_new": common.hbm_read_bytes_per_step(
-            (N, N), TILE, DTYPE_BYTES) / t,
-        "read_bytes_step_direct_subblocked": common.hbm_read_bytes_per_step(
-            (N, N), TILE, DTYPE_BYTES, h_block=hb) / t,
-        "read_bytes_step_matmul_old": legacy.hbm_read_bytes_per_step(
-            (N, N), TILE, TILE, DTYPE_BYTES, bands_shape=bands_old) / t,
-        "read_bytes_step_matmul_new": common.hbm_read_bytes_per_step(
-            (N, N), TILE, DTYPE_BYTES, bands_shape=bands_new) / t,
-        "read_bytes_step_matmul_subblocked": common.hbm_read_bytes_per_step(
-            (N, N), TILE, DTYPE_BYTES, bands_shape=bands_new,
-            h_block=hb) / t,
+        "read_bytes_step_direct_wholestrip": reads(TILE),
+        "read_bytes_step_direct_subblocked": reads(hb),
+        "read_bytes_step_matmul_wholestrip": reads(TILE, bands),
+        "read_bytes_step_matmul_subblocked": reads(hb, bands),
     }
     # Star-vs-box sparsity sweep (DESIGN.md §14): element sparsity of the
     # banded operand, the achievable kept-row fraction S, and the per-step
@@ -198,27 +195,12 @@ def _case(shape: str, r: int, t: int, x) -> dict:
     # composition happen at build (accounted separately below), the plan's
     # jitted callable is what gets timed -- time_us's warmup still absorbs
     # trace+compile, so the timed iterations are steady-state execution with
-    # zero re-analysis.  Backends map the three substrates: the seed 9-tile
-    # foil registers as legacy_*, the whole-strip pipeline as
-    # *_wholestrip, and the default sub-blocked substrate as fused_direct /
-    # fused_matmul_reuse (all degenerate to the plain kernels at t=1).
+    # zero re-analysis (the fused regimes degenerate to the plain kernels
+    # at t=1).
     paths = {
-        "us_step_direct_old": stencil_plan(
-            w, x.shape, x.dtype, t, backend="legacy_direct",
-            tile_m=TILE, tile_n=TILE, interpret=True),
-        "us_step_direct_new": stencil_plan(
-            w, x.shape, x.dtype, t, backend="fused_direct_wholestrip",
-            tile_m=TILE, interpret=True),
         "us_step_direct_subblocked": stencil_plan(
             w, x.shape, x.dtype, t, backend="fused_direct",
             tile_m=TILE, h_block=hb, interpret=True),
-        # MXU paths: seed monolithic fusion vs strip intermediate reuse
-        "us_step_matmul_old": stencil_plan(
-            w, x.shape, x.dtype, t, backend="legacy_matmul",
-            tile_m=TILE, tile_n=TILE, interpret=True),
-        "us_step_matmul_new": stencil_plan(
-            w, x.shape, x.dtype, t, backend="fused_matmul_reuse_wholestrip",
-            tile_m=TILE, tile_n=TILE, interpret=True),
         "us_step_matmul_subblocked": stencil_plan(
             w, x.shape, x.dtype, t, backend="fused_matmul_reuse",
             tile_m=TILE, tile_n=TILE, h_block=hb, interpret=True),
@@ -241,7 +223,8 @@ def _case(shape: str, r: int, t: int, x) -> dict:
 
 
 def _case3d(shape: str, r: int, t: int, x3) -> dict:
-    """One 3D traffic case: whole-slab foil vs sub-blocked halo planes."""
+    """One 3D traffic case: auto halo-plane blocks vs the whole-slab pin
+    (analytic)."""
     spec = StencilSpec(shape, 3, r)
     w = make_weights(spec, seed=r)
     halo = r * t
@@ -249,15 +232,15 @@ def _case3d(shape: str, r: int, t: int, x3) -> dict:
     zb = choose_hblock(SLAB3, halo, 1)          # z is a leading axis
     sub = SubstrateGeom(dim=3, strip_m=STRIP3, h_block=hb,
                         z_slab=SLAB3, z_block=zb)
-    whole = SubstrateGeom(dim=3, strip_m=STRIP3, h_block=0,
-                          z_slab=SLAB3, z_block=0)
+    whole = SubstrateGeom(dim=3, strip_m=STRIP3, h_block=STRIP3,
+                          z_slab=SLAB3, z_block=SLAB3)
     bands = build_bands_nd(w.astype(np.float32), TILE3)[1].shape
 
     row = {
         "case": f"{spec.name}-t{t}", "shape": shape, "dim": 3, "r": r, "t": t,
         "boundary": "periodic",
         "z_slab": SLAB3, "strip_m": STRIP3, "h_block": hb, "z_block": zb,
-        "loads_per_cell_wholestrip": 9,
+        "loads_per_cell_wholestrip": 3 * 3,
         "loads_per_cell_subblocked": (SLAB3 // zb + 2) * (STRIP3 // hb + 2),
         "read_amp_wholestrip": whole.read_amp,
         "read_amp_subblocked": sub.read_amp,
@@ -279,14 +262,9 @@ def _case3d(shape: str, r: int, t: int, x3) -> dict:
 
     pins = dict(tile_m=STRIP3, z_slab=SLAB3, interpret=True)
     paths = {
-        "us_step_direct_wholestrip": stencil_plan(
-            w, N3, x3.dtype, t, backend="fused_direct_wholestrip", **pins),
         "us_step_direct_subblocked": stencil_plan(
             w, N3, x3.dtype, t, backend="fused_direct",
             h_block=hb, z_block=zb, **pins),
-        "us_step_matmul_wholestrip": stencil_plan(
-            w, N3, x3.dtype, t, backend="fused_matmul_reuse_wholestrip",
-            tile_n=TILE3, **pins),
         "us_step_matmul_subblocked": stencil_plan(
             w, N3, x3.dtype, t, backend="fused_matmul_reuse",
             tile_n=TILE3, h_block=hb, z_block=zb, **pins),
@@ -306,11 +284,11 @@ def _case3d(shape: str, r: int, t: int, x3) -> dict:
 
 
 def _case_wide(shape: str, r: int, t: int, xw) -> dict:
-    """One wide-grid case: whole-width 3-load foil vs the column-tiled
-    substrate that auto resolution picks when full width cannot fit the
-    (reduced) VMEM budget.  Per-step reads follow the three-factor
-    product (1 + 2h/strip_m)(1 + 2w_block/w_tile)·H·W·D vs the foil's 3x.
-    """
+    """One wide-grid case: the column-tiled substrate that auto
+    resolution picks when full width cannot fit the (reduced) VMEM
+    budget.  Per-step reads follow the three-factor product
+    (1 + 2h/strip_m)(1 + 2w_block/w_tile)·H·W·D vs the whole-width
+    whole-strip pin's analytic 3x."""
     spec = StencilSpec(shape, 2, r)
     w = make_weights(spec, seed=r)
     halo = r * t
@@ -329,11 +307,13 @@ def _case_wide(shape: str, r: int, t: int, xw) -> dict:
             "grid": list(N_WIDE), "vmem_budget": WIDE_BUDGET,
             "strip_m": geom.strip_m, "h_block": geom.h_block,
             "w_tile": geom.w_tile, "w_block": geom.w_block,
-            "read_amp_wholestrip": substrate_read_amp(geom.strip_m, 0),
+            "read_amp_wholestrip": substrate_read_amp(geom.strip_m,
+                                                      geom.strip_m),
             "read_amp_coltiled": geom.read_amp,
             "read_bytes_step_direct_wholestrip":
                 common.hbm_read_bytes_per_step(
-                    N_WIDE, geom.strip_m, DTYPE_BYTES) / t,
+                    N_WIDE, geom.strip_m, DTYPE_BYTES,
+                    h_block=geom.strip_m) / t,
             "read_bytes_step_direct_coltiled":
                 common.hbm_read_bytes_per_step(
                     N_WIDE, geom.strip_m, DTYPE_BYTES,
@@ -350,11 +330,6 @@ def _case_wide(shape: str, r: int, t: int, xw) -> dict:
         col = dict(h_block=geom.h_block, w_tile=geom.w_tile,
                    w_block=geom.w_block)
         paths = {
-            # the whole-width foil executes in interpret mode regardless
-            # of VMEM -- it is the analytic+timed foil, not a TPU claim
-            "us_step_direct_wholestrip": stencil_plan(
-                w, N_WIDE, xw.dtype, t, backend="fused_direct_wholestrip",
-                **pins),
             "us_step_direct_coltiled": stencil_plan(
                 w, N_WIDE, xw.dtype, t, backend="fused_direct",
                 **col, **pins),
@@ -573,55 +548,45 @@ def run() -> list[str]:
     rows_wide = [c for c in rows_wide if not c.get("timed_out")]
     rows_boundary = [c for c in rows_boundary if not c.get("timed_out")]
 
-    out = ["traffic.case,loads_old/new/sub,read_amp_direct_new,"
-           "read_amp_direct_sub,rdMB_step_mm_old,rdMB_step_mm_new,"
-           "rdMB_step_mm_sub,us_dir_old,us_dir_new,us_dir_sub,"
-           "us_mm_old,us_mm_new,us_mm_sub,us_mm_sparse,kept_S,"
-           "sparse_bitwise"]
-    grid_bytes = N * N * DTYPE_BYTES
+    out = ["traffic.case,loads_whole/sub,read_amp_whole,read_amp_sub,"
+           "rdMB_step_mm_whole,rdMB_step_mm_sub,us_dir_sub,us_mm_sub,"
+           "us_mm_sparse,kept_S,sparse_bitwise"]
     for c in rows:
-        amp_new = c["read_bytes_step_direct_new"] * c["t"] / grid_bytes
-        amp_sub = c["read_bytes_step_direct_subblocked"] * c["t"] / grid_bytes
         out.append(
-            f"traffic.{c['case']},{c['loads_per_tile_old']}/"
-            f"{c['loads_per_tile_new']}/{c['loads_per_tile_subblocked']},"
-            f"{amp_new:.2f}x,{amp_sub:.2f}x,"
-            f"{c['read_bytes_step_matmul_old']/2**20:.3f},"
-            f"{c['read_bytes_step_matmul_new']/2**20:.3f},"
+            f"traffic.{c['case']},{c['loads_per_tile_wholestrip']}/"
+            f"{c['loads_per_tile_subblocked']},"
+            f"{c['read_amp_wholestrip']:.2f}x,"
+            f"{c['read_amp_subblocked']:.2f}x,"
+            f"{c['read_bytes_step_matmul_wholestrip']/2**20:.3f},"
             f"{c['read_bytes_step_matmul_subblocked']/2**20:.3f},"
-            f"{c['us_step_direct_old']:.0f},{c['us_step_direct_new']:.0f},"
             f"{c['us_step_direct_subblocked']:.0f},"
-            f"{c['us_step_matmul_old']:.0f},{c['us_step_matmul_new']:.0f},"
             f"{c['us_step_matmul_subblocked']:.0f},"
             f"{c['us_step_matmul_sparse']:.0f},"
             f"{c['kept_row_fraction']:.4f},{c['sparse_bitwise_equal']}")
 
     out.append("traffic3d.case,read_amp_whole,read_amp_sub,"
-               "rdMB_step_mm_whole,rdMB_step_mm_sub,us_dir_whole,us_dir_sub,"
-               "us_mm_whole,us_mm_sub,us_mm_sparse,kept_S,sparse_bitwise")
+               "rdMB_step_mm_whole,rdMB_step_mm_sub,us_dir_sub,"
+               "us_mm_sub,us_mm_sparse,kept_S,sparse_bitwise")
     for c in rows3d:
         out.append(
             f"traffic3d.{c['case']},{c['read_amp_wholestrip']:.2f}x,"
             f"{c['read_amp_subblocked']:.2f}x,"
             f"{c['read_bytes_step_matmul_wholestrip']/2**20:.3f},"
             f"{c['read_bytes_step_matmul_subblocked']/2**20:.3f},"
-            f"{c['us_step_direct_wholestrip']:.0f},"
             f"{c['us_step_direct_subblocked']:.0f},"
-            f"{c['us_step_matmul_wholestrip']:.0f},"
             f"{c['us_step_matmul_subblocked']:.0f},"
             f"{c['us_step_matmul_sparse']:.0f},"
             f"{c['kept_row_fraction']:.4f},{c['sparse_bitwise_equal']}")
 
     out.append("trafficwide.case,w_tile/w_block,read_amp_whole,"
                "read_amp_coltiled,rdMB_step_dir_whole,rdMB_step_dir_col,"
-               "us_dir_whole,us_dir_col,us_mm_col")
+               "us_dir_col,us_mm_col")
     for c in rows_wide:
         out.append(
             f"trafficwide.{c['case']},{c['w_tile']}/{c['w_block']},"
             f"{c['read_amp_wholestrip']:.2f}x,{c['read_amp_coltiled']:.2f}x,"
             f"{c['read_bytes_step_direct_wholestrip']/2**20:.3f},"
             f"{c['read_bytes_step_direct_coltiled']/2**20:.3f},"
-            f"{c['us_step_direct_wholestrip']:.0f},"
             f"{c['us_step_direct_coltiled']:.0f},"
             f"{c['us_step_matmul_coltiled']:.0f}")
 

@@ -167,26 +167,30 @@ def phase_2d(n: int = 10240, t: int = 4, n_steps: int = 3, interpret=None,
 
 
 def phase_substrates(n: int = 2048, t: int = 4, interpret=None, hw=None):
-    """The sub-blocked ``fused_direct`` plan against its whole-strip foil:
+    """The auto ``fused_direct`` plan against the same backend with its
+    ring pinned to ``h_block = strip_m`` (three full strips per strip):
     both assemble the same halo-extended strips, so they should agree
     bit for bit; the check admits ``F32_TOL`` and reports whether they
     were bitwise equal."""
     from repro.core import perfmodel as pm
-    from repro.kernels import stencil_plan
+    from repro.kernels import resolve_substrate_geom, stencil_plan
 
     w, x = _weights(BOX2), _grid((n, n), seed=1)
     t0 = time.perf_counter()
+    strip_m = resolve_substrate_geom((n, n), t, 4).strip_m
     ys = {}
-    for backend in ("fused_direct", "fused_direct_wholestrip"):
-        plan = stencil_plan(w, (n, n), x.dtype, t, backend=backend,
-                            interpret=interpret, hw=hw or pm.TPU_V5E_BF16)
-        _check_plan(plan, backend, interpret)
-        ys[backend] = plan(x).block_until_ready()
+    for pins in ({}, dict(tile_m=strip_m, h_block=strip_m)):
+        plan = stencil_plan(w, (n, n), x.dtype, t, backend="fused_direct",
+                            interpret=interpret, hw=hw or pm.TPU_V5E_BF16,
+                            **pins)
+        _check_plan(plan, "fused_direct", interpret)
+        ys[bool(pins)] = plan(x).block_until_ready()
     a, b = ys.values()
     err = _max_err(a, b)
-    _check(err <= F32_TOL, f"sub-blocked vs whole-strip max diff {err}")
+    _check(err <= F32_TOL, f"auto vs h_block=strip_m max diff {err}")
     return [{"phase": "b.substrates", "backend": "fused_direct",
-             "foil": "fused_direct_wholestrip",
+             "pinned": f"h_block={strip_m}",
+             "geometry": _geometry(plan),
              "column_shifts": _column_shifts(plan),
              "interpret": plan.interpret,
              "grid": [n, n], "t": t, "max_err": err, "tol": F32_TOL,

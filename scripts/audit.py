@@ -29,7 +29,7 @@ from repro.stencil.spec import StencilSpec  # noqa: E402
 from repro.stencil.weights import jacobi_weights  # noqa: E402
 
 # (grid, t, spec kwargs, pinned substrate kwargs): the paper's three ranks,
-# both halo substrates, divisible and remainder widths.  Pinned w_tile on
+# full-width and column-tiled rings, divisible and remainder widths.  Pinned w_tile on
 # the remainder cases forces the edge-tile path regardless of the VMEM
 # budget's auto choice.
 MATRIX = [
@@ -88,9 +88,9 @@ def main(argv=None) -> int:
             try:
                 rep = audit.audit_context(ctx, name)
             except ValueError as e:
-                # The backend itself rejects this configuration (e.g. the
-                # whole-strip foil refuses column tiling) -- the builder
-                # raises identically, so there is no plan to audit.
+                # The backend itself rejects this configuration (e.g.
+                # fused_matmul refuses t>1 non-periodic boundaries) -- the
+                # builder raises identically, so there is no plan to audit.
                 skipped_cfg.append({"backend": name, "grid": list(grid),
                                     "t": t, "reason": str(e)})
                 continue

@@ -43,23 +43,20 @@ MAX_GRID_STEPS = 2_000_000
 
 
 def enumerate_fetches(lg):
-    """Walk the full launch grid; return per-input-ref fetch counts under
-    Pallas revisit semantics plus the raw step count.
+    """Walk the full launch grid; return the input block fetch count
+    under Pallas revisit semantics (a block index equal to the previous
+    step's is not fetched again) plus the raw step count.
 
-    Returns ``(fetch_counts, n_steps, ring_steps)`` where ``fetch_counts``
-    has one entry per input reference.
+    Returns ``(fetches, n_steps)``.
     """
     grid = lg.grid
-    n_steps = math.prod(grid)
-    counts = [0] * len(lg.in_index_maps)
-    prev = [None] * len(lg.in_index_maps)
+    fetches, prev = 0, None
     for ix in itertools.product(*map(range, grid)):
-        for k, im in enumerate(lg.in_index_maps):
-            idx = im(*ix)
-            if idx != prev[k]:
-                counts[k] += 1
-                prev[k] = idx
-    return counts, n_steps
+        idx = lg.in_index_map(*ix)
+        if idx != prev:
+            fetches += 1
+            prev = idx
+    return fetches, math.prod(grid)
 
 
 def _block_limits(shape, block):
@@ -103,17 +100,15 @@ def audit_blocks(lg, launch, dtype_bytes: int) -> List[AuditCheck]:
     oob = []
     out_blocks = {}
     ring_drift = []
-    counts = [0] * len(lg.in_index_maps)
-    prev = [None] * len(lg.in_index_maps)
+    fetches, prev = 0, None
     for ix in itertools.product(*map(range, lg.grid)):
-        for k, im in enumerate(lg.in_index_maps):
-            idx = im(*ix)
-            if any(not 0 <= b < lim for b, lim in zip(idx, in_lim)):
-                if len(oob) < 8:
-                    oob.append((ix, k, idx))
-            if idx != prev[k]:
-                counts[k] += 1
-                prev[k] = idx
+        idx = lg.in_index_map(*ix)
+        if any(not 0 <= b < lim for b, lim in zip(idx, in_lim)):
+            if len(oob) < 8:
+                oob.append((ix, "in", idx))
+        if idx != prev:
+            fetches += 1
+            prev = idx
         oidx = lg.out_index_map(*ix)
         if any(not 0 <= b < lim for b, lim in zip(oidx, out_lim)):
             if len(oob) < 8:
@@ -143,7 +138,7 @@ def audit_blocks(lg, launch, dtype_bytes: int) -> List[AuditCheck]:
                "constant across the ring"))
 
     # ---- deduplicated grid bytes vs the analytic traffic model --------
-    audited = sum(c * math.prod(lg.in_block) for c in counts) * dtype_bytes
+    audited = fetches * math.prod(lg.in_block) * dtype_bytes
     model = _model_grid_bytes(launch, dtype_bytes)
     degenerate = _degenerate_axes(lg)
     if degenerate:
@@ -211,6 +206,6 @@ def audited_read_amp(lg, dtype_bytes: int) -> float:
     """Audited read amplification of one launch: dedup'd grid-input bytes
     over padded-output bytes (the third witness of the explain==decision
     parity sweep -- tests/test_audit.py)."""
-    counts, _ = enumerate_fetches(lg)
-    audited = sum(c * math.prod(lg.in_block) for c in counts) * dtype_bytes
+    fetches, _ = enumerate_fetches(lg)
+    audited = fetches * math.prod(lg.in_block) * dtype_bytes
     return audited / (math.prod(lg.out_shape) * dtype_bytes)

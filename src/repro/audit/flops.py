@@ -133,15 +133,10 @@ def _jaxprs_in(v):
 # --------------------------------------------------------------------------
 def _region(lg):
     """Shape of the f32 region ``compute`` receives (DESIGN.md §9/§10):
-    the scratch read window, the flat block, or the foil concat."""
+    the scratch read window of a ring, or the flat block."""
     if lg.scratch_shape is not None:
         return tuple(hi - lo for lo, hi in lg.read_bounds)
-    if lg.kind == "flat":
-        return lg.in_block
-    shape = list(lg.in_block)           # wholestrip / wholeslab concat
-    for ax in range(len(shape) - 1):
-        shape[ax] += 2 * lg.halo
-    return tuple(shape)
+    return lg.in_block
 
 
 def mirror_launch_flops(launch, lg):

@@ -55,8 +55,8 @@ class AuditReport:
     t: int
     dtype: str
     checks: List[AuditCheck] = dataclasses.field(default_factory=list)
-    #: Non-None when the backend declared itself exempt (legacy foils,
-    #: the pure-jnp reference oracle -- registry audit hooks).
+    #: Non-None when the backend declared itself exempt (the pure-jnp
+    #: reference oracle -- registry audit hooks).
     exempt: Optional[str] = None
 
     @property

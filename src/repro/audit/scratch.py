@@ -86,7 +86,7 @@ def _gather_window_check(launch) -> AuditCheck:
 
 def audit_scratch(lg, launch) -> List[AuditCheck]:
     """All scratch-pipeline checks for one launch geometry (empty list
-    for the scratch-free foil/flat kinds -- nothing to prove beyond the
+    for the scratch-free "flat" kind -- nothing to prove beyond the
     sparse gather window, which is substrate-independent)."""
     checks: List[AuditCheck] = []
     if launch.engine == "sparse_matmul":
@@ -150,7 +150,7 @@ def audit_scratch(lg, launch) -> List[AuditCheck]:
     bad = []
     for cell in _sample_cells(cell_dims):
         for j in range(ring):
-            idx = lg.in_index_maps[0](*cell, j)
+            idx = lg.in_index_map(*cell, j)
             ks = lg.ring_indices(j)
             for ax in range(n_ring_axes):
                 b = lg.block_dims[ax]

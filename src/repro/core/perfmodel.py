@@ -146,9 +146,9 @@ class StencilWorkload:
     """A stencil problem instance bound to a fusion depth and dtype.
 
     ``read_amp`` is the substrate's grid-read amplification: 1.0 models the
-    paper's ideal (each point read once), 1 + 2h/strip_m the halo-row
-    sub-blocked strip substrate, 3.0 whole neighbor strips, 9.0 the seed
-    scheme (see ``repro.kernels.common.substrate_read_amp``).  It scales
+    paper's ideal (each point read once), 1 + 2h/strip_m the block-ring
+    strip substrate (3.0 when its ring is pinned to whole neighbor
+    strips; see ``repro.kernels.common.substrate_read_amp``).  It scales
     M and therefore every intensity below -- the substrate's traffic model
     IS the experiment (Eq. 6), so the selector prices the substrate it
     actually runs on.

@@ -43,7 +43,7 @@ class PricingContext:
     s_mono: float                 # structural S at the fused radius t*r
     s_reuse: float                # structural S at the base radius r
     strip_m: int
-    #: Resolved halo sub-block height (0 = whole-strip) -- INFORMATIONAL
+    #: Resolved halo sub-block height -- INFORMATIONAL
     #: for plug-in pricers: its read amplification is already folded into
     #: ``workload.read_amp``, which is the canonical channel.
     h_block: Optional[int] = None
@@ -99,7 +99,7 @@ def select_backend(
     Candidates are enumerated from the backend registry
     (``repro.kernels.registry``): every registered backend with a ``price``
     hook that returns a throughput for this workload competes; the rest
-    (reference oracle, legacy/whole-strip foils) are never selected.
+    (the reference oracle) are never selected.
 
     ``sparsity`` overrides the scheme's structural S for BOTH matrix
     regimes (useful to model published schemes); by default the monolithic
@@ -108,7 +108,7 @@ def select_backend(
     MXU efficiency at depth.
 
     ``h_block`` is the substrate's halo sub-block height (``None`` = the
-    kernels' own auto choice, ``0`` = whole-strip): the workload's memory
+    kernels' own auto choice; ``0`` is refused): the workload's memory
     term M uses the resulting read amplification 1 + 2h/strip_m, so
     intensities -- and the VPU-vs-MXU crossover -- price the substrate
     that actually runs rather than the paper's ideal M = 2D.  3D
@@ -139,8 +139,8 @@ def select_backend(
     # candidate's substrate faithfully: the fused regimes build with exactly
     # this halo, and the sequential regimes (direct/matmul) only price at
     # t=1 -- their t>1 hooks return None -- where t*r == r.  pricing_geom
-    # shares resolve_substrate_geom's pin rules (including the hybrid
-    # z_block=0 rejection), so the priced substrate is always buildable.
+    # shares resolve_substrate_geom's pin rules (including the zero-block
+    # refusal), so the priced substrate is always buildable.
     geom = pricing_geom(spec.dim, t * spec.radius, strip_m, h_block,
                         z_slab, z_block, w_tile, w_block)
     read_amp = geom.read_amp

@@ -1,6 +1,5 @@
 """Pallas TPU kernels for the stencil hot paths (VPU direct, MXU banded),
-on the strip-mined halo substrate (kernels.common; seed scheme preserved in
-kernels.legacy for traffic benchmarking).
+on the one block-ring halo substrate (kernels.common).
 
 The public surface is the plan API: ``stencil_plan`` compiles the paper's
 decision procedure + kernel lowering into a reusable ``StencilPlan``;
@@ -23,8 +22,8 @@ from .stencil_matmul import (stencil_matmul, build_bands, build_bands_nd,
 from .common import (SubstrateGeom, choose_col_blocks, choose_hblock,
                      choose_slab_blocks, choose_strip, choose_strip_blocks,
                      choose_tile, pricing_geom, resolve_strip_blocks,
-                     resolve_substrate_geom, strip_in_specs,
-                     substrate_read_amp, vmem_budget_bytes)
+                     resolve_substrate_geom, substrate_read_amp,
+                     vmem_budget_bytes)
 
 
 def __getattr__(name):

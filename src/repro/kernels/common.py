@@ -1,20 +1,9 @@
-"""Shared Pallas plumbing: halo-row sub-blocked strip substrate.
+"""Shared Pallas plumbing: the block-ring halo substrate every kernel runs.
 
 TPU Pallas BlockSpecs address non-overlapping blocks (element offset = block
 index * block shape), so halo reads cannot be expressed as one overlapping
-block.  The seed substrate worked around that by referencing the SAME input
-nine times with shifted ``index_map``s -- one full (tile_m, tile_n) block
-per 2D neighbor -- which streams 9x the grid through HBM per step even
-though only halo-wide edges of eight of those blocks are ever read.
-
-PR 1 replaced that with WHOLE row strips: a 1D grid over (strip_m, N)
-bands, each output strip loading itself plus its full top/bottom neighbor
-strips (3 loads, modulo wrap in the index map = periodic rows), with the
-horizontal periodic halo materialized in-VMEM (``wrap_columns``) at zero
-HBM cost.  3x read amplification -- but the two neighbor strips are still
-fetched whole although only ``halo`` rows of each are ever read.
-
-This module now implements the halo-row SUB-BLOCKED scheme (DESIGN.md §3):
+block.  The substrate instead walks a RING of blocks per output cell
+(DESIGN.md §3):
 
   * the grid is 2D over (strip, h-block): block height ``h_block`` divides
     ``strip_m`` (``nb = strip_m / h_block`` blocks per strip);
@@ -30,16 +19,15 @@ This module now implements the halo-row SUB-BLOCKED scheme (DESIGN.md §3):
 
         reads/step = (1 + 2*h_block/strip_m) * H*W*D
 
-    vs 3x for whole neighbor strips and 9x for the seed scheme.  The
-    modulo index map keeps periodic top/bottom boundaries for free, and
-    every scratch row is still a TRUE global row, so the horizontal wrap
+    The modulo index map keeps periodic top/bottom boundaries for free,
+    and every scratch row is a TRUE global row, so the horizontal wrap
     re-applies to in-VMEM intermediates at every fused step -- the
     property that enables ``fused_matmul_reuse`` (DESIGN.md §4).
 
-``h_block=0`` (or ``subblocked=False`` at the kernel level) selects the
-whole-strip 3-load substrate -- kept registered as the ``*_wholestrip``
-benchmark foils so ``benchmarks/traffic.py`` can measure seed / whole-strip
-/ sub-blocked three ways.
+``h_block = strip_m`` is the ring's coarsest pin: three full strips per
+output strip, read amplification 3 (the whole-strip read pattern).
+``h_block`` must be at least 1: 0 names no ring and is refused at
+resolution (``check_block_pins``).
 
 N-D HALO-PLANE GENERALIZATION (DESIGN.md §9).  The scheme above is the
 d=2 instance of a general halo-plane substrate:
@@ -56,13 +44,14 @@ d=2 instance of a general halo-plane substrate:
 
     The last axis keeps the free in-VMEM periodic wrap (every scratch row
     is a TRUE global row), so the fused regimes carry over unchanged.
-    ``h_block=0`` selects the whole-slab foil (3x3 full neighbor slabs =
-    9x reads, the 3D analogue of the 2D 3-load scheme).
+    ``z_block = z_slab`` with ``h_block = strip_m`` walks 3x3 full
+    neighbor slabs, read amplification 9 (the whole-slab read pattern).
   * 1D grids route through the 2D substrate lifted to (1, N): the
     vertical halo is 0, so each strip streams only its own rows
-    (read amplification exactly 1) and the x-wrap stays in-VMEM.
+    (the "flat" launch: no ring, read amplification exactly 1) and the
+    x-wrap stays in-VMEM.
 
-COLUMN-TILED W AXIS (DESIGN.md §10).  Both schemes above span the FULL
+COLUMN-TILED W AXIS (DESIGN.md §10).  The rings above span the FULL
 width in VMEM, so grids with W >> VMEM (weather/fluid planes with W in
 the tens of thousands) cannot execute at all.  When the full-width
 working set exceeds the VMEM budget the substrate column-tiles the last
@@ -94,9 +83,7 @@ axis too:
 
 ``w_tile=0`` is the full-width fast path: the launchers and sizing are
 bit-for-bit the pre-column-tiling scheme, and auto-resolution only
-column-tiles when full width cannot fit the budget.  The whole-strip /
-whole-slab foils never column-tile (they are full-width by
-construction), so ``w_tile > 0`` requires the sub-blocked substrate.
+column-tiles when full width cannot fit the budget.
 
 ``SubstrateGeom`` carries the resolved (z_slab, z_block, strip_m,
 h_block, w_tile, w_block) geometry through plans, the selector and the
@@ -132,9 +119,7 @@ bit.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -143,15 +128,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import trace
 from repro.stencil.boundary import PAD_MODE, resolve_boundary
-
-#: Vertical neighbor offsets of the whole-strip scheme (up, center, down) --
-#: the strip analogue of the seed's 9-entry 2D offset table (kernels.legacy).
-NEIGHBOR_OFFSETS_STRIP = (-1, 0, 1)
-
-#: Per-output-strip input block loads issued by the WHOLE-strip substrate.
-#: The seed scheme issued 9 (kernels.legacy.NEIGHBOR_OFFSETS_2D); the
-#: sub-blocked substrate issues ``strip_m/h_block + 2`` h-row blocks.
-STRIP_NEIGHBOR_LOADS = len(NEIGHBOR_OFFSETS_STRIP)
 
 #: Default VMEM working-set budget for strip sizing (bytes).  A kernel
 #: compiled for a TPU v5e gets a 16 MiB scoped VMEM limit by default (the
@@ -221,68 +197,6 @@ def vmem_budget_bytes() -> int:
     plan cache folds the effective value into its keys."""
     from repro.core.envutil import env_int
     return env_int("REPRO_VMEM_BUDGET", VMEM_BUDGET_BYTES, minimum=1)
-
-
-def strip_in_specs(strip_m: int, n: int, grid_m: int):
-    """Three BlockSpecs addressing row strips (i-1, i, i+1) mod grid_m.
-
-    The WHOLE-strip substrate: each spec covers a full-width (strip_m, n)
-    band; modulo wrap in the index map yields periodic top/bottom boundaries
-    for free (matching the ppermute ring of the distributed runtime).
-    """
-    specs = []
-    for di in NEIGHBOR_OFFSETS_STRIP:
-        specs.append(
-            pl.BlockSpec(
-                (strip_m, n),
-                functools.partial(lambda i, di=di: ((i + di) % grid_m, 0)),
-            )
-        )
-    return specs
-
-
-def subblock_in_spec(h_block: int, n: int, nb: int, total_blocks: int):
-    """The single h-block BlockSpec of the sub-blocked substrate.
-
-    Grid cell (i, j), j in [0, nb+2), fetches h-block
-    ``(i*nb + j - 1) mod total_blocks``: j=0 is the top neighbor strip's
-    last h-block, j=1..nb the strip's own blocks, j=nb+1 the bottom
-    neighbor's first h-block.  Modulo wrap = periodic rows, exactly as the
-    whole-strip index maps.
-    """
-    return pl.BlockSpec(
-        (h_block, n),
-        lambda i, j: ((i * nb + j - 1) % total_blocks, 0),
-    )
-
-
-def subblock_store(scratch_ref, block_ref, h_block: int) -> None:
-    """Copy grid cell (i, j)'s h-block into scratch rows [j*h, (j+1)*h)."""
-    j = pl.program_id(1)
-    scratch_ref[pl.ds(j * h_block, h_block), :] = block_ref[...]
-
-
-def subblock_extended(scratch_ref, h_block: int, strip_m: int,
-                      halo: int) -> jax.Array:
-    """The (strip_m + 2*halo, n) halo-extended strip from assembled scratch.
-
-    Scratch rows cover global rows [i*strip_m - h_block,
-    (i+1)*strip_m + h_block); the extended strip needs only ``halo`` of the
-    ``h_block`` neighbor rows at each end.
-    """
-    return scratch_ref[h_block - halo : h_block + strip_m + halo, :]
-
-
-def assemble_strip(top_ref, mid_ref, bot_ref, halo: int) -> jax.Array:
-    """Build the (strip_m + 2h, n) vertically halo-extended strip in VMEM.
-
-    Whole-strip substrate: only the bottom ``halo`` rows of the top neighbor
-    and the top ``halo`` rows of the bottom neighbor are read.
-    """
-    h = halo
-    return jnp.concatenate(
-        [top_ref[...][-h:, :], mid_ref[...], bot_ref[...][:h, :]], axis=0
-    )
 
 
 def wrap_columns(x: jax.Array, halo: int) -> jax.Array:
@@ -460,8 +374,8 @@ def choose_hblock(strip_m: int, halo: int, align: int = SUBLANE) -> int:
     divide the strip.  Smaller blocks cut traffic (amplification is
     1 + 2h/strip_m) but multiply grid cells, so we floor at
     ceil(strip_m/16) -- amplification lands at ~1.125 whenever the halo
-    allows, and degrades gracefully toward the whole-strip 3x as the halo
-    forces h_block up.  ``align`` is the tiling rule of the axis the
+    allows, and degrades gracefully toward 3x (h_block = strip_m) as the
+    halo forces h_block up.  ``align`` is the tiling rule of the axis the
     block sits on: the sublane tile for the row axis (8 for 32-bit,
     ``sublane_tile``), ``LANE`` for the lane axis, 1 for a leading axis
     (z), which Mosaic does not tile.
@@ -476,10 +390,10 @@ def choose_hblock(strip_m: int, halo: int, align: int = SUBLANE) -> int:
 
 def _strip_working_set(d: int, hb: int, n: int, halo: int,
                        dtype_bytes: int) -> int:
-    """Full-width 2D VMEM working set, priced at the WORSE of the two
-    substrates -- 3 full strips (whole-strip foil) vs scratch +
-    in-flight h-block (sub-blocked) -- plus the horizontally-extended
-    f32 compute tile and the output strip."""
+    """Full-width 2D VMEM working set: the ring's scratch + in-flight
+    h-block (floored at three full strips, below), plus the
+    horizontally-extended f32 compute tile and the output strip."""
+    # 3*d*n: the deleted whole-strip foil's pricing (ROADMAP Speed 2).
     inputs = max(3 * d * n, (d + 2 * hb) * n + hb * n)
     return (inputs + (d + 2 * halo) * (n + 2 * halo) + d * n) * dtype_bytes
 
@@ -487,8 +401,7 @@ def _strip_working_set(d: int, hb: int, n: int, halo: int,
 def _col_working_set_2d(sm: int, hb: int, wt: int, wb: int, halo: int,
                         x_halo: int, dtype_bytes: int) -> int:
     """Column-tiled 2D VMEM working set: scratch + in-flight block +
-    halo-extended compute tile + output tile (sub-blocked only -- the
-    whole-strip foil never column-tiles)."""
+    halo-extended compute tile + output tile."""
     scratch = (sm + 2 * hb) * (wt + 2 * wb) + hb * wb
     compute = (sm + 2 * halo) * (wt + 2 * x_halo)
     return (scratch + compute + sm * wt) * dtype_bytes
@@ -524,11 +437,8 @@ def choose_strip_blocks(
     rule); among fitting divisors prefer the largest <= ``preferred``
     (taller strips both amortize per-cell cost and shrink the halo read
     factor 1 + 2h/strip_m).
-    ``h_block``: ``choose_hblock`` of the chosen strip.  The input-side
-    working set is priced at the worse of the two substrates
-    (``_strip_working_set``), so a strip that fits the budget fits
-    whichever substrate the caller ends up running (the ``*_wholestrip``
-    foils share this sizing).  When NO full-width strip fits, the
+    ``h_block``: ``choose_hblock`` of the chosen strip; the working set
+    is ``_strip_working_set``'s.  When NO full-width strip fits, the
     smallest viable one is returned anyway -- ``resolve_strip_blocks``
     detects that case and escalates to the column-tiled sizing
     (``choose_col_blocks``) instead.
@@ -653,19 +563,16 @@ def choose_slab_blocks(
     ``z_block``/``h_block`` are ``choose_hblock`` of each (smallest
     halo-covering divisor above the 1/16 floor).  Two phases:
 
-      * FULL WIDTH (w_tile = w_block = 0, the fast path): the input
-        working set is priced at the WORSE of the two substrates -- 9
-        full neighbor slabs (whole-slab foil) vs scratch + in-flight
-        block (sub-blocked) -- plus the f32 halo-extended compute slab
-        and the output slab, so a geometry that fits the budget fits
-        whichever substrate ends up running.  Taken whenever any
-        full-width pair fits (or ``w_pin=0`` forces it).
+      * FULL WIDTH (w_tile = w_block = 0, the fast path): the ring's
+        scratch + in-flight block (floored at nine full slabs, below),
+        plus the f32 halo-extended compute slab and the output slab.
+        Taken whenever any full-width pair fits (or ``w_pin=0`` forces
+        it).
       * COLUMN-TILED (DESIGN.md §10): when no full-width pair fits (or
         ``w_pin`` > 0), the search adds the (w_tile, w_block) axis --
         ``_wtile_candidates`` of W, w_block floored at the carried
-        x-halo ``x_halo`` (= t*r) -- and prices the sub-blocked scratch
-        + compute + output cell only (the whole-slab foil never
-        column-tiles).
+        x-halo ``x_halo`` (= t*r) -- and prices the ring's scratch +
+        compute + output cell.
 
     Among fitting combinations (free axes capped at ``preferred``) the
     rule minimizes the analytic read-amplification product, tie-breaking
@@ -689,8 +596,8 @@ def choose_slab_blocks(
     def working_set(zs: int, sm: int) -> int:
         zb, hb = blocks(zs, sm)
         scratch = (zs + 2 * zb) * (sm + 2 * hb) * n + zb * hb * n
-        whole = 9 * zs * sm * n
-        inputs = max(whole, scratch)
+        # 9*zs*sm*n: the deleted whole-slab foil's pricing (ROADMAP Speed 2).
+        inputs = max(9 * zs * sm * n, scratch)
         compute = (zs + 2 * halo) * (sm + 2 * halo) * (n + 2 * halo)
         return (inputs + compute + zs * sm * n) * dtype_bytes
 
@@ -733,19 +640,18 @@ class SubstrateGeom:
     """Resolved halo-plane substrate geometry for one kernel launch.
 
     ``dim`` is the grid rank (1D executes lifted through the 2D substrate
-    with strip_m=1 and zero vertical halo).  ``h_block=0`` selects the
-    whole-strip/whole-slab foil substrate (and forces ``z_block=0``);
-    otherwise both block heights are >= the halo and divide their tile.
-    ``w_tile=0`` is the full-width fast path; ``w_tile > 0`` selects the
-    column-tiled substrate (DESIGN.md §10: sub-blocked only, with
-    ``w_block`` >= the carried x-halo t*r and dividing ``w_tile``).
+    with strip_m=1 and zero vertical halo).  Both block heights are >=
+    the halo and divide their tile.  ``w_tile=0`` is the full-width fast
+    path; ``w_tile > 0`` selects the column-tiled substrate (DESIGN.md
+    §10, with ``w_block`` >= the carried x-halo t*r and dividing
+    ``w_tile``).
     """
 
     dim: int
     strip_m: int
-    h_block: int                 # 0 = whole-strip/whole-slab foil
+    h_block: int
     z_slab: int = 1              # 3D only; 1 otherwise
-    z_block: int = 0             # 3D only; 0 = whole-slab (with h_block=0)
+    z_block: int = 0             # 3D only; 0 otherwise
     w_tile: int = 0              # 0 = full width (fast path)
     w_block: int = 0             # column halo block; 0 iff w_tile == 0
 
@@ -754,7 +660,7 @@ class SubstrateGeom:
         """Analytic grid-read amplification of this geometry (DESIGN.md
         §9/§10): 1 (lifted 1D), 1 + 2h/strip_m (2D), times
         (1 + 2z_block/z_slab) (3D), times (1 + 2w_block/w_tile) when
-        column-tiled; the full-width foils read 3x (2D) and 9x (3D)."""
+        column-tiled."""
         if self.dim == 1:
             return 1.0
         amp = substrate_read_amp(self.strip_m, self.h_block)
@@ -783,33 +689,31 @@ class SubstrateGeom:
         return f"substrate read_amp={self.read_amp:.3f}x ({geo})"
 
 
-def _resolve_z_block(h_block: int, z_block: int, z_slab: int,
-                     halo: int) -> int:
-    """z_block under the shared pin rules: forced 0 by the whole foil
-    (h_block=0), rejected as a lone 0 (no hybrid substrate exists),
-    otherwise the explicit pin or ``choose_hblock`` of the slab.  Both
-    ``resolve_substrate_geom`` and ``pricing_geom`` route through here, so
-    plan building and grid-free pricing can never disagree on the rule.
-    """
+def _check_block_pins(h_block: int, z_block: int) -> None:
+    """Refuse zero block pins: every launch walks a block ring, and a
+    ring block holds at least one row (plane).  The coarsest ring is
+    ``h_block = strip_m`` (three full strips per output strip, read amp
+    3) and, in 3D, ``z_block = z_slab`` beside it (nine full slabs, read
+    amp 9).  Both ``resolve_substrate_geom`` and ``pricing_geom`` check
+    here first."""
     if h_block == 0:
-        return 0
+        raise ValueError(
+            "h_block=0 names no block ring; pin h_block=strip_m for the "
+            "whole-strip read pattern")
     if z_block == 0:
         raise ValueError(
-            "z_block=0 (whole-slab) is only valid together with "
-            "h_block=0 (the whole-slab foil substrate)")
-    return z_block if z_block is not None else choose_hblock(z_slab, halo, 1)
+            "z_block=0 names no block ring; pin z_block=z_slab (with "
+            "h_block=strip_m) for the whole-slab read pattern")
 
 
-def _resolve_w_block(w_tile: int, w_block: int, h_block: int,
-                     x_halo: int) -> tuple:
+def _resolve_w_block(w_tile: int, w_block: int, x_halo: int) -> tuple:
     """(w_tile, w_block) under the shared pin rules: ``w_tile`` in
     (None, 0) is the full-width fast path (w_block forced 0; a lone
-    w_block pin is rejected); a positive ``w_tile`` requires the
-    sub-blocked substrate (the whole-strip/whole-slab foils are
-    full-width by construction) and gets ``choose_hblock`` of the tile
-    floored at the carried x-halo unless ``w_block`` is pinned too.
-    Both ``resolve_substrate_geom`` and ``pricing_geom`` route through
-    here, so plan building and grid-free pricing can never disagree.
+    w_block pin is rejected); a positive ``w_tile`` gets
+    ``choose_hblock`` of the tile floored at the carried x-halo unless
+    ``w_block`` is pinned too.  Both ``resolve_substrate_geom`` and
+    ``pricing_geom`` route through here, so plan building and grid-free
+    pricing can never disagree.
     """
     if not w_tile:
         if w_block:
@@ -817,11 +721,6 @@ def _resolve_w_block(w_tile: int, w_block: int, h_block: int,
                 f"w_block={w_block} without a w_tile names no substrate; "
                 "pin w_tile too (or drop both for full width)")
         return 0, 0
-    if h_block == 0:
-        raise ValueError(
-            "the whole-strip/whole-slab foil substrate (h_block=0) spans "
-            "the full width; column tiling (w_tile > 0) requires the "
-            "sub-blocked substrate")
     if w_block is None or w_block == 0:
         return w_tile, choose_hblock(w_tile, max(x_halo, 1), LANE)
     return w_tile, w_block
@@ -839,17 +738,18 @@ def pricing_geom(dim: int, halo: int, strip_m: int = 128,
     in (None, 0) prices the full-width fast path; a positive ``w_tile``
     prices the column-tiled substrate (w_block auto-resolved at the
     fused x-halo ``halo`` unless pinned)."""
+    _check_block_pins(h_block, z_block)
     if dim == 1:
         return SubstrateGeom(dim=1, strip_m=1, h_block=1)
     hb = choose_hblock(strip_m, halo) if h_block is None else h_block
-    wt, wb = _resolve_w_block(w_tile, w_block, hb, halo)
+    wt, wb = _resolve_w_block(w_tile, w_block, halo)
     if dim == 2:
         return SubstrateGeom(dim=2, strip_m=strip_m, h_block=hb,
                              w_tile=wt, w_block=wb)
     if dim != 3:
         raise ValueError(f"substrate supports 1D/2D/3D grids, got dim {dim}")
     zs = strip_m if z_slab is None else z_slab
-    zb = _resolve_z_block(hb, z_block, zs, halo)
+    zb = z_block if z_block is not None else choose_hblock(zs, halo, 1)
     return SubstrateGeom(dim=3, strip_m=strip_m, h_block=hb,
                          z_slab=zs, z_block=zb, w_tile=wt, w_block=wb)
 
@@ -879,9 +779,9 @@ def resolve_substrate_geom(grid_shape, halo: int, dtype_bytes: int,
       * 2D: exactly ``resolve_strip_blocks`` (z fields stay inert);
       * 3D: joint ``choose_slab_blocks`` when unpinned; explicit ``tile_m``
         / ``z_slab`` are clamped to the grid and get ``choose_hblock``
-        blocks unless those are pinned too.  ``h_block=0`` selects the
-        whole-slab foil and forces ``z_block=0``; a lone ``z_block=0``
-        under a sub-blocked h_block is rejected (no hybrid substrate).
+        blocks unless those are pinned too.
+
+    ``h_block=0`` and ``z_block=0`` are refused (``_check_block_pins``).
 
     Width (DESIGN.md §10): ``w_tile=None`` auto-resolves -- full width
     whenever the full-width working set fits the VMEM budget, the
@@ -891,10 +791,10 @@ def resolve_substrate_geom(grid_shape, halo: int, dtype_bytes: int,
     column-tiled kernels cannot re-wrap partial rows) and defaults to
     ``halo`` -- exact for the square kernels this repo builds.
     """
+    _check_block_pins(h_block, z_block)
     dim = len(grid_shape)
     if dim == 1:
-        hb = 0 if h_block == 0 else 1
-        return SubstrateGeom(dim=1, strip_m=1, h_block=hb)
+        return SubstrateGeom(dim=1, strip_m=1, h_block=1)
     xh = halo if x_halo is None else x_halo
     if dim == 2:
         strip_m, hb, wt, wb = resolve_strip_blocks(
@@ -907,24 +807,20 @@ def resolve_substrate_geom(grid_shape, halo: int, dtype_bytes: int,
     z, h, wid = grid_shape
     w_tile, w_block = _normalize_w_pin(w_tile, w_block, wid)
     if w_block and w_tile is None:
-        _resolve_w_block(0, w_block, h_block, xh)    # raises: lone w_block
-        # pins are rejected on every path (see resolve_strip_blocks)
-    if h_block == 0 and w_tile:
-        _resolve_w_block(w_tile, w_block, 0, halo)   # raises: foil is
-        # full-width by construction
+        _resolve_w_block(0, w_block, xh)    # raises: lone w_block pins are
+        # rejected on every path (see resolve_strip_blocks)
     # One pin-aware joint search: a pinned axis is fixed (clamped to the
     # grid) and only the free axes are sized -- conditioned on the pins, so
     # the VMEM fit and amp-minimization always describe the geometry that
-    # actually runs.  The whole-slab foil (h_block=0) never column-tiles.
+    # actually runs.
     zs, auto_zb, sm, auto_hb, wt, auto_wb = choose_slab_blocks(
         z, h, wid, halo, dtype_bytes,
         z_pin=min(z_slab, z) if z_slab is not None else None,
         m_pin=min(tile_m, h) if tile_m is not None else None,
-        w_pin=0 if h_block == 0 else w_tile,
-        x_halo=xh)
+        w_pin=w_tile, x_halo=xh)
     hb = h_block if h_block is not None else auto_hb
-    zb = _resolve_z_block(hb, z_block, zs, halo)
-    wt, wb = _resolve_w_block(wt, w_block if w_block else auto_wb, hb, xh)
+    zb = z_block if z_block is not None else auto_zb
+    wt, wb = _resolve_w_block(wt, w_block if w_block else auto_wb, xh)
     return SubstrateGeom(dim=3, strip_m=sm, h_block=hb, z_slab=zs,
                          z_block=zb, w_tile=wt, w_block=wb)
 
@@ -976,14 +872,13 @@ def validate_tiling(shape, strip_m: int, tile_n: int, halo: int,
     width for the VPU path) -- any width in [1, W] is legal, the kernels
     handle a narrower final chunk by slicing the banded operand.
     ``radius`` is the per-step wrap radius (defaults to ``halo`` for
-    callers that run a single step at the full radius).  ``h_block``
-    (sub-blocked substrate) must divide ``strip_m`` and cover the
-    vertical halo; pass ``None``/0 for the whole-strip substrate.
-    3D grids additionally constrain ``z_slab`` (divides Z, >= halo) and
-    ``z_block`` (divides ``z_slab``, >= halo when sub-blocked).
-    Column-tiled launches (``w_tile`` > 0, DESIGN.md §10) require the
-    sub-blocked substrate and a ``w_block`` that divides ``w_tile`` and
-    covers the CARRIED x-halo ``x_halo`` (= t*r; defaults to ``halo``) --
+    callers that run a single step at the full radius).  ``h_block`` must
+    divide ``strip_m`` and cover the vertical halo.  3D grids
+    additionally constrain ``z_slab`` (divides Z, >= halo) and
+    ``z_block`` (divides ``z_slab``, >= halo); ``None`` blocks are not
+    checked.  Column-tiled launches (``w_tile`` > 0, DESIGN.md §10)
+    require a ``w_block`` that divides ``w_tile`` and covers the CARRIED
+    x-halo ``x_halo`` (= t*r; defaults to ``halo``) --
     ``w_tile`` need NOT divide W (edge tiles run the remainder path).
     ``boundary`` is the per-axis mode spec (DESIGN.md §15): non-periodic
     axes swap the wrap-radius guard for the mode-specific one, and
@@ -1039,11 +934,6 @@ def validate_tiling(shape, strip_m: int, tile_n: int, halo: int,
                 "enlarge h_block or lower fusion depth"
             )
     if w_tile:
-        if not h_block:
-            raise ValueError(
-                "column tiling (w_tile > 0) requires the sub-blocked "
-                "substrate; the whole-strip/whole-slab foil (h_block=0) "
-                "spans the full width")
         if w_tile > w:
             raise ValueError(
                 f"w_tile {w_tile} exceeds grid width {w}")
@@ -1098,11 +988,13 @@ class LaunchGeometry:
     with concrete grid indices is exact enumeration, no tracing): the
     audited geometry IS the launched geometry, never a re-derivation.
 
-    ``kind`` is one of "flat", "wholestrip", "subblocked", "coltiled",
-    "wholeslab", "slab_subblocked", "slab_coltiled".  Ring kinds (those
-    with a scratch) put the ring on the LAST grid axis; ``ring_dims`` is
-    its row-major mixed-radix shape (e.g. (nb+2, ring_w)) and
-    ``block_dims`` the matching per-ringed-axis scratch block sizes.
+    ``kind`` is one of "flat", "subblocked", "coltiled",
+    "slab_subblocked", "slab_coltiled".  Every launch reads its input
+    through ONE BlockSpec (``in_block``, ``in_index_map``).  Ring kinds
+    (all but "flat", the lifted 1D launch) have a scratch and put the
+    ring on the LAST grid axis; ``ring_dims`` is its row-major
+    mixed-radix shape (e.g. (nb+2, ring_w)) and ``block_dims`` the
+    matching per-ringed-axis scratch block sizes.
     ``out_shape`` is the launch output BEFORE the remainder-path column
     slice; ``src_shape`` the input AFTER any host-side column extension
     (``_extend_columns_for_tiling``) -- both equal the grid shape on
@@ -1112,7 +1004,7 @@ class LaunchGeometry:
     kind: str
     grid: tuple
     in_block: tuple
-    in_index_maps: tuple
+    in_index_map: object
     out_block: tuple
     out_index_map: object
     out_shape: tuple
@@ -1173,7 +1065,6 @@ def strip_launch_geometry(x_shape, strip_m: int, h_block: int, halo: int,
     truth for what ``strip_substrate_call`` launches.
 
     ``halo=0`` -> "flat" (one load per strip, read amp exactly 1);
-    ``h_block=0`` -> "wholestrip" (3 shifted full-strip refs);
     otherwise "subblocked" ((strip, h-block) ring into VMEM scratch);
     ``w_tile>0`` -> "coltiled" (DESIGN.md §10, full 2-axis block ring,
     edge-tile remainder path on non-dividing widths).
@@ -1210,9 +1101,9 @@ def strip_launch_geometry(x_shape, strip_m: int, h_block: int, halo: int,
             kind="coltiled",
             grid=(gm, gw, (nb + 2) * ring_w),
             in_block=(h_block, w_block),
-            in_index_maps=(lambda i, iw, j: (
+            in_index_map=lambda i, iw, j: (
                 _axis_block_index(i * nb + j // ring_w - 1, total_h, by),
-                col_index(iw, j % ring_w)),),
+                col_index(iw, j % ring_w)),
             out_block=(strip_m, w_tile),
             out_index_map=lambda i, iw, j: (i, iw),
             out_shape=(h, out_w),
@@ -1227,28 +1118,15 @@ def strip_launch_geometry(x_shape, strip_m: int, h_block: int, halo: int,
             boundary=(by, bx),
         )
     elif halo == 0:
-        # No vertical halo => no neighbor loads on either substrate
-        # (they coincide here): one load per strip, read amp exactly 1.
+        # No vertical halo => no neighbor loads: one load per strip, read
+        # amp exactly 1.
         lg = LaunchGeometry(
             kind="flat", grid=(gm,),
             in_block=(strip_m, n),
-            in_index_maps=(lambda i: (i, 0),),
+            in_index_map=lambda i: (i, 0),
             out_block=(strip_m, n),
             out_index_map=lambda i: (i, 0),
             out_shape=(h, n), src_shape=(h, n), halo=0, x_halo=x_halo,
-            boundary=(by, bx),
-        )
-    elif not h_block:
-        maps = tuple(functools.partial(
-            lambda i, di=di: (_axis_block_index(i + di, gm, by), 0))
-            for di in NEIGHBOR_OFFSETS_STRIP)
-        lg = LaunchGeometry(
-            kind="wholestrip", grid=(gm,),
-            in_block=(strip_m, n),
-            in_index_maps=maps,
-            out_block=(strip_m, n),
-            out_index_map=lambda i: (i, 0),
-            out_shape=(h, n), src_shape=(h, n), halo=halo, x_halo=x_halo,
             boundary=(by, bx),
         )
     else:
@@ -1257,8 +1135,8 @@ def strip_launch_geometry(x_shape, strip_m: int, h_block: int, halo: int,
         lg = LaunchGeometry(
             kind="subblocked", grid=(gm, nb + 2),
             in_block=(h_block, n),
-            in_index_maps=(lambda i, j: (
-                _axis_block_index(i * nb + j - 1, total, by), 0),),
+            in_index_map=lambda i, j: (
+                _axis_block_index(i * nb + j - 1, total, by), 0),
             out_block=(strip_m, n),
             out_index_map=lambda i, j: (i, 0),
             out_shape=(h, n), src_shape=(h, n), halo=halo, x_halo=x_halo,
@@ -1275,7 +1153,7 @@ def strip_launch_geometry(x_shape, strip_m: int, h_block: int, halo: int,
 def slab_launch_geometry(x_shape, geom: SubstrateGeom, halo: int,
                          x_halo: int = 0, boundary=None) -> LaunchGeometry:
     """Build the 3D launch geometry: the single source of truth for what
-    ``slab_substrate_call`` launches ("wholeslab" / "slab_subblocked" /
+    ``slab_substrate_call`` launches ("slab_subblocked" /
     "slab_coltiled", mirroring the 2D kinds one rank up).  ``boundary``
     is the per-axis (z, y, x) mode triple (see
     :func:`strip_launch_geometry`)."""
@@ -1316,7 +1194,7 @@ def slab_launch_geometry(x_shape, geom: SubstrateGeom, halo: int,
             kind="slab_coltiled",
             grid=(gz, gm, gw, (nbz + 2) * ring_y * ring_w),
             in_block=(zb, hb, wb),
-            in_index_maps=(block_index,),
+            in_index_map=block_index,
             out_block=(zs, sm, wt),
             out_index_map=lambda iz, iy, iw, j: (iz, iy, iw),
             out_shape=(z, h, out_w),
@@ -1329,22 +1207,6 @@ def slab_launch_geometry(x_shape, geom: SubstrateGeom, halo: int,
                          (hb - halo, hb + sm + halo),
                          (wb - x_halo, wb + wt + x_halo)),
             aligned=aligned,
-            boundary=(bz, by, bx),
-        )
-    elif not geom.h_block:
-        maps = tuple(
-            functools.partial(lambda iz, iy, dz=dz, dy=dy:
-                              (_axis_block_index(iz + dz, gz, bz),
-                               _axis_block_index(iy + dy, gm, by), 0))
-            for dz in (-1, 0, 1) for dy in (-1, 0, 1))
-        lg = LaunchGeometry(
-            kind="wholeslab", grid=(gz, gm),
-            in_block=(zs, sm, n),
-            in_index_maps=maps,
-            out_block=(zs, sm, n),
-            out_index_map=lambda iz, iy: (iz, iy, 0),
-            out_shape=(z, h, n), src_shape=(z, h, n),
-            halo=halo, x_halo=x_halo,
             boundary=(bz, by, bx),
         )
     else:
@@ -1361,7 +1223,7 @@ def slab_launch_geometry(x_shape, geom: SubstrateGeom, halo: int,
         lg = LaunchGeometry(
             kind="slab_subblocked", grid=(gz, gm, (nbz + 2) * ring_y),
             in_block=(zb, hb, n),
-            in_index_maps=(block_index,),
+            in_index_map=block_index,
             out_block=(zs, sm, n),
             out_index_map=lambda iz, iy, j: (iz, iy, 0),
             out_shape=(z, h, n), src_shape=(z, h, n),
@@ -1402,27 +1264,6 @@ def launch_geometry(grid_shape, geom: SubstrateGeom, halo: int,
                                 boundary=boundary)
 
 
-def _assemble_foil(lg: LaunchGeometry, ins):
-    """In-kernel halo assembly of the scratch-free kinds: identity for
-    "flat", the 3-strip concat for "wholestrip", the 3x3 neighbor-slab
-    concat for "wholeslab" (only halo-deep edges of the neighbors are
-    ever read -- that is the foils' read amplification)."""
-    halo = lg.halo
-    if lg.kind == "flat":
-        return ins[0][...]
-    if lg.kind == "wholestrip":
-        return assemble_strip(*ins, halo)
-
-    def yrow(r_up, r_mid, r_dn):
-        return jnp.concatenate(
-            [r_up[...][:, -halo:, :], r_mid[...], r_dn[...][:, :halo, :]],
-            axis=1)
-
-    rows = [yrow(*ins[3 * i: 3 * i + 3]) for i in range(3)]
-    return jnp.concatenate(
-        [rows[0][-halo:], rows[1], rows[2][:halo]], axis=0)
-
-
 def _edge_flags(lg: LaunchGeometry):
     """Per-region-axis (is_lo, is_hi) domain-edge flags of the current
     grid cell, traced from ``pl.program_id`` -- called INSIDE the kernel
@@ -1444,13 +1285,14 @@ def _edge_flags(lg: LaunchGeometry):
 
 def _pin_region(cur: jax.Array, interpret: bool) -> jax.Array:
     """Interpret mode only: an ``optimization_barrier`` between region
-    assembly and compute.  The interpreter runs the kernel body on
-    XLA:CPU, which would otherwise fuse the assembly (a concat for the
-    foils, a scratch slice for the ring) into the tap sums and form FMAs
-    differently per substrate -- the 3D sub-blocked and whole-slab
-    kernels then differ in the last ulp, where the tests assert them
-    bitwise equal.  Mosaic has no lowering for the barrier, so compiled
-    kernels go without it."""
+    assembly and compute, so that interpret-mode FMA formation does not
+    depend on the ring geometry.  The interpreter runs the kernel body
+    on XLA:CPU, which would otherwise fuse the read of the assembled
+    region (a scratch slice whose offsets follow h_block and z_block)
+    into the tap sums and contract their FMAs differently per geometry
+    -- two rings of one grid then differ in the last ulp, where the
+    tests assert them bitwise equal.  Mosaic has no lowering for the
+    barrier, so compiled kernels go without it."""
     return jax.lax.optimization_barrier(cur) if interpret else cur
 
 
@@ -1459,7 +1301,8 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
     """Execute one launch geometry: THE place every substrate kind lowers
     through.  Grid, BlockSpecs, scratch, ring slots, fire step and read
     window all come from ``lg`` -- the kernel body only dispatches on
-    whether a scratch exists (foil assembly vs ring assembly).
+    whether a scratch exists (the ring) or not (the lifted 1D "flat"
+    launch, whose one block is its whole region).
 
     ``compute(cur, edges, *const_refs)`` receives the f32 halo-extended
     region and the per-axis domain-edge flags (``None`` on all-periodic
@@ -1471,9 +1314,9 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
     in-kernel trace scopes in (``repro.core.trace``), each a
     ``tpu.trace_start``/``trace_stop`` pair:
 
-      * ``repro.substrate.assemble`` -- scratch kinds: every grid step's
-        store of its fetched block into its ring slot; scratch-free kinds:
-        the foil's concat of the fetched neighbor blocks (and its cast);
+      * ``repro.substrate.assemble`` -- ring kinds: every grid step's
+        store of its fetched block into its ring slot; "flat": the read
+        of its block (and its cast);
       * ``repro.kernel.compute`` -- the fire step: the read of the
         assembled region, ``compute`` (every fused step's fills and tap
         sums or contractions) and the store to the output block.
@@ -1484,7 +1327,7 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
     out_dtype = x.dtype
     rank = len(lg.grid)
     zero_map = _ZERO_INDEX_MAPS[rank]
-    in_specs = ([pl.BlockSpec(lg.in_block, im) for im in lg.in_index_maps]
+    in_specs = ([pl.BlockSpec(lg.in_block, lg.in_index_map)]
                 + [pl.BlockSpec(c.shape, zero_map((0,) * c.ndim))
                    for c in consts])
     src = x
@@ -1495,17 +1338,15 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
         src = _extend_columns_for_tiling(
             x, lg.block_dims[-1], lg.grid[-2], lg.out_block[-1],
             mode=lg.boundary[-1] if lg.boundary else "periodic")
-    n_in = len(lg.in_index_maps)
     edged = lg.boundary and not lg.periodic
 
     if lg.scratch_shape is None:
-        def kern(*refs):
-            ins = refs[:n_in]
-            *const_refs, out_ref = refs[n_in:]
+        def kern(blk_ref, *rest):
+            *const_refs, out_ref = rest
             edges = _edge_flags(lg) if edged else None
             with trace.scope(trace.SUBSTRATE_ASSEMBLE, scopes):
-                cur = _pin_region(
-                    _assemble_foil(lg, ins).astype(jnp.float32), interpret)
+                cur = _pin_region(blk_ref[...].astype(jnp.float32),
+                                  interpret)
             with trace.scope(trace.KERNEL_COMPUTE, scopes):
                 out_ref[...] = compute(cur, edges,
                                        *const_refs).astype(out_dtype)
@@ -1548,7 +1389,7 @@ def _launch(lg: LaunchGeometry, compute, x: jax.Array, interpret: bool,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name=name,
         **extra,
-    )(*((src,) * n_in), *consts)
+    )(src, *consts)
     if lg.out_shape != x.shape:
         y = y[..., : x.shape[-1]]
     return y
@@ -1559,22 +1400,21 @@ def strip_substrate_call(compute, x: jax.Array, strip_m: int, h_block: int,
                          w_tile: int = 0, w_block: int = 0,
                          x_halo: int = 0, boundary=None, name: str = None,
                          scopes: bool = False) -> jax.Array:
-    """Launch ``compute`` over every output strip, on any halo substrate.
+    """Launch ``compute`` over every output strip of a 2D (or lifted 1D)
+    grid.
 
-    The ONE place both strip kernels lower through -- substrate changes
-    (semantics, buffering, a third scheme) happen here, never per kernel.
+    The ONE place the strip kernels lower through -- substrate changes
+    (semantics, buffering) happen here, never per kernel.
     ``compute(cur, edges, *const_refs)`` receives the f32 halo-extended
     region, the per-axis domain-edge flags (``None`` on all-periodic
     launches) plus one VMEM ref per ``consts`` operand (operands
     constant across the grid, e.g. banded weights) and returns the
     output region; the launcher casts back to ``x.dtype``.  ``boundary``
     is the per-axis mode pair threaded into the launch geometry
-    (DESIGN.md §15).  ``h_block=0`` runs the
-    whole-strip 3-load pipeline; otherwise the sub-blocked
-    (strip, h-block) grid with VMEM scratch assembly (module docstring).
-    ``halo=0`` (the lifted-1D case: no vertical support at all) drops
-    the neighbor loads entirely on either substrate -- each strip
-    streams only its own rows, read amplification exactly 1.
+    (DESIGN.md §15).  The launch is the (strip, h-block) ring with VMEM
+    scratch assembly (module docstring); ``halo=0`` (the lifted-1D case:
+    no vertical support at all) drops the neighbor loads entirely --
+    each strip streams only its own rows, read amplification exactly 1.
 
     Full width (``w_tile=0``): ``compute`` maps (strip_m + 2*halo, n) ->
     (strip_m, n) and re-wraps the x-halo in-VMEM itself (every row is a
@@ -1638,7 +1478,7 @@ def slab_substrate_call(compute, x: jax.Array, geom: SubstrateGeom,
                         x_halo: int = 0, boundary=None, name: str = None,
                         scopes: bool = False) -> jax.Array:
     """Launch ``compute`` over every (z-slab, strip) output cell of a 3D
-    grid, on either halo-plane substrate (module docstring, DESIGN.md §9).
+    grid (module docstring, DESIGN.md §9).
 
     The 3D analogue of ``strip_substrate_call`` -- and like it, the ONE
     place the 3D kernels lower through.
@@ -1646,16 +1486,14 @@ def slab_substrate_call(compute, x: jax.Array, geom: SubstrateGeom,
     receives the (z_slab + 2*halo, strip_m + 2*halo, W) f32 halo-extended
     slab (periodic in z and y via the modulo index maps; the x-wrap is the
     kernels' own in-VMEM job) and returns the (z_slab, strip_m, W) output
-    slab.  ``geom.h_block=0`` runs the whole-slab foil: 3x3 full neighbor
-    slabs referenced through nine shifted index maps (9x reads).
-    Otherwise the sub-blocked scheme: ONE (z_block, h_block, W) input
-    reference walks, for output cell (iz, iy), the
-    (z_slab/z_block + 2) x (strip_m/h_block + 2) block ring -- own blocks
-    plus the single neighbor blocks that can contain halo planes/rows --
-    into a VMEM scratch of (z_slab + 2*z_block, strip_m + 2*h_block, W);
-    compute fires on the ring's final block (``pl.when``).  Both paths
-    assemble byte-identical extended slabs, so (with ``_pin_region``
-    between assembly and compute in interpret mode) their outputs are
+    slab.  ONE (z_block, h_block, W) input reference walks, for output
+    cell (iz, iy), the (z_slab/z_block + 2) x (strip_m/h_block + 2) block
+    ring -- own blocks plus the single neighbor blocks that can contain
+    halo planes/rows -- into a VMEM scratch of
+    (z_slab + 2*z_block, strip_m + 2*h_block, W); compute fires on the
+    ring's final block (``pl.when``).  Every ring of one grid assembles
+    byte-identical extended slabs, so (with ``_pin_region`` between
+    assembly and compute in interpret mode) their outputs are
     bit-for-bit equal.
 
     ``geom.w_tile`` > 0 selects the column-tiled scheme (DESIGN.md §10):
@@ -1700,21 +1538,18 @@ def fold_batch(run, mode: str):
 
 
 def substrate_read_amp(strip_m: int, h_block: int) -> float:
-    """Analytic grid-read amplification of one kernel launch.
+    """Analytic grid-read amplification of one ring axis.
 
-    Sub-blocked substrate: each output strip streams its own rows once plus
-    one h-block of each vertical neighbor -> 1 + 2*h_block/strip_m.
-    Whole-strip substrate (``h_block=0``): 3 full strips -> 3.0.  ``None``
-    is rejected: everywhere else in the kernel API it means "auto", which
-    this function cannot resolve (it has no halo) -- resolve first
-    (``choose_hblock``) or pass 0 explicitly.
+    Each output strip streams its own rows once plus one h-block of each
+    vertical neighbor -> 1 + 2*h_block/strip_m (3.0 at h_block =
+    strip_m, three full strips).  ``None`` is rejected: everywhere else
+    in the kernel API it means "auto", which this function cannot
+    resolve (it has no halo) -- resolve first (``choose_hblock``); 0
+    names no ring.
     """
-    if h_block is None:
-        raise ValueError("h_block=None is 'auto' in the kernel API; resolve "
-                         "it via choose_hblock first, or pass 0 for the "
-                         "whole-strip substrate")
-    if h_block == 0:
-        return float(STRIP_NEIGHBOR_LOADS)
+    if not h_block:
+        raise ValueError(f"h_block={h_block!r} names no block ring; 'auto' "
+                         "(None) must be resolved via choose_hblock first")
     return 1.0 + 2.0 * h_block / strip_m
 
 
@@ -1730,11 +1565,10 @@ def resolve_strip_blocks(grid_shape, halo: int, dtype_bytes: int,
     can never drift apart.  ``tile_m=None`` sizes both jointly
     (``choose_strip_blocks``); an explicit ``tile_m`` is clamped to the
     grid and, when ``h_block`` is also ``None``, gets ``choose_hblock``
-    of the clamped strip.  ``h_block=0`` passes through (whole-strip).
+    of the clamped strip.
 
-    Width (DESIGN.md §10): full width (w_tile=0) whenever pinned so, the
-    foil substrate is requested (h_block=0, full-width by construction),
-    or the full-width working set fits the VMEM budget; otherwise the
+    Width (DESIGN.md §10): full width (w_tile=0) whenever pinned so or
+    the full-width working set fits the VMEM budget; otherwise the
     column-tiled joint sizing ``choose_col_blocks`` runs, conditioned on
     any strip/width pins.
     """
@@ -1745,7 +1579,7 @@ def resolve_strip_blocks(grid_shape, halo: int, dtype_bytes: int,
         # Uniform lone-pin rejection: a w_block without a w_tile names no
         # substrate on EITHER resolution path -- acceptance must not flip
         # with the VMEM budget (the auto w_tile need not be divisible).
-        _resolve_w_block(0, w_block, h_block, xh)
+        _resolve_w_block(0, w_block, xh)
     budget = vmem_budget_bytes()
     sub = sublane_tile(dtype_bytes)
 
@@ -1761,40 +1595,34 @@ def resolve_strip_blocks(grid_shape, halo: int, dtype_bytes: int,
                 else auto_hb
         return strip_m, hb
 
-    if w_tile == 0 or h_block == 0:
+    if w_tile == 0:
         sm, hb = fullwidth()
-        # Reject lone w_block pins, and column tiling pinned onto the
-        # full-width-by-construction foil substrate.
-        _resolve_w_block(w_tile if h_block == 0 else 0, w_block, hb, xh)
+        _resolve_w_block(0, w_block, xh)    # rejects a lone w_block pin
         return sm, hb, 0, 0
     if w_tile is None:
         sm, hb = fullwidth()
-        ws_hb = hb if hb else choose_hblock(sm, halo, sub)
-        if _strip_working_set(sm, ws_hb, wid, halo, dtype_bytes) <= budget:
-            _resolve_w_block(0, w_block, hb, xh)
+        if _strip_working_set(sm, hb, wid, halo, dtype_bytes) <= budget:
             return sm, hb, 0, 0
     sm, auto_hb, wt, auto_wb = choose_col_blocks(
         h, wid, halo, xh, dtype_bytes, budget,
         m_pin=min(tile_m, h) if tile_m is not None else None,
         w_pin=w_tile)
     hb = h_block if h_block is not None else auto_hb
-    wt, wb = _resolve_w_block(wt, w_block if w_block else auto_wb, hb, xh)
+    wt, wb = _resolve_w_block(wt, w_block if w_block else auto_wb, xh)
     return sm, hb, wt, wb
 
 
 def hbm_read_bytes_per_step(shape, strip_m: int, dtype_bytes: int,
-                            bands_shape=None, h_block: int = 0,
+                            bands_shape=None, *, h_block: int,
                             w_tile: int = 0, w_block: int = 0) -> int:
     """Analytic HBM read traffic of one strip-substrate kernel launch.
 
-    Whole-strip (``h_block=0``, the default -- this is an analytic model
-    with no halo to auto-resolve from, so ``None`` is rejected just like
-    ``substrate_read_amp``): each of the ``h/strip_m`` grid cells streams
-    three (strip_m, n) blocks -> the grid is read 3x per step (vs 9x for
-    kernels.legacy).  Sub-blocked (``h_block > 0``): each output strip
-    streams ``strip_m/h_block + 2`` (h_block, n) blocks -> the grid is
-    read ``1 + 2*h_block/strip_m`` times.  Column-tiled (``w_tile`` > 0,
-    DESIGN.md §10): each of the ``(h/strip_m)(ceil(w/w_tile))`` output
+    Each output strip streams ``strip_m/h_block + 2`` (h_block, n) blocks
+    -> the grid is read ``1 + 2*h_block/strip_m`` times (3x at h_block =
+    strip_m; this analytic model has no halo to auto-resolve from, so
+    ``h_block`` is required, as in ``substrate_read_amp``).  Column-tiled
+    (``w_tile`` > 0, DESIGN.md §10): each of the
+    ``(h/strip_m)(ceil(w/w_tile))`` output
     tiles streams its (strip_m + 2*h_block, w_tile + 2*w_block) block
     neighborhood -> the product amplification
     (1 + 2*h_block/strip_m)(1 + 2*w_block/w_tile) on aligned widths
@@ -1808,8 +1636,7 @@ def hbm_read_bytes_per_step(shape, strip_m: int, dtype_bytes: int,
     h, w = shape
     gm = h // strip_m
     # One formula per axis: substrate_read_amp is the model (and rejects
-    # the h_block=None 'auto' sentinel); rows = strip_m * amp is exact
-    # (3*strip_m whole-strip, strip_m + 2*h_block sub-blocked).
+    # the h_block=None 'auto' sentinel); rows = strip_m + 2*h_block exactly.
     rows_per_strip = round(strip_m * substrate_read_amp(strip_m, h_block))
     if w_tile:
         gw = -(-w // w_tile)
@@ -1828,11 +1655,10 @@ def hbm_read_bytes_per_step_3d(shape, geom: SubstrateGeom, dtype_bytes: int,
                                bands_shape=None) -> int:
     """Analytic HBM read traffic of one 3D slab-substrate kernel launch.
 
-    Whole-slab foil (``geom.h_block=0``): each of the (Z/z_slab)(H/strip_m)
-    cells streams 9 full (z_slab, strip_m, W) slabs -> the grid is read 9x
-    per step.  Sub-blocked: each cell streams the
+    Each of the (Z/z_slab)(H/strip_m) cells streams the
     (z_slab + 2*z_block)(strip_m + 2*h_block) block ring -> the grid is
-    read (1 + 2*h_block/strip_m)(1 + 2*z_block/z_slab) times.
+    read (1 + 2*h_block/strip_m)(1 + 2*z_block/z_slab) times (9x at
+    z_block = z_slab, h_block = strip_m).
     Column-tiled (``geom.w_tile`` > 0): the x axis joins the ring and
     the amplification gains the (1 + 2*w_block/w_tile) factor
     (DESIGN.md §10).  The banded operand (if any) is charged once per

@@ -23,8 +23,9 @@ Degradation ladder
          budget halved, so ``resolve_substrate_geom`` shrinks
          strip_m / z_slab / w_tile)
       -> registry backends by ``fallback_rank``
-         (fused_matmul_reuse -> fused_matmul -> matmul -> fused_direct
-          -> direct -> *_wholestrip foils -> reference oracle)
+         (fused_matmul_reuse -> fused_sparse_matmul -> sparse_matmul
+          -> fused_matmul -> matmul -> fused_direct -> direct
+          -> reference oracle)
 
   Each rung failure is classified, recorded in the
   :mod:`repro.core.events` ring buffer, and noted in the plan module's

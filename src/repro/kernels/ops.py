@@ -21,11 +21,10 @@ Backends (see ``repro.kernels.registry``; any registered name is accepted)
                       rows -- K shrinks by the kept-row     under use_sparse_unit
                       fraction S, bitwise-equal outputs     -- DESIGN.md §14)
   reference           jnp oracle (debug)
-  legacy_direct/      seed 9-tile substrate (benchmark foil)
-  legacy_matmul
-  *_wholestrip        the five regimes on the whole-strip 3-load substrate
-                      (benchmark foils; default is halo-row sub-blocked)
   auto                selector decides among the priced backends
+
+Every Pallas backend runs on the one block-ring substrate; geometry pins
+(``tile_m``, ``h_block``, ...) choose its read pattern, not the backend.
 
 ``interpret`` defaults to True off-TPU so every path is CPU-checkable; on a
 real TPU it compiles through Mosaic.

@@ -605,7 +605,8 @@ def stencil_plan(
       tile_m/tile_n: explicit strip height / column-tile width (``None`` =
         auto-sized exactly as the kernels themselves would).
       h_block: halo sub-block height of the strip substrate (``None`` =
-        auto, ``0`` = whole-strip/whole-slab foil); part of the cache key.
+        auto; ``strip_m`` reads whole neighbor strips); part of the cache
+        key.
       z_slab/z_block: 3D grids only -- slab depth and halo-plane block of
         the halo-plane substrate (``None`` = auto); part of the cache key.
       w_tile/w_block: column-tiled W substrate (DESIGN.md §10; ``None`` =

@@ -2,9 +2,10 @@
 
 A *backend* is one way to advance the grid ``t`` time steps -- the five
 regimes of the strip substrate (VPU direct/fused, MXU sequential /
-monolithic / intermediate-reuse), the seed 9-tile foil (``legacy_*``), and
-the pure-jnp reference oracle all register here via :func:`register_backend`
-and are addressed by name from ``stencil_plan`` / ``stencil_apply``.
+monolithic / intermediate-reuse), their two sparse-compacted MXU
+variants, and the pure-jnp reference oracle all register here via
+:func:`register_backend` and are addressed by name from ``stencil_plan`` /
+``stencil_apply``.
 
 Each :class:`BackendDef` carries two callables:
 
@@ -20,10 +21,10 @@ Each :class:`BackendDef` carries two callables:
     backends instead of a hard-coded dict, so new regimes (e.g. a sparse
     unit) become selectable just by registering.
 
-The five strip regimes run on the halo-row sub-blocked substrate by
-default (kernels.common, DESIGN.md §3); each also registers a
-``*_wholestrip`` foil (3-load substrate, unpriced) for benchmarking and
-substrate-equivalence tests.
+Every Pallas regime runs on the one block-ring substrate (kernels.common,
+DESIGN.md §3); a caller who wants a coarser read pattern pins the ring
+(``h_block=strip_m`` reads whole neighbor strips) instead of picking
+another backend.
 """
 from __future__ import annotations
 
@@ -39,7 +40,6 @@ from repro.stencil.weights import fuse_weights
 from .common import (SubstrateGeom, check_tpu_tiling, choose_tile,
                      launch_geometry, resolve_substrate_geom,
                      validate_tiling)
-from . import legacy as _legacy
 from . import ref as _ref
 from .stencil_direct import stencil_direct
 from .stencil_matmul import build_bands_nd, stencil_matmul
@@ -62,7 +62,7 @@ class PlanContext:
     tile_n: Optional[int]
     interpret: bool
     compute_dtype: object = None
-    h_block: Optional[int] = None   # None = auto, 0 = whole-strip/slab foil
+    h_block: Optional[int] = None   # None = auto
     z_slab: Optional[int] = None    # 3D grids: slab depth (None = auto)
     z_block: Optional[int] = None   # 3D grids: halo-plane block (None = auto)
     w_tile: Optional[int] = None    # None = auto, 0 = full width (fast path)
@@ -174,9 +174,8 @@ class AuditSpec:
     """A backend's full audit declaration: its launches, in run order."""
 
     launches: Tuple[LaunchAudit, ...] = ()
-    #: Non-None opts the backend out with a recorded reason (the seed
-    #: foils predate the substrate model; the reference oracle has no
-    #: launch structure to audit).
+    #: Non-None opts the backend out with a recorded reason (the
+    #: reference oracle has no launch structure to audit).
     exempt: Optional[str] = None
 
 
@@ -257,14 +256,6 @@ def _audit_fused_sparse_matmul(ctx: PlanContext) -> AuditSpec:
     return AuditSpec(launches=(l,))
 
 
-def _wholestrip_audit(audit: Callable) -> Callable:
-    """Audit the same regime on the whole-strip substrate (h_block=0),
-    mirroring :func:`_wholestrip` exactly."""
-    def audit_ws(ctx: PlanContext) -> AuditSpec:
-        return audit(dataclasses.replace(ctx, h_block=0))
-    return audit_ws
-
-
 def _audit_exempt(reason: str) -> Callable:
     def audit(ctx: PlanContext) -> AuditSpec:
         return AuditSpec(exempt=reason)
@@ -280,9 +271,9 @@ class BackendDef:
     unit: Optional[str] = None         # "vector" | "matrix" | None (other)
     #: Position on the guard layer's degradation ladder (DESIGN.md §11):
     #: lower = more aggressive, higher = more conservative.  ``None`` means
-    #: the backend is never a fallback target (legacy 2D-only foils,
-    #: matmul wholestrip foils).  The reference oracle carries the largest
-    #: rank so the ladder always terminates on it.
+    #: the backend is never a fallback target (plug-ins that ask for no
+    #: rank).  The reference oracle carries the largest rank so the ladder
+    #: always terminates on it.
     fallback_rank: Optional[int] = None
     #: ``audit(ctx) -> AuditSpec`` declares the backend's launches for the
     #: static auditor (repro.audit); ``None`` means "not yet auditable"
@@ -374,7 +365,7 @@ def fallback_ladder(after: Optional[str] = None) -> Tuple[str, ...]:
 
     ``after=name`` returns only the rungs strictly more conservative than
     ``name`` -- the remaining ladder once ``name`` has failed.  A backend
-    with no rank (foils, plug-ins) yields the FULL ladder: an unranked
+    with no rank (plug-ins) yields the FULL ladder: an unranked
     regime that fails falls back onto the standard sequence from the top.
     """
     ranked = sorted((bd for bd in _REGISTRY.values()
@@ -391,7 +382,7 @@ def fallback_ladder(after: Optional[str] = None) -> Tuple[str, ...]:
 
 
 # ---------------------------------------------------------------------------
-# Builders: the five strip-substrate regimes + reference + legacy foil.
+# Builders: the strip-substrate regimes + reference.
 # Each resolves its tiling/operands at build time and closes over them, so
 # plan execution re-derives nothing.
 # ---------------------------------------------------------------------------
@@ -518,61 +509,12 @@ def _build_fused_sparse_matmul(ctx: PlanContext) -> Callable:
     return run
 
 
-def _wholestrip(build: Callable) -> Callable:
-    """Same regime on the whole-strip (3-load) substrate: force h_block=0."""
-    def build_ws(ctx: PlanContext) -> Callable:
-        return build(dataclasses.replace(ctx, h_block=0))
-    return build_ws
-
-
-def _require_2d(ctx: PlanContext, name: str) -> None:
-    if len(ctx.grid_shape) != 2:
-        raise ValueError(
-            f"backend {name!r} is the seed 2D 9-tile foil and supports only "
-            f"2D grids, got rank {len(ctx.grid_shape)}; use the halo-plane "
-            "substrate regimes (direct/matmul families) for 1D/3D")
-    if not is_periodic(ctx.boundary):
-        raise ValueError(
-            f"backend {name!r} is the seed periodic-only foil and does not "
-            f"support boundary={ctx.boundary!r}; use the halo-plane "
-            "substrate regimes (direct/matmul families) for non-periodic "
-            "boundaries (DESIGN.md §15)")
-
-
-def _build_legacy_direct(ctx: PlanContext) -> Callable:
-    """Seed 9-neighbor full-tile VPU scheme (benchmark foil)."""
-    _require_2d(ctx, "legacy_direct")
-    w, t = ctx.weights, ctx.t
-    tile_m = 128 if ctx.tile_m is None else ctx.tile_m
-    tile_n = 128 if ctx.tile_n is None else ctx.tile_n
-    interp = ctx.interpret
-
-    def run(x):
-        return _legacy.stencil_direct_9pt(x, w, t=t, tile_m=tile_m,
-                                          tile_n=tile_n, interpret=interp)
-    return run
-
-
-def _build_legacy_matmul(ctx: PlanContext) -> Callable:
-    """Seed 9-neighbor monolithic MXU scheme on the composed kernel."""
-    _require_2d(ctx, "legacy_matmul")
-    wf = ctx.fused_weights()
-    tile_m = 128 if ctx.tile_m is None else ctx.tile_m
-    tile_n = 128 if ctx.tile_n is None else ctx.tile_n
-    interp, cdt = ctx.interpret, ctx.compute_dtype
-
-    def run(x):
-        return _legacy.stencil_matmul_9pt(x, wf, tile_m=tile_m, tile_n=tile_n,
-                                          interpret=interp, compute_dtype=cdt)
-    return run
-
-
 # ---------------------------------------------------------------------------
 # Pricers: the selector's candidate set, one per selectable regime.  The
 # unfused/fused VPU and MXU pairs share a throughput model and partition on
 # fusion depth, preserving the historical candidate naming (``direct`` vs
-# ``fused_direct`` etc.).  Legacy and reference backends are unpriced: they
-# exist for benchmarking/debugging and must never win selection.
+# ``fused_direct`` etc.).  The reference backend is unpriced: it exists
+# for debugging and must never win selection.
 # ---------------------------------------------------------------------------
 def _price_direct(p):
     return p.comparison.vector.actual_flops if p.workload.t == 1 else None
@@ -627,7 +569,7 @@ def _price_fused_sparse_matmul(p):
 # Fallback ranks order the degradation ladder from most aggressive (deep
 # fusion, MXU, VMEM-hungry) to most conservative (reference oracle): each
 # rung drops one source of fragility -- intermediate reuse, then the MXU,
-# then temporal fusion, then halo-row sub-blocking, then Pallas entirely.
+# then temporal fusion, then Pallas entirely.
 register_backend("direct", _build_direct, _price_direct,
                  "t sequential VPU kernel steps (halo r per step)",
                  unit="vector", fallback_rank=50, audit=_audit_direct)
@@ -660,36 +602,3 @@ register_backend("reference", _build_reference,
                  description="pure-jnp oracle (debug)", fallback_rank=1000,
                  audit=_audit_exempt("pure-jnp oracle: no launch structure "
                                      "to audit"))
-register_backend("legacy_direct", _build_legacy_direct,
-                 description="seed 9-tile VPU scheme (benchmark foil)",
-                 unit="vector",
-                 audit=_audit_exempt("seed 9-tile foil predates the "
-                                     "substrate traffic model"))
-register_backend("legacy_matmul", _build_legacy_matmul,
-                 description="seed 9-tile monolithic MXU scheme (foil)",
-                 unit="matrix",
-                 audit=_audit_exempt("seed 9-tile foil predates the "
-                                     "substrate traffic model"))
-
-# Whole-strip (3-load) substrate foils: the same five regimes with halo-row
-# sub-blocking disabled, unpriced so they never win selection -- they exist
-# so benchmarks/traffic.py can measure seed / whole-strip / sub-blocked
-# three ways and tests can assert bit-for-bit substrate equivalence.
-# The direct-family wholestrip foils also serve as the ladder's
-# penultimate rungs (DESIGN.md §11): after every sub-blocked regime has
-# failed, the 3-load substrate drops halo-row sub-blocking -- the last
-# Pallas configuration before surrendering to the reference oracle.
-for _name, _build, _audit, _unit, _rank in (
-    ("direct", _build_direct, _audit_direct, "vector", 60),
-    ("fused_direct", _build_fused_direct, _audit_fused_direct, "vector", 55),
-    ("matmul", _build_matmul, _audit_matmul, "matrix", None),
-    ("fused_matmul", _build_fused_matmul, _audit_fused_matmul,
-     "matrix", None),
-    ("fused_matmul_reuse", _build_fused_matmul_reuse,
-     _audit_fused_matmul_reuse, "matrix", None),
-):
-    register_backend(f"{_name}_wholestrip", _wholestrip(_build),
-                     description=f"{_name} on the whole-strip 3-load "
-                                 "substrate (benchmark foil)",
-                     unit=_unit, fallback_rank=_rank,
-                     audit=_wholestrip_audit(_audit))

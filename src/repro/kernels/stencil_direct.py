@@ -9,8 +9,7 @@ scratch -- the cell's own blocks plus the single ring of neighbor blocks
 that can contain halo planes/rows -- and the final cell computes on the
 assembled halo-extended region, so HBM reads per step are
 (1 + 2*h_block/strip_m) x the grid in 2D and additionally
-(1 + 2*z_block/z_slab) x in 3D, instead of 3x/9x (whole neighbor strips/
-slabs) or 9x (seed scheme).  The stencil is an unrolled sum of shifted
+(1 + 2*z_block/z_slab) x in 3D.  The stencil is an unrolled sum of shifted
 slices times scalar taps -- pure element-wise VPU work, accumulated in
 f32.  1D grids route through the 2D substrate lifted to (1, N): the
 vertical halo is zero, so strips stream only their own rows.
@@ -32,10 +31,6 @@ stays flat while compute scales by t (I = t*K/D).  Because every row of
 the extended region is a true global row, the last-axis wrap is re-applied
 per step at radius ``r`` -- no 2*t*r horizontal halo is ever carried.
 This kernel IS `stencil_fused`'s engine; ``t=1`` is the plain baseline.
-
-``h_block=0`` selects the whole-strip/whole-slab foil substrate (kept for
-the ``*_wholestrip`` benchmark foils); both substrates assemble
-byte-identical extended regions, so their outputs are bit-for-bit equal.
 
 Grids whose FULL-WIDTH working set exceeds the VMEM budget execute on
 the column-tiled substrate (DESIGN.md §10): the grid gains a
@@ -197,8 +192,8 @@ def stencil_direct(
     when full width exceeds the VMEM budget) -- any left ``None``
     (default) is auto-sized via ``resolve_substrate_geom`` (divisors,
     halo-covering, VMEM-budgeted); explicit values are validated
-    strictly.  ``h_block=0`` disables sub-blocking (whole-strip 3-load /
-    whole-slab 9-load foil substrate).  ``tile_n`` is accepted for
+    strictly (``h_block=tile_m`` reads whole neighbor strips).  ``tile_n``
+    is accepted for
     signature parity with the MXU kernel but unused (the VPU path's only
     column tiling is the substrate's own).
     """
@@ -207,14 +202,15 @@ def stencil_direct(
     if x.ndim != w.ndim:
         raise ValueError(f"grid rank {x.ndim} != kernel rank {w.ndim}")
     if x.ndim == 1:
-        # The lifted (1, N) grid admits exactly two h_blocks (0 = foil,
-        # 1 = sub-blocked) and never column-tiles; coerce like
-        # resolve_substrate_geom's dim-1 rule so kernel-level and
-        # plan-level pins can never disagree.  The synthetic row axis is
-        # periodic (it has no halo); the real axis keeps its mode.
-        hb = h_block if h_block in (None, 0) else 1
+        # The lifted (1, N) grid's only h_block is 1 and it never
+        # column-tiles: a positive pin coerces to 1 like
+        # resolve_substrate_geom's dim-1 rule (0 is refused there too),
+        # so kernel-level and plan-level pins can never disagree.  The
+        # synthetic row axis is periodic (it has no halo); the real axis
+        # keeps its mode.
         y = stencil_direct(x[None, :], w[None, :], t=t, tile_m=1,
-                           h_block=hb, w_tile=0, interpret=interpret,
+                           h_block=h_block and 1, w_tile=0,
+                           interpret=interpret,
                            boundary=lift_boundary_1d(boundary),
                            name=name, scopes=scopes)
         return y[0]

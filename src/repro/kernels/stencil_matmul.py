@@ -24,10 +24,8 @@ Transformation (DESIGN.md §2):
 The substrate is the halo-row sub-blocked strip pipeline (kernels.common,
 DESIGN.md §3): a 2D (strip, h-block) grid assembles each output strip's
 halo-extended rows from (h_block, N) blocks -- (1 + 2*h_block/strip_m)x
-HBM reads per step -- with the horizontal halo wrapped in-VMEM.
-``h_block=0`` selects the whole-strip 3-load substrate (the
-``*_wholestrip`` benchmark foils); both assemble byte-identical extended
-strips, so outputs are bit-for-bit equal.  Widths exceeding the VMEM
+HBM reads per step -- with the horizontal halo wrapped in-VMEM.  Widths
+exceeding the VMEM
 budget column-tile the last axis too (DESIGN.md §10): the contraction
 then consumes a CARRIED 2*t*r x-halo instead of re-wrapping, and a
 final chunk narrower than ``tile_n`` (awkward/prime widths -- the
@@ -244,8 +242,7 @@ def stencil_matmul(
     widths not divisible by ``tile_n`` contract a narrower final chunk
     against the operand's leading submatrix, so awkward/prime widths
     keep full-size chunks -- the ``choose_tile`` cap policy);
-    ``h_block`` the halo sub-block height (``None`` = auto, 0 =
-    whole-strip/whole-slab foil substrate); 3D grids add
+    ``h_block`` the halo sub-block height (``None`` = auto); 3D grids add
     ``z_slab``/``z_block``; 2D/3D grids add ``w_tile``/``w_block`` (the
     column-tiled W substrate, DESIGN.md §10 -- each step then consumes a
     carried x-halo instead of re-wrapping).  Any left ``None`` is
@@ -258,9 +255,8 @@ def stencil_matmul(
     if x.ndim == 1:
         # coerce h_block exactly like resolve_substrate_geom's dim-1 rule
         # (see stencil_direct); 1D never column-tiles
-        hb = h_block if h_block in (None, 0) else 1
         y = stencil_matmul(x[None, :], w[None, :], t=t, tile_m=1,
-                           tile_n=tile_n, h_block=hb, w_tile=0,
+                           tile_n=tile_n, h_block=h_block and 1, w_tile=0,
                            interpret=interpret, compute_dtype=compute_dtype,
                            boundary=lift_boundary_1d(boundary),
                            name=name, scopes=scopes)

@@ -224,9 +224,10 @@ def stencil_sparse_matmul(
     if x.ndim != w.ndim:
         raise ValueError(f"grid rank {x.ndim} != kernel rank {w.ndim}")
     if x.ndim == 1:
-        hb = h_block if h_block in (None, 0) else 1
+        # coerce h_block like the other kernels' 1D lift
         y = stencil_sparse_matmul(x[None, :], w[None, :], t=t, tile_m=1,
-                                  tile_n=tile_n, h_block=hb, w_tile=0,
+                                  tile_n=tile_n, h_block=h_block and 1,
+                                  w_tile=0,
                                   interpret=interpret,
                                   compute_dtype=compute_dtype,
                                   boundary=lift_boundary_1d(boundary),

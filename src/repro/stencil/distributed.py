@@ -454,9 +454,8 @@ def pallas_local_apply(
     rarely tile-aligned, and the padding rows only feed the discarded
     halo ring.  Pass explicit tiles to pin the geometry instead (the
     block is then used as is).  ``h_block``/``z_block`` select the halo
-    block heights of the substrate (``None`` = auto, ``h_block=0`` =
-    whole-strip/whole-slab foil) -- the modulo wrap of either substrate is
-    equally harmless here.  ``w_tile``/``w_block`` select the column-tiled
+    block heights of the substrate (``None`` = auto) -- the ring's modulo
+    wrap is harmless here.  ``w_tile``/``w_block`` select the column-tiled
     W substrate (DESIGN.md §10) for W-sharded meshes whose local width
     still exceeds VMEM (``None`` = auto: full width whenever it fits the
     budget); the column walk's wrap is as harmless as the row wrap -- it

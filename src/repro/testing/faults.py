@@ -217,13 +217,12 @@ def corrupt_geometry(lg):
     for spec in active_faults():
         if spec.kind == "geometry" and spec.should_fire():
             import dataclasses
-            orig = lg.in_index_maps[0]
+            orig = lg.in_index_map
 
             def warped(*ix):
                 j = ix[-1]
                 return orig(*ix[:-1], j - 1 if isinstance(j, int) and j > 0
                             else j)
 
-            return dataclasses.replace(
-                lg, in_index_maps=(warped,) + lg.in_index_maps[1:])
+            return dataclasses.replace(lg, in_index_map=warped)
     return lg

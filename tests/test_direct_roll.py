@@ -37,7 +37,9 @@ def _x(shape, seed=0):
     (("box", 3, 1), (16, 16, 128), 2, "fused_direct", {}),
     # the 2x2 cell's local block (10240 + 8 wide), scaled down
     (("box", 2, 1), (264, 264), 4, "fused_direct", {}),
-    (("box", 2, 1), (64, 256), 2, "fused_direct", {"h_block": 0}),
+    # the ring pinned to whole neighbor strips
+    (("box", 2, 1), (64, 256), 2, "fused_direct",
+     {"tile_m": 32, "h_block": 32}),
 ], ids=["box2d1r-t1", "box2d1r-t4", "box2d3r-t1", "box3d1r-t2",
         "width264-t4", "wholestrip-t2"])
 def test_rolled_kernel_is_bitwise_the_oracle(spec, grid, t, backend, pins):
@@ -95,17 +97,19 @@ def _kernel_counts(spec, grid, t, backend, **kw):
     return counts
 
 
-@pytest.mark.parametrize("spec, grid, t, backend, steps", [
-    (("box", 2, 1), (64, 256), 4, "fused_direct", 4),
-    (("box", 2, 1), (64, 256), 2, "direct", 1),
-    (("box", 2, 3), (64, 256), 1, "direct", 1),
-    (("box", 3, 1), (16, 16, 128), 2, "fused_direct", 2),
-    (("box", 2, 1), (64, 256), 2, "fused_direct_wholestrip", 2),
+@pytest.mark.parametrize("spec, grid, t, backend, steps, pins", [
+    (("box", 2, 1), (64, 256), 4, "fused_direct", 4, {}),
+    (("box", 2, 1), (64, 256), 2, "direct", 1, {}),
+    (("box", 2, 3), (64, 256), 1, "direct", 1, {}),
+    (("box", 3, 1), (16, 16, 128), 2, "fused_direct", 2, {}),
+    (("box", 2, 1), (64, 256), 2, "fused_direct", 2,
+     {"tile_m": 32, "h_block": 32}),
 ], ids=["box2d1r-fused-t4", "box2d1r-direct-t2", "box2d3r", "box3d1r",
         "wholestrip"])
-def test_box_kernels_trace_2r_rolls_per_step(spec, grid, t, backend, steps):
+def test_box_kernels_trace_2r_rolls_per_step(spec, grid, t, backend, steps,
+                                             pins):
     """2r rolls per fused step of each kernel, no last-axis concat."""
-    for rolls, concat_x in _kernel_counts(spec, grid, t, backend):
+    for rolls, concat_x in _kernel_counts(spec, grid, t, backend, **pins):
         assert rolls == 2 * spec[2] * steps
         assert concat_x == 0
 

@@ -201,12 +201,11 @@ class TestLadder:
         ladder = fallback_ladder()
         assert ladder[0] == "fused_matmul_reuse"
         assert ladder[-1] == "reference"
-        assert ladder.index("fused_matmul") < ladder.index("matmul") \
-            < ladder.index("fused_direct") < ladder.index("direct") \
-            < ladder.index("fused_direct_wholestrip") \
-            < ladder.index("direct_wholestrip")
-        # unranked names fall back onto the FULL ladder
-        assert fallback_ladder(after="legacy_direct") == ladder
+        assert ladder == ("fused_matmul_reuse", "fused_sparse_matmul",
+                          "sparse_matmul", "fused_matmul", "matmul",
+                          "fused_direct", "direct", "reference")
+        # unknown or unranked names fall back onto the FULL ladder
+        assert fallback_ladder(after="no_such_backend") == ladder
 
     def test_clean_run_is_invisible(self):
         p0 = stencil_plan(W, X.shape, np.float32, 2, backend="fused_direct")

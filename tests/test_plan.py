@@ -28,30 +28,40 @@ def _hms(stats=None):
     return {k: s[k] for k in ("hits", "misses", "size")}
 
 
+#: Every registered backend at its auto ring, plus the five core regimes
+#: with the ring pinned to whole neighbor strips (h_block = strip_m = 32).
+CORE_BACKENDS = ("direct", "fused_direct", "matmul", "fused_matmul",
+                 "fused_matmul_reuse")
+BACKEND_CASES = ([pytest.param(b, {}, id=b) for b in BACKENDS if b != "auto"]
+                 + [pytest.param(b, {"h_block": 32}, id=f"{b}-h_block=strip_m")
+                    for b in CORE_BACKENDS])
+
+
 class TestPlanExecution:
-    @pytest.mark.parametrize("backend", [b for b in BACKENDS if b != "auto"])
-    def test_every_registered_backend_executes(self, backend):
-        """plan(x) runs all five regimes + reference + legacy via the
-        registry and matches the oracle."""
+    @pytest.mark.parametrize("backend, pins", BACKEND_CASES)
+    def test_every_registered_backend_executes(self, backend, pins):
+        """plan(x) runs every registered regime + reference via the
+        registry, and the core regimes on the whole-strip pin, and
+        matches the oracle."""
         w = make_weights(StencilSpec("box", 2, 1), seed=1)
         x = _x(64, 64)
         t = 3
         plan = stencil_plan(w, x.shape, x.dtype, t, backend=backend,
-                            tile_m=32, tile_n=32)
+                            tile_m=32, tile_n=32, **pins)
         ref = stencil_direct_ref(x, w, t)
         np.testing.assert_allclose(np.asarray(plan(x)), np.asarray(ref),
                                    atol=1e-4)
 
-    @pytest.mark.parametrize("backend", [b for b in BACKENDS if b != "auto"])
-    def test_wrapper_parity_bitwise(self, backend):
+    @pytest.mark.parametrize("backend, pins", BACKEND_CASES)
+    def test_wrapper_parity_bitwise(self, backend, pins):
         """stencil_apply == direct plan execution, bit-for-bit in f32."""
         w = make_weights(StencilSpec("star", 2, 2), seed=2)
         x = _x(64, 64)
         t = 2
         plan = stencil_plan(w, x.shape, x.dtype, t, backend=backend,
-                            tile_m=32, tile_n=32)
+                            tile_m=32, tile_n=32, **pins)
         via_wrapper = stencil_apply(x, w, t=t, backend=backend,
-                                    tile_m=32, tile_n=32)
+                                    tile_m=32, tile_n=32, **pins)
         assert np.array_equal(np.asarray(plan(x)), np.asarray(via_wrapper))
 
     def test_step_and_run(self):
@@ -247,10 +257,10 @@ class TestSingleDecisionPath:
 
     def test_explain_with_grid_threads_pins(self):
         """Explicit tile_m/h_block pins resolve identically in explain and
-        stencil_plan -- including h_block=0 (whole-strip pricing)."""
+        stencil_plan -- including the whole-strip pin h_block=strip_m."""
         w = make_weights(StencilSpec("box", 2, 1), seed=3)
         grid = (64, 64)
-        for pins in ({"h_block": 0}, {"tile_m": 16},
+        for pins in ({"tile_m": 32, "h_block": 32}, {"tile_m": 16},
                      {"tile_m": 32, "h_block": 8}):
             plan = stencil_plan(w, grid, np.float32, 2, **pins)
             d = explain(w, 2, dtype_bytes=4, hw=plan.hw, grid_shape=grid,
@@ -263,7 +273,6 @@ class TestSingleDecisionPath:
         assert set(d.candidates) <= set(registered_backends())
         # unpriced backends never show up as candidates
         assert "reference" not in d.candidates
-        assert "legacy_direct" not in d.candidates
 
 
 class TestPlan3D:
@@ -284,12 +293,6 @@ class TestPlan3D:
         t = 2
         ref = stencil_direct_ref(x, w, t)
         for backend in registered_backends():
-            if backend.startswith("legacy_"):
-                # the seed 9-tile foil is 2D-only by contract
-                with pytest.raises(ValueError, match="2D"):
-                    stencil_plan(w, x.shape, x.dtype, t, backend=backend,
-                                 use_cache=False)
-                continue
             plan = stencil_plan(w, x.shape, x.dtype, t, backend=backend)
             np.testing.assert_allclose(np.asarray(plan(x)), np.asarray(ref),
                                        atol=2e-4)
@@ -314,8 +317,9 @@ class TestPlan3D:
             assert d == plan.decision
             assert "read_amp=" in d.reason
             assert "z_slab=" in d.reason and "strip_m=" in d.reason
-        # pins thread identically, including the whole-slab foil
-        for pins in ({"h_block": 0}, {"tile_m": 12, "z_slab": 6},
+        # pins thread identically, including the whole-slab pin
+        for pins in ({"tile_m": 12, "h_block": 12, "z_slab": 6,
+                      "z_block": 6}, {"tile_m": 12, "z_slab": 6},
                      {"tile_m": 12, "h_block": 2, "z_slab": 6,
                       "z_block": 2}):
             plan = stencil_plan(w, (12, 24, 32), np.float32, 2, **pins)
@@ -351,10 +355,9 @@ class TestPlan3D:
             stencil_plan(w, (12, 24, 32), np.float32, 1)
 
     def test_hybrid_substrate_rejected_everywhere(self):
-        """z_block=0 under a sub-blocked h_block names a substrate no
-        kernel implements: the selector must refuse to price it exactly
-        like resolve_substrate_geom refuses to build it (single-decision-
-        path contract, with or without a grid)."""
+        """z_block=0 names no block ring: the selector must refuse to
+        price it exactly like resolve_substrate_geom refuses to build it
+        (single-decision-path contract, with or without a grid)."""
         w = make_weights(StencilSpec("box", 3, 1), seed=0)
         with pytest.raises(ValueError, match="whole-slab"):
             explain(w, 2, 4, h_block=4, z_block=0)

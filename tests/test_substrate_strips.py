@@ -1,16 +1,17 @@
-"""Strip-mined halo substrate: equivalence sweeps vs the jnp oracle, the
-halo-row sub-blocked substrate's bit-for-bit equality with the whole-strip
-kernels, the intermediate-reuse MXU regime's exactness guarantee, tiling
-validation error paths, and the substrate's traffic accounting
-(1 + 2h/strip_m vs 3 vs the seed scheme's 9) -- plus the N-D halo-plane
-generalization (DESIGN.md §9): 3D slab-substrate equivalence
-(sub-blocked vs whole-slab foil vs oracle), the 3D read-amplification
-product formula, and the 1D lift through the 2D substrate."""
+"""Block-ring halo substrate: equivalence sweeps vs the jnp oracle, the
+bit-for-bit equality of every ring of one grid with the ring pinned to
+whole neighbor strips (``h_block = strip_m``), the intermediate-reuse MXU
+regime's exactness guarantee, tiling validation error paths, and the
+substrate's traffic accounting (1 + 2h/strip_m vs the pin's 3) -- plus
+the N-D halo-plane generalization (DESIGN.md §9): 3D slab-substrate
+equivalence (auto halo planes vs the whole-slab pin ``z_block = z_slab,
+h_block = strip_m`` vs oracle), the 3D read-amplification product
+formula, and the 1D lift through the 2D substrate."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import common, legacy
+from repro.kernels import common
 from repro.kernels.common import (SubstrateGeom, choose_col_blocks,
                                   choose_hblock, choose_slab_blocks,
                                   choose_strip, choose_strip_blocks,
@@ -79,10 +80,10 @@ class TestStripEquivalence:
 
 
 class TestSubblockedEquivalence:
-    """The halo-row sub-blocked substrate assembles byte-identical extended
-    strips, so its outputs are BIT-FOR-BIT equal to the whole-strip kernels
-    in f32 -- the ISSUE's acceptance sweep: box/star x r{1,2,3} x t{1,2,4}
-    x h_block dividing strip_m."""
+    """Every ring of one grid assembles byte-identical extended strips, so
+    its outputs are BIT-FOR-BIT equal to the ring pinned to whole neighbor
+    strips (h_block = strip_m) in f32 -- box/star x r{1,2,3} x t{1,2,4} x
+    h_block dividing strip_m."""
 
     STRIP_M = 24
 
@@ -97,8 +98,8 @@ class TestSubblockedEquivalence:
     def test_direct_bitwise_vs_wholestrip(self, shape, r, t):
         w = make_weights(StencilSpec(shape, 2, r), seed=r)
         x = _x(48, 64)
-        whole = stencil_direct(x, w, t=t, tile_m=self.STRIP_M, h_block=0,
-                               interpret=True)
+        whole = stencil_direct(x, w, t=t, tile_m=self.STRIP_M,
+                               h_block=self.STRIP_M, interpret=True)
         for hb in self._hblocks(r, t):
             sub = stencil_direct(x, w, t=t, tile_m=self.STRIP_M, h_block=hb,
                                  interpret=True)
@@ -111,15 +112,15 @@ class TestSubblockedEquivalence:
         w = make_weights(StencilSpec(shape, 2, r), seed=r)
         x = _x(48, 64)
         whole = stencil_matmul(x, w, t=t, tile_m=self.STRIP_M, tile_n=32,
-                               h_block=0, interpret=True)
+                               h_block=self.STRIP_M, interpret=True)
         for hb in self._hblocks(r, t):
             sub = stencil_matmul(x, w, t=t, tile_m=self.STRIP_M, tile_n=32,
                                  h_block=hb, interpret=True)
             np.testing.assert_array_equal(np.asarray(sub), np.asarray(whole))
 
     def test_single_strip_wraps_to_itself(self):
-        """gm=1: both substrates take the periodic halo from the strip
-        itself (modulo wrap), matching the oracle."""
+        """gm=1: the ring takes the periodic halo from the strip itself
+        (modulo wrap), matching the oracle."""
         w = make_weights(StencilSpec("box", 2, 2), seed=0)
         x = _x(32, 32)
         ref = stencil_direct_ref(x, w, 2)
@@ -141,11 +142,12 @@ class TestSubblockedEquivalence:
 
 
 class TestSubstrate3D:
-    """The ISSUE's 3D acceptance sweep: sub-blocked vs whole-slab foil vs
-    the kernels/ref.py oracle, box/star x r{1,2} x t{1,2} x f32/bf16.
-    Both substrates assemble byte-identical halo-extended slabs, so their
-    outputs are BIT-for-bit equal in every dtype; the VPU box path even
-    reproduces the roll oracle bitwise in f32 (identical tap order)."""
+    """The 3D sweep: auto halo-plane blocks vs the whole-slab pin
+    (z_block = z_slab, h_block = strip_m) vs the kernels/ref.py oracle,
+    box/star x r{1,2} x t{1,2} x f32/bf16.  Both rings assemble
+    byte-identical halo-extended slabs, so their outputs are BIT-for-bit
+    equal in every dtype; the VPU box path even reproduces the roll
+    oracle bitwise in f32 (identical tap order)."""
 
     Z, H, W = 12, 24, 32
     SLAB, STRIP = 6, 12
@@ -166,8 +168,9 @@ class TestSubstrate3D:
         w = make_weights(StencilSpec(shape, 3, r), seed=r)
         x = _x3(self.Z, self.H, self.W, dtype)
         zb, hb = self._blocks(r * t)
-        whole = stencil_direct(x, w, t=t, tile_m=self.STRIP, h_block=0,
-                               z_slab=self.SLAB, interpret=True)
+        whole = stencil_direct(x, w, t=t, tile_m=self.STRIP,
+                               h_block=self.STRIP, z_slab=self.SLAB,
+                               z_block=self.SLAB, interpret=True)
         sub = stencil_direct(x, w, t=t, tile_m=self.STRIP, h_block=hb,
                              z_slab=self.SLAB, z_block=zb, interpret=True)
         np.testing.assert_array_equal(np.asarray(sub), np.asarray(whole))
@@ -191,7 +194,8 @@ class TestSubstrate3D:
         x = _x3(self.Z, self.H, self.W, dtype)
         zb, hb = self._blocks(r * t)
         whole = stencil_matmul(x, w, t=t, tile_m=self.STRIP, tile_n=16,
-                               h_block=0, z_slab=self.SLAB, interpret=True)
+                               h_block=self.STRIP, z_slab=self.SLAB,
+                               z_block=self.SLAB, interpret=True)
         sub = stencil_matmul(x, w, t=t, tile_m=self.STRIP, tile_n=16,
                              h_block=hb, z_slab=self.SLAB, z_block=zb,
                              interpret=True)
@@ -228,7 +232,7 @@ class TestSubstrate3D:
 
     def test_read_bytes_product_formula(self):
         """Analytic 3D reads == (1 + 2h/strip)(1 + 2zb/slab) * Z*H*W*D for
-        every (z_block | z_slab, h_block | strip_m); the whole-slab foil
+        every (z_block | z_slab, h_block | strip_m); the whole-slab pin
         reads exactly 9x."""
         Z, H, W, D = 16, 32, 64, 4
         grid_bytes = Z * H * W * D
@@ -242,21 +246,25 @@ class TestSubstrate3D:
                             * grid_bytes)
                     assert got == pytest.approx(want)
                     assert g.read_amp == pytest.approx(got / grid_bytes)
-            foil = SubstrateGeom(dim=3, strip_m=sm, h_block=0,
-                                 z_slab=zs, z_block=0)
-            assert hbm_read_bytes_per_step_3d((Z, H, W), foil, D) == \
+            whole = SubstrateGeom(dim=3, strip_m=sm, h_block=sm,
+                                  z_slab=zs, z_block=zs)
+            assert hbm_read_bytes_per_step_3d((Z, H, W), whole, D) == \
                 9 * grid_bytes
-            assert foil.read_amp == 9.0
+            assert whole.read_amp == 9.0
 
     def test_subblocked_amp_strictly_below_wholeslab(self):
-        """Auto joint sizing always beats the 9x foil (the acceptance
-        bound), and by a wide margin for shallow halos."""
+        """Auto joint sizing always beats the whole-slab pin's 9x (read
+        amp of z_block = z_slab, h_block = strip_m), and by a wide margin
+        for shallow halos."""
         for halo in (1, 2, 4):
             zs, zb, sm, hb, wt, wb = choose_slab_blocks(64, 256, 512, halo)
             assert (wt, wb) == (0, 0)     # full width fits at this size
             g = SubstrateGeom(dim=3, strip_m=sm, h_block=hb,
                               z_slab=zs, z_block=zb)
-            assert g.read_amp < 9.0
+            whole = SubstrateGeom(dim=3, strip_m=sm, h_block=sm,
+                                  z_slab=zs, z_block=zs)
+            assert whole.read_amp == 9.0
+            assert g.read_amp < whole.read_amp
             if halo <= 2:
                 # h_block is at least one 8-row sublane tile (the TPU
                 # tiling rule), so y costs 1.5x at the 32-row strips
@@ -288,7 +296,7 @@ class TestSubstrate3D:
         with pytest.raises(ValueError, match="z_block"):
             stencil_direct(_x3(12, 24, 32), w, t=2, tile_m=12, z_slab=6,
                            h_block=2, z_block=1, interpret=True)
-        with pytest.raises(ValueError, match="whole-slab"):
+        with pytest.raises(ValueError, match="z_block=z_slab"):
             resolve_substrate_geom((12, 24, 32), 1, 4, tile_m=12,
                                    h_block=2, z_slab=6, z_block=0)
         with pytest.raises(ValueError, match="rank"):
@@ -318,12 +326,17 @@ class Test1DLift:
 
     def test_h_block_pins_coerce_like_plans(self):
         """Kernel-level h_block pins on 1D grids coerce exactly as the
-        plan-level rule does (0 stays the foil, anything else becomes 1)
+        plan-level rule does (any positive pin becomes 1, 0 is refused)
         -- no pin a plan accepts may crash the kernel."""
         w = make_weights(StencilSpec("box", 1, 1), seed=0)
         x = jnp.asarray(RNG.normal(size=(64,)).astype(np.float32))
         base = stencil_direct(x, w, t=1, interpret=True)
-        for hb in (0, 1, 4):
+        with pytest.raises(ValueError, match="h_block=strip_m"):
+            resolve_substrate_geom(x.shape, 0, 4, h_block=0)
+        for kernel in (stencil_direct, stencil_matmul):
+            with pytest.raises(ValueError, match="h_block=strip_m"):
+                kernel(x, w, t=1, h_block=0, interpret=True)
+        for hb in (1, 4):
             np.testing.assert_array_equal(
                 np.asarray(stencil_direct(x, w, t=1, h_block=hb,
                                           interpret=True)),
@@ -343,6 +356,8 @@ class TestChooseHBlock:
     def test_degenerates_to_whole_strip_at_full_halo(self):
         assert choose_hblock(32, 32) == 32
         assert substrate_read_amp(32, 32) == 3.0
+        g = resolve_substrate_geom((64, 128), 32, 4, tile_m=32)
+        assert g.h_block == 32 and g.read_amp == 3.0
 
     def test_amp_small_when_halo_allows(self):
         strip_m, hb = choose_strip_blocks(1024, 512, 2)
@@ -411,6 +426,32 @@ class TestValidateTiling:
             stencil_direct(_x(64, 64), w, tile_m=32, h_block=5,
                            interpret=True)
 
+    def test_zero_block_pins_name_the_ring_pin(self):
+        """h_block=0 and z_block=0 name no block ring: every entry point
+        (kernel, plan, grid-free pricing) refuses them with the pin that
+        reads the same whole strips or slabs."""
+        from repro.kernels import explain, stencil_plan
+        w2 = make_weights(StencilSpec("box", 2, 1), seed=0)
+        w3 = make_weights(StencilSpec("star", 3, 1), seed=0)
+        strip = "pin h_block=strip_m"
+        slab = "pin z_block=z_slab"
+        with pytest.raises(ValueError, match=strip):
+            stencil_direct(_x(64, 64), w2, tile_m=32, h_block=0,
+                           interpret=True)
+        with pytest.raises(ValueError, match=strip):
+            stencil_plan(w2, (64, 64), np.float32, 1, h_block=0,
+                         interpret=True, use_cache=False)
+        with pytest.raises(ValueError, match=strip):
+            explain(w2, 1, h_block=0)
+        with pytest.raises(ValueError, match=slab):
+            stencil_matmul(_x3(12, 24, 32), w3, tile_m=12, z_slab=6,
+                           z_block=0, interpret=True)
+        with pytest.raises(ValueError, match=slab):
+            stencil_plan(w3, (12, 24, 32), np.float32, 1, z_block=0,
+                         interpret=True, use_cache=False)
+        with pytest.raises(ValueError, match=slab):
+            explain(w3, 1, z_block=0)
+
     def test_hblock_smaller_than_halo(self):
         w = make_weights(StencilSpec("box", 2, 2), seed=0)
         with pytest.raises(ValueError, match="h_block"):
@@ -457,20 +498,30 @@ class TestChooseStrip:
 
 
 class TestTrafficAccounting:
-    """The acceptance criteria: analytic reads fall 9x (seed) -> 3x
-    (whole-strip) -> 1 + 2h/strip_m (sub-blocked)."""
+    """Analytic reads of the ring: 9x at the whole-slab pin, 3x at the
+    whole-strip pin, 1 + 2h/strip_m at the auto halo blocks."""
 
     def test_loads_per_output_tile(self):
-        assert len(common.strip_in_specs(32, 128, 4)) == 3 <= 4
-        assert len(legacy.neighbor_in_specs(32, 32, 4, 4)) == 9
+        """Block loads per output strip (the ring length): 3 at the
+        whole-strip pin, strip_m/h_block + 2 below it, 3x3 at the 3D
+        whole-slab pin."""
+        whole = common.strip_launch_geometry((128, 128), 32, 32, 4)
+        sub = common.strip_launch_geometry((128, 128), 32, 8, 4)
+        assert whole.ring == 3 and sub.ring == 32 // 8 + 2
+        slab = common.slab_launch_geometry(
+            (16, 64, 128), SubstrateGeom(dim=3, strip_m=32, h_block=32,
+                                         z_slab=8, z_block=8), 4)
+        assert slab.ring == 9
 
     def test_read_amplification_3x_vs_9x(self):
         shape = (256, 256)
-        new = common.hbm_read_bytes_per_step(shape, 32, 4)
-        old = legacy.hbm_read_bytes_per_step(shape, 32, 32, 4)
+        strip = common.hbm_read_bytes_per_step(shape, 32, 4, h_block=32)
+        slab = hbm_read_bytes_per_step_3d(
+            (16,) + shape, SubstrateGeom(dim=3, strip_m=32, h_block=32,
+                                         z_slab=8, z_block=8), 4)
         grid_bytes = 256 * 256 * 4
-        assert new == 3 * grid_bytes
-        assert old == 9 * grid_bytes
+        assert strip == 3 * grid_bytes
+        assert slab == 9 * 16 * grid_bytes
 
     def test_subblocked_read_bytes_formula(self):
         """Analytic read_bytes == (1 + 2h/strip_m) * H*W*D exactly, for
@@ -492,30 +543,24 @@ class TestTrafficAccounting:
         for halo in (1, 2, 4):
             strip_m, hb = choose_strip_blocks(1024, 1024, halo)
             assert substrate_read_amp(strip_m, hb) <= 1.3
-        assert substrate_read_amp(strip_m, 0) == 3.0      # whole-strip foil
+        assert substrate_read_amp(strip_m, strip_m) == 3.0   # whole-strip pin
         with pytest.raises(ValueError, match="auto"):
-            substrate_read_amp(strip_m, None)             # None != whole-strip
+            substrate_read_amp(strip_m, None)             # None is not a ring
+        with pytest.raises(ValueError, match="no block ring"):
+            substrate_read_amp(strip_m, 0)
 
     def test_bands_charged_identically(self):
-        """The banded operand term is substrate-independent (one fetch per
-        output strip)."""
+        """The banded operand term is independent of the ring's blocks
+        (one fetch per output strip)."""
         bands = (3, 40, 32)
-        base = common.hbm_read_bytes_per_step((256, 256), 32, 4)
+        base = common.hbm_read_bytes_per_step((256, 256), 32, 4, h_block=32)
         with_b = common.hbm_read_bytes_per_step((256, 256), 32, 4,
-                                                bands_shape=bands)
+                                                bands_shape=bands,
+                                                h_block=32)
         sub = common.hbm_read_bytes_per_step((256, 256), 32, 4, h_block=8)
         sub_b = common.hbm_read_bytes_per_step((256, 256), 32, 4,
                                                bands_shape=bands, h_block=8)
         assert with_b - base == sub_b - sub == 8 * 3 * 40 * 32 * 4
-
-    def test_legacy_kernels_still_correct(self):
-        """legacy.py backs the old-vs-new benchmark; keep it honest."""
-        w = make_weights(StencilSpec("box", 2, 1), seed=0)
-        x = _x(64, 64)
-        ref = stencil_direct_ref(x, w, 2)
-        yd = legacy.stencil_direct_9pt(x, w, t=2, tile_m=32, tile_n=32,
-                                       interpret=True)
-        np.testing.assert_allclose(np.asarray(yd), np.asarray(ref), atol=1e-4)
 
 
 class TestChooseTile:
@@ -777,7 +822,7 @@ class TestColumnTiled:
         with pytest.raises(ValueError, match="x-halo"):
             stencil_direct(x, w2, t=2, tile_m=24, h_block=12, w_tile=32,
                            w_block=2, interpret=True)
-        with pytest.raises(ValueError, match="full-width|foil"):
+        with pytest.raises(ValueError, match="h_block=strip_m"):
             stencil_direct(x, w, tile_m=24, h_block=0, w_tile=32,
                            interpret=True)
         with pytest.raises(ValueError, match="w_tile"):
@@ -796,8 +841,8 @@ class TestColumnTiled:
         with pytest.raises(ValueError, match="exceeds grid width"):
             validate_tiling((48, 64), 24, 64, 1, h_block=12, w_tile=128,
                             w_block=8)
-        # whole-slab foil + column tiling rejected in 3D too
-        with pytest.raises(ValueError, match="full-width|foil"):
+        # a zero block pin is refused under column tiling in 3D too
+        with pytest.raises(ValueError, match="h_block=strip_m"):
             resolve_substrate_geom((12, 24, 32), 1, 4, tile_m=12,
                                    z_slab=6, h_block=0, w_tile=16)
 

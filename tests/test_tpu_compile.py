@@ -29,7 +29,9 @@ from repro.stencil import StencilSpec, make_weights
 STRIP_BACKENDS = ("direct", "fused_direct", "matmul", "fused_matmul",
                   "fused_matmul_reuse", "sparse_matmul",
                   "fused_sparse_matmul")
-FOILS = tuple(f"{b}_wholestrip" for b in STRIP_BACKENDS[:5])
+#: The ring pinned to whole neighbor strips on the 10240^2 grid: three
+#: full 32-row strips per output strip (read amp 3).
+WHOLE_STRIP = dict(tile_m=32, h_block=32)
 
 GRID_2D = (10240, 10240)
 GRID_3D = (512, 512, 512)
@@ -85,10 +87,11 @@ def test_2d_strip_backends_compile(one_chip, backend, dtype):
     assert plan.backend == backend and plan.interpret is False
 
 
-@pytest.mark.parametrize("backend", FOILS)
+@pytest.mark.parametrize("backend", STRIP_BACKENDS[:5])
 def test_2d_wholestrip_foils_compile(one_chip, backend):
+    """The core regimes on the ring pinned to h_block = strip_m."""
     _compile(one_chip, StencilSpec("box", 2, 1), GRID_2D, jnp.float32,
-             _depth(backend), backend=backend)
+             _depth(backend), backend=backend, **WHOLE_STRIP)
 
 
 @pytest.mark.parametrize("backend", STRIP_BACKENDS)
@@ -190,13 +193,18 @@ def test_compiled_kernels_trace_no_unlowerable_primitive(backend):
     assert not prims & {"rev", "optimization_barrier"}, prims
 
 
-@pytest.mark.parametrize("backend", STRIP_BACKENDS + FOILS)
-def test_kernel_is_named_after_its_backend(one_chip, backend):
+@pytest.mark.parametrize("backend, pins", [
+    *[pytest.param(b, {}, id=b) for b in STRIP_BACKENDS],
+    *[pytest.param(b, WHOLE_STRIP, id=f"{b}_wholestrip")
+      for b in STRIP_BACKENDS[:5]]])
+def test_kernel_is_named_after_its_backend(one_chip, backend, pins):
     """Every custom call of the compiled plan carries the backend's name
     (``%fused_direct.1 = ... custom-call``), so the profiler trace and
-    the benchmark's breakdown name each kernel by its backend."""
+    the benchmark's breakdown name each kernel by its backend -- on the
+    auto ring and on the whole-strip pin alike."""
     _, text = _compile(one_chip, StencilSpec("box", 2, 1), (512, 1024),
-                       jnp.float32, _depth(backend), backend=backend)
+                       jnp.float32, _depth(backend), backend=backend,
+                       **pins)
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls
@@ -223,7 +231,8 @@ def _scoped_plan(one_chip, backend, spec, grid, t=2):
 
 @pytest.mark.parametrize("backend, spec, grid", [
     ("fused_direct", StencilSpec("box", 2, 1), (512, 1024)),
-    ("fused_direct_wholestrip", StencilSpec("box", 2, 1), (512, 1024)),
+    # 1D: the scratch-free "flat" launch
+    ("fused_direct", StencilSpec("box", 1, 1), (4096,)),
     ("fused_matmul_reuse", StencilSpec("star", 3, 1), (32, 64, 256)),
 ])
 def test_kernel_scopes_compile_in_only_when_on(one_chip, backend, spec,
