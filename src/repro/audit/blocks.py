@@ -26,6 +26,8 @@ Checks emitted per launch:
     remainder path's edge tile exactly as the model does).
   * ``blocks/bands-term``    -- for MXU launches, the model's banded
     operand term charges exactly ``cells * prod(bands_shape) * D``.
+  * ``blocks/grid-steps-model`` -- ``SubstrateGeom.grid_steps``, the
+    step count the sizing priced, == the launch grid's steps.
 """
 from __future__ import annotations
 
@@ -87,6 +89,10 @@ def audit_blocks(lg, launch, dtype_bytes: int) -> List[AuditCheck]:
     """
     checks: List[AuditCheck] = []
     n_steps = math.prod(lg.grid)
+    checks.append(AuditCheck(
+        "blocks/grid-steps-model", launch.geom.grid_steps == n_steps,
+        expected=n_steps, actual=launch.geom.grid_steps,
+        detail="SubstrateGeom.grid_steps vs the launch grid's steps"))
     if n_steps > MAX_GRID_STEPS:
         checks.append(AuditCheck(
             "blocks/grid-bytes-model", True, skipped=True,

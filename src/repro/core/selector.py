@@ -93,6 +93,7 @@ def select_backend(
     w_tile: Optional[int] = None,
     w_block: Optional[int] = None,
     boundary=None,
+    grid_shape=None,
 ) -> Decision:
     """Pick the predicted-fastest backend for ``t`` fused steps of ``spec``.
 
@@ -121,7 +122,8 @@ def select_backend(
     factor and the reuse beta the carried-x-halo recompute.  The resolved
     geometry and its read factor (including the resolved ``w_tile``) are
     appended to every reason string, so ``ops.explain`` surfaces what the
-    substrate costs.
+    substrate costs; given the ``grid_shape`` the geometry was resolved
+    for, the reason also counts its launch's ``grid_steps``.
 
     ``boundary`` (DESIGN.md §15) does not move the crossover -- the
     boundary fills are FLOP-free select/concat lanes and the fetch count
@@ -142,7 +144,7 @@ def select_backend(
     # shares resolve_substrate_geom's pin rules (including the zero-block
     # refusal), so the priced substrate is always buildable.
     geom = pricing_geom(spec.dim, t * spec.radius, strip_m, h_block,
-                        z_slab, z_block, w_tile, w_block)
+                        z_slab, z_block, w_tile, w_block, grid_shape)
     read_amp = geom.read_amp
     w = pm.StencilWorkload(spec, t, dtype_bytes, read_amp=read_amp)
     s_mono = sparsity if sparsity is not None else \

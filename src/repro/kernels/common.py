@@ -149,6 +149,15 @@ VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 #: v5e core has 128 MiB of VMEM, so half of it leaves those a 2x margin.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
+#: HBM bytes that take as long to read as one grid step of a block-ring
+#: launch costs on a TPU v5e: the 3D sizing (``choose_slab_blocks``)
+#: prices each step of a call's ring walk at this many bytes of ring
+#: reads.  Fitted on the chip over nine pinned Star-3D1R 1024^3 f32, t=2
+#: geometries: the kernel time outside the fire step (DMA waits, grid
+#: steps and ring-slot stores) read 0.254 us per step plus ring bytes at
+#: 867 GB/s (DESIGN.md §9).
+STEP_BYTES = 220_000
+
 #: Mosaic's block tiling rule: the last two dims of every BlockSpec block
 #: must be multiples of (sublane tile, LANE) or span the whole array dim.
 #: The sublane tile is 8 rows of 32-bit words, so packed dtypes need
@@ -543,6 +552,41 @@ def choose_col_blocks(
     return sm, hb_of(sm), wt, wb_of(wt)
 
 
+def _block_candidates(tile: int, halo: int, align: int) -> list:
+    """Ring-block heights for one axis of a ``tile``-wide cell: every
+    divisor of ``tile`` that covers the halo and is a multiple of the
+    axis alignment (1 for z, the sublane tile for rows, ``LANE`` for
+    columns); the whole tile when none is."""
+    return [d for d in range(align, tile + 1, align)
+            if tile % d == 0 and d >= halo] or [tile]
+
+
+def _slab_working_set(geom: "SubstrateGeom", n: int, halo: int,
+                      x_halo: int, dtype_bytes: int) -> int:
+    """VMEM bytes one 3D launch holds: the ring's scratch, the in-flight
+    block and the output slab in the grid's dtype, plus the f32
+    halo-extended compute slab (full width re-wraps x in VMEM;
+    column-tiled cells carry the ``x_halo``)."""
+    zs, zb, sm, hb = geom.z_slab, geom.z_block, geom.strip_m, geom.h_block
+    if geom.w_tile:
+        wt, wb = geom.w_tile, geom.w_block
+        ring_w, block_w, comp_w, out_w = wt + 2 * wb, wb, wt + 2 * x_halo, wt
+    else:
+        ring_w, block_w, comp_w, out_w = n, n, n + 2 * halo, n
+    held = ((zs + 2 * zb) * (sm + 2 * hb) * ring_w + zb * hb * block_w
+            + zs * sm * out_w)
+    return held * dtype_bytes + (zs + 2 * halo) * (sm + 2 * halo) * comp_w * 4
+
+
+def slab_walk_cost(grid_shape, geom: "SubstrateGeom",
+                   dtype_bytes: int) -> int:
+    """What the 3D sizing minimizes, in HBM bytes: one call's ring reads
+    (``hbm_read_bytes_per_step_3d``) plus its grid steps
+    (``substrate_grid_steps``) at ``STEP_BYTES`` each."""
+    return (hbm_read_bytes_per_step_3d(grid_shape, geom, dtype_bytes)
+            + substrate_grid_steps(grid_shape, geom) * STEP_BYTES)
+
+
 def choose_slab_blocks(
     z: int,
     h: int,
@@ -555,29 +599,35 @@ def choose_slab_blocks(
     m_pin: int = None,
     w_pin: int = None,
     x_halo: int = None,
+    zb_pin: int = None,
+    hb_pin: int = None,
+    wb_pin: int = None,
 ) -> tuple:
     """Jointly size the 3D geometry
-    (z_slab, z_block, strip_m, h_block, w_tile, w_block).
+    (z_slab, z_block, strip_m, h_block, w_tile, w_block) by what one
+    call's ring walk costs (``slab_walk_cost``):
 
-    ``z_slab`` divides Z and ``strip_m`` divides H, both >= halo;
-    ``z_block``/``h_block`` are ``choose_hblock`` of each (smallest
-    halo-covering divisor above the 1/16 floor).  Two phases:
+        cost = ring read bytes + grid steps * STEP_BYTES
 
-      * FULL WIDTH (w_tile = w_block = 0, the fast path): the ring's
-        scratch + in-flight block (floored at nine full slabs, below),
-        plus the f32 halo-extended compute slab and the output slab.
-        Taken whenever any full-width pair fits (or ``w_pin=0`` forces
-        it).
-      * COLUMN-TILED (DESIGN.md §10): when no full-width pair fits (or
-        ``w_pin`` > 0), the search adds the (w_tile, w_block) axis --
-        ``_wtile_candidates`` of W, w_block floored at the carried
-        x-halo ``x_halo`` (= t*r) -- and prices the ring's scratch +
-        compute + output cell.
+    ``z_slab`` divides Z and ``strip_m`` divides H, both >= halo (free
+    axes capped at ``preferred``); each block height is any divisor of
+    its tile that covers the halo and meets the axis alignment
+    (``_block_candidates``).  Ties go to the smaller halo-extended
+    compute slab per output point, its rows padded to whole sublane tiles
+    as the vector unit computes them (less fused-step recompute), then to
+    larger cells, then to taller strips.  Only geometries whose
+    ``_slab_working_set`` fits the VMEM budget compete.  Two phases:
 
-    Among fitting combinations (free axes capped at ``preferred``) the
-    rule minimizes the analytic read-amplification product, tie-breaking
-    toward fewer grid cells (larger cells).  ``z_pin``/``m_pin``/
-    ``w_pin`` fix axes to explicit user pins: the search sizes only the
+      * FULL WIDTH (w_tile = w_block = 0, the fast path): blocks span W.
+        Taken whenever any full-width geometry fits (or ``w_pin=0``
+        forces it).
+      * COLUMN-TILED (DESIGN.md §10): when none fits (or ``w_pin`` > 0),
+        the search adds the (w_tile, w_block) axis --
+        ``_wtile_candidates`` of W, w_block covering the carried x-halo
+        ``x_halo`` (= t*r) -- and the column ring multiplies the steps.
+
+    When nothing fits, the smallest working set wins.  The ``*_pin``
+    arguments fix axes to explicit user pins: the search sizes only the
     FREE axes conditioned on the pins.  Pins are exempt from the
     divisor/halo/``preferred`` filters (explicit values are validated
     strictly by the caller).
@@ -587,52 +637,45 @@ def choose_slab_blocks(
     xh = halo if x_halo is None else x_halo
     sub = sublane_tile(dtype_bytes)
 
-    def blocks(zs: int, sm: int) -> tuple:
-        return choose_hblock(zs, halo, 1), choose_hblock(sm, halo, sub)
+    def blocks(tile: int, pin: int, blk_halo: int, align: int) -> list:
+        return [pin] if pin else _block_candidates(tile, blk_halo, align)
 
-    def wb_of(wt: int) -> int:
-        return choose_hblock(wt, max(xh, 1), LANE)
+    def ws(g: SubstrateGeom) -> int:
+        return _slab_working_set(g, n, halo, xh, dtype_bytes)
 
-    def working_set(zs: int, sm: int) -> int:
-        zb, hb = blocks(zs, sm)
-        scratch = (zs + 2 * zb) * (sm + 2 * hb) * n + zb * hb * n
-        # 9*zs*sm*n: the deleted whole-slab foil's pricing (ROADMAP Speed 2).
-        inputs = max(9 * zs * sm * n, scratch)
-        compute = (zs + 2 * halo) * (sm + 2 * halo) * (n + 2 * halo)
-        return (inputs + compute + zs * sm * n) * dtype_bytes
+    def rank(g: SubstrateGeom) -> tuple:
+        rows = -(-(g.strip_m + 2 * halo) // sub) * sub
+        extended = (g.z_slab + 2 * halo) * rows / (g.z_slab * g.strip_m)
+        if g.w_tile:
+            extended *= (g.w_tile + 2 * xh) / g.w_tile
+        return (slab_walk_cost((z, h, n), g, dtype_bytes), extended,
+                -g.z_slab * g.strip_m * (g.w_tile or n), -g.strip_m)
 
-    def working_set_col(zs: int, sm: int, wt: int) -> int:
-        zb, hb = blocks(zs, sm)
-        wb = wb_of(wt)
-        scratch = ((zs + 2 * zb) * (sm + 2 * hb) * (wt + 2 * wb)
-                   + zb * hb * wb)
-        compute = (zs + 2 * halo) * (sm + 2 * halo) * (wt + 2 * xh)
-        return (scratch + compute + zs * sm * wt) * dtype_bytes
+    def search(w_cands: list) -> tuple:
+        cands = [SubstrateGeom(dim=3, strip_m=sm, h_block=hb, z_slab=zs,
+                               z_block=zb, w_tile=wt, w_block=wb)
+                 for zs in _axis_candidates(z, halo, z_pin, preferred)
+                 for zb in blocks(zs, zb_pin, halo, 1)
+                 for sm in _axis_candidates(h, halo, m_pin, preferred, sub)
+                 for hb in blocks(sm, hb_pin, halo, sub)
+                 for wt in w_cands
+                 for wb in (blocks(wt, wb_pin, max(xh, 1), LANE) if wt
+                            else (0,))]
+        fitting = [g for g in cands if ws(g) <= vmem_budget]
+        return min(fitting, key=rank) if fitting else None, cands
 
-    def amp(zs: int, sm: int) -> float:
-        zb, hb = blocks(zs, sm)
-        return substrate_read_amp(sm, hb) * substrate_read_amp(zs, zb)
+    def fields(g: SubstrateGeom) -> tuple:
+        return g.z_slab, g.z_block, g.strip_m, g.h_block, g.w_tile, g.w_block
 
-    pairs = [(zs, sm) for zs in _axis_candidates(z, halo, z_pin, preferred)
-             for sm in _axis_candidates(h, halo, m_pin, preferred, sub)]
     w_cands = [w_pin] if w_pin else _wtile_candidates(n, xh)
     if not w_pin:
-        fitting = [p for p in pairs if working_set(*p) <= vmem_budget]
-        if fitting or w_pin == 0 or not w_cands:
-            pool = fitting or [min(pairs, key=lambda p: working_set(*p))]
-            zs, sm = min(pool, key=lambda p: (amp(*p), -p[0] * p[1]))
-            zb, hb = blocks(zs, sm)
-            return zs, zb, sm, hb, 0, 0
-
-    triples = [(zs, sm, wt) for zs, sm in pairs for wt in w_cands]
-    fitting = [t for t in triples if working_set_col(*t) <= vmem_budget]
-    pool = fitting or [min(triples, key=lambda t: working_set_col(*t))]
-    zs, sm, wt = min(
-        pool, key=lambda t: (amp(t[0], t[1])
-                             * substrate_read_amp(t[2], wb_of(t[2])),
-                             -t[0] * t[1] * t[2]))
-    zb, hb = blocks(zs, sm)
-    return zs, zb, sm, hb, wt, wb_of(wt)
+        best, cands = search([0])
+        if best is not None:
+            return fields(best)
+        if w_pin == 0 or not w_cands:
+            return fields(min(cands, key=ws))
+    best, cands = search(w_cands)
+    return fields(best if best is not None else min(cands, key=ws))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -644,7 +687,9 @@ class SubstrateGeom:
     the halo and divide their tile.  ``w_tile=0`` is the full-width fast
     path; ``w_tile > 0`` selects the column-tiled substrate (DESIGN.md
     §10, with ``w_block`` >= the carried x-halo t*r and dividing
-    ``w_tile``).
+    ``w_tile``).  ``grid_steps`` counts the launch's grid steps on the
+    grid the geometry was resolved for (``substrate_grid_steps``); it is
+    0 where pricing had no grid.
     """
 
     dim: int
@@ -654,6 +699,7 @@ class SubstrateGeom:
     z_block: int = 0             # 3D only; 0 otherwise
     w_tile: int = 0              # 0 = full width (fast path)
     w_block: int = 0             # column halo block; 0 iff w_tile == 0
+    grid_steps: int = 0          # launch grid steps per call; 0 = no grid
 
     @property
     def read_amp(self) -> float:
@@ -673,7 +719,9 @@ class SubstrateGeom:
     def describe(self) -> str:
         """The substrate clause of decision reason strings -- formatted
         from resolved numbers only, so ``ops.explain`` and plan decisions
-        agree verbatim whenever they resolve the same geometry."""
+        agree verbatim whenever they resolve the same geometry.  A 3D
+        clause resolved on a grid also counts its ``grid_steps``, which
+        the 3D sizing prices (the 2D sizing does not)."""
         if self.dim == 3:
             geo = (f"z_slab={self.z_slab}, z_block={self.z_block}, "
                    f"strip_m={self.strip_m}, h_block={self.h_block}")
@@ -686,7 +734,26 @@ class SubstrateGeom:
                 geo += f", w_tile={self.w_tile}, w_block={self.w_block}"
             else:
                 geo += ", w_tile=full"
-        return f"substrate read_amp={self.read_amp:.3f}x ({geo})"
+        steps = (f", grid_steps={self.grid_steps}"
+                 if self.dim == 3 and self.grid_steps else "")
+        return f"substrate read_amp={self.read_amp:.3f}x{steps} ({geo})"
+
+
+def substrate_grid_steps(grid_shape, geom: SubstrateGeom) -> int:
+    """Grid steps of one launch of ``geom`` over ``grid_shape``: each
+    output cell walks its own blocks plus one neighbor block per side on
+    every ringed axis; the lifted 1D launch is one step."""
+    if geom.dim == 1 or len(grid_shape) == 1:
+        return 1
+    cells = grid_shape[-2] // geom.strip_m
+    rings = [(geom.strip_m, geom.h_block)]
+    if geom.dim == 3:
+        cells *= grid_shape[0] // geom.z_slab
+        rings.append((geom.z_slab, geom.z_block))
+    if geom.w_tile:
+        cells *= -(-grid_shape[-1] // geom.w_tile)
+        rings.append((geom.w_tile, geom.w_block))
+    return cells * math.prod(tile // block + 2 for tile, block in rings)
 
 
 def _check_block_pins(h_block: int, z_block: int) -> None:
@@ -729,29 +796,39 @@ def _resolve_w_block(w_tile: int, w_block: int, x_halo: int) -> tuple:
 def pricing_geom(dim: int, halo: int, strip_m: int = 128,
                  h_block: int = None, z_slab: int = None,
                  z_block: int = None, w_tile: int = None,
-                 w_block: int = None) -> SubstrateGeom:
-    """Grid-free geometry resolution for pricing paths (the selector has
-    no grid to size against): dim 1 is always the lifted substrate; dim 2
-    takes ``strip_m`` as given with ``choose_hblock`` filling ``h_block``;
-    dim 3 defaults ``z_slab`` to ``strip_m`` and resolves ``z_block``
-    under the same shared rule as ``resolve_substrate_geom``.  ``w_tile``
+                 w_block: int = None, grid_shape=None) -> SubstrateGeom:
+    """Geometry resolution for pricing paths, which are handed the
+    resolved numbers and need no grid to size against: dim 1 is always
+    the lifted substrate; dim 2 takes ``strip_m`` as given with
+    ``choose_hblock`` filling ``h_block``; dim 3 defaults ``z_slab`` to
+    ``strip_m`` and ``z_block`` to ``choose_hblock`` of it.  ``w_tile``
     in (None, 0) prices the full-width fast path; a positive ``w_tile``
     prices the column-tiled substrate (w_block auto-resolved at the
-    fused x-halo ``halo`` unless pinned)."""
+    fused x-halo ``halo`` unless pinned).  With ``grid_shape`` the
+    geometry also counts its launch's ``grid_steps`` on that grid."""
     _check_block_pins(h_block, z_block)
-    if dim == 1:
-        return SubstrateGeom(dim=1, strip_m=1, h_block=1)
-    hb = choose_hblock(strip_m, halo) if h_block is None else h_block
-    wt, wb = _resolve_w_block(w_tile, w_block, halo)
-    if dim == 2:
-        return SubstrateGeom(dim=2, strip_m=strip_m, h_block=hb,
-                             w_tile=wt, w_block=wb)
-    if dim != 3:
+    if dim not in (1, 2, 3):
         raise ValueError(f"substrate supports 1D/2D/3D grids, got dim {dim}")
-    zs = strip_m if z_slab is None else z_slab
-    zb = z_block if z_block is not None else choose_hblock(zs, halo, 1)
-    return SubstrateGeom(dim=3, strip_m=strip_m, h_block=hb,
-                         z_slab=zs, z_block=zb, w_tile=wt, w_block=wb)
+    if dim == 1:
+        geom = SubstrateGeom(dim=1, strip_m=1, h_block=1)
+    else:
+        hb = choose_hblock(strip_m, halo) if h_block is None else h_block
+        wt, wb = _resolve_w_block(w_tile, w_block, halo)
+        geom = SubstrateGeom(dim=2, strip_m=strip_m, h_block=hb,
+                             w_tile=wt, w_block=wb)
+    if dim == 3:
+        zs = strip_m if z_slab is None else z_slab
+        zb = z_block if z_block is not None else choose_hblock(zs, halo, 1)
+        geom = dataclasses.replace(geom, dim=3, z_slab=zs, z_block=zb)
+    return _with_grid_steps(geom, grid_shape)
+
+
+def _with_grid_steps(geom: SubstrateGeom, grid_shape) -> SubstrateGeom:
+    """``geom`` with its ``grid_steps`` on ``grid_shape`` (None: as is)."""
+    if grid_shape is None:
+        return geom
+    return dataclasses.replace(
+        geom, grid_steps=substrate_grid_steps(grid_shape, geom))
 
 
 def _normalize_w_pin(w_tile, w_block, wid: int):
@@ -777,11 +854,12 @@ def resolve_substrate_geom(grid_shape, halo: int, dtype_bytes: int,
       * 1D: lifted 2D geometry (strip_m=1, zero vertical halo, read amp 1;
         never column-tiled);
       * 2D: exactly ``resolve_strip_blocks`` (z fields stay inert);
-      * 3D: joint ``choose_slab_blocks`` when unpinned; explicit ``tile_m``
-        / ``z_slab`` are clamped to the grid and get ``choose_hblock``
-        blocks unless those are pinned too.
+      * 3D: ``choose_slab_blocks``, which prices each call's ring walk;
+        explicit ``tile_m`` / ``z_slab`` are clamped to the grid, and every
+        pin conditions the search of the free axes.
 
     ``h_block=0`` and ``z_block=0`` are refused (``_check_block_pins``).
+    The result carries its ``grid_steps`` on ``grid_shape``.
 
     Width (DESIGN.md §10): ``w_tile=None`` auto-resolves -- full width
     whenever the full-width working set fits the VMEM budget, the
@@ -792,16 +870,19 @@ def resolve_substrate_geom(grid_shape, halo: int, dtype_bytes: int,
     ``halo`` -- exact for the square kernels this repo builds.
     """
     _check_block_pins(h_block, z_block)
+    grid_shape = tuple(grid_shape)
     dim = len(grid_shape)
     if dim == 1:
-        return SubstrateGeom(dim=1, strip_m=1, h_block=1)
+        return _with_grid_steps(SubstrateGeom(dim=1, strip_m=1, h_block=1),
+                                grid_shape)
     xh = halo if x_halo is None else x_halo
     if dim == 2:
         strip_m, hb, wt, wb = resolve_strip_blocks(
             grid_shape, halo, dtype_bytes, tile_m, h_block,
             w_tile, w_block, xh)
-        return SubstrateGeom(dim=2, strip_m=strip_m, h_block=hb,
-                             w_tile=wt, w_block=wb)
+        return _with_grid_steps(
+            SubstrateGeom(dim=2, strip_m=strip_m, h_block=hb, w_tile=wt,
+                          w_block=wb), grid_shape)
     if dim != 3:
         raise ValueError(f"substrate supports 1D/2D/3D grids, got rank {dim}")
     z, h, wid = grid_shape
@@ -811,18 +892,18 @@ def resolve_substrate_geom(grid_shape, halo: int, dtype_bytes: int,
         # rejected on every path (see resolve_strip_blocks)
     # One pin-aware joint search: a pinned axis is fixed (clamped to the
     # grid) and only the free axes are sized -- conditioned on the pins, so
-    # the VMEM fit and amp-minimization always describe the geometry that
+    # the VMEM fit and the priced walk always describe the geometry that
     # actually runs.
-    zs, auto_zb, sm, auto_hb, wt, auto_wb = choose_slab_blocks(
+    zs, zb, sm, hb, wt, auto_wb = choose_slab_blocks(
         z, h, wid, halo, dtype_bytes,
         z_pin=min(z_slab, z) if z_slab is not None else None,
         m_pin=min(tile_m, h) if tile_m is not None else None,
-        w_pin=w_tile, x_halo=xh)
-    hb = h_block if h_block is not None else auto_hb
-    zb = z_block if z_block is not None else auto_zb
+        w_pin=w_tile, x_halo=xh, zb_pin=z_block, hb_pin=h_block,
+        wb_pin=w_block or None)
     wt, wb = _resolve_w_block(wt, w_block if w_block else auto_wb, xh)
-    return SubstrateGeom(dim=3, strip_m=sm, h_block=hb, z_slab=zs,
-                         z_block=zb, w_tile=wt, w_block=wb)
+    return _with_grid_steps(
+        SubstrateGeom(dim=3, strip_m=sm, h_block=hb, z_slab=zs, z_block=zb,
+                      w_tile=wt, w_block=wb), grid_shape)
 
 
 def _check_wrap_radius(w: int, r: int, mode: str = "periodic") -> None:

@@ -121,7 +121,8 @@ def explain(
     Delegates to ``repro.kernels.plan.decide`` -- the same single decision
     path plan building and the ``auto`` backend consult.  The reason
     string includes the substrate's read-amplification factor and the
-    resolved (z_slab, strip_m, h_block, w_tile) geometry for every rank.
+    resolved (z_slab, strip_m, h_block, w_tile) geometry for every rank,
+    and with ``grid_shape`` the launch's grid steps per call.
     Plans price the geometry they resolve FOR THEIR GRID, so pass
     ``grid_shape`` -- plus the same ``tile_m``/``h_block``/``z_slab``/
     ``z_block``/``w_tile``/``w_block`` pins you would hand
@@ -134,8 +135,9 @@ def explain(
     spec = spec_from_weights(weights)
     if grid_shape is not None:
         from .common import resolve_substrate_geom
+        grid_shape = tuple(int(n) for n in grid_shape)
         geom = resolve_substrate_geom(
-            tuple(int(n) for n in grid_shape), t * spec.radius, dtype_bytes,
+            grid_shape, t * spec.radius, dtype_bytes,
             tile_m, h_block, z_slab, z_block, w_tile, w_block)
         strip_m, h_block = geom.strip_m, geom.h_block
         z_slab = geom.z_slab if geom.dim == 3 else None
@@ -150,4 +152,4 @@ def explain(
                   z_slab=z_slab, z_block=z_block,
                   w_tile=w_tile, w_block=w_block,
                   use_sparse_unit=use_sparse_unit, boundary=boundary,
-                  weights=weights)
+                  weights=weights, grid_shape=grid_shape)

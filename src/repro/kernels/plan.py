@@ -79,6 +79,7 @@ def decide(
     use_sparse_unit: bool = False,
     boundary: BoundaryLike = None,
     weights=None,
+    grid_shape=None,
 ) -> Decision:
     """THE decision path: plan building, ``stencil_apply(backend="auto")``
     and ``ops.explain`` all consult this one function, so they can never
@@ -89,6 +90,8 @@ def decide(
     the sparse-compacted backends as priced candidates (DESIGN.md §14);
     ``boundary`` (DESIGN.md §15) is recorded in the decision's reason --
     the in-kernel fills are FLOP-free, so it never moves the pricing.
+    ``grid_shape`` (the grid the geometry was resolved for) puts the
+    launch's ``grid_steps`` into the reason's substrate clause.
     A VPU tap-sum pick also records the column-shift form its kernel
     traces (``stencil_direct.column_shift_form`` of ``weights``, else of
     the spec's own Jacobi weights): ``| column shifts=roll`` or
@@ -99,7 +102,7 @@ def decide(
                               z_block=z_block, w_tile=w_tile,
                               w_block=w_block,
                               use_sparse_unit=use_sparse_unit,
-                              boundary=boundary)
+                              boundary=boundary, grid_shape=grid_shape)
     if decision.backend not in ("direct", "fused_direct"):
         return decision
     from .stencil_direct import column_shift_form
@@ -673,7 +676,7 @@ def stencil_plan(
             w_tile=geom_px.w_tile if geom_px.dim >= 2 else None,
             w_block=geom_px.w_block if geom_px.dim >= 2 else None,
             use_sparse_unit=use_sparse_unit,
-            boundary=modes, weights=weights,
+            boundary=modes, weights=weights, grid_shape=grid_shape,
         )
         exec_backend = backend if backend is not None else decision.backend
 

@@ -259,7 +259,7 @@ class TestMatrixPeakByDtype:
                       h_block=g.h_block,
                       z_slab=g.z_slab if three else None,
                       z_block=g.z_block if three else None,
-                      w_tile=g.w_tile, w_block=g.w_block)
+                      w_tile=g.w_tile, w_block=g.w_block, grid_shape=grid)
 
     def test_matrix_peak_per_dtype(self):
         assert self.V5E.f32_matrix_passes == 6
@@ -271,14 +271,17 @@ class TestMatrixPeakByDtype:
     def test_star3d_f32_leaves_the_mxu_by_a_margin(self):
         """Star-3D1R f32 1024^3 t=2 at its resolved geometry: every unit
         was memory-bound at the bf16 peak and the MXU won a rounding tie;
-        at six passes the VPU wins by more than 2x."""
+        at six passes the VPU wins by more than 1.5x (2.1x at the read
+        amplification 3 of the ring before grid steps were priced; the
+        priced ring reads 4x, and the memory-bound VPU's price falls by
+        4/5)."""
         d = self._decide(StencilSpec("star", 3, 1), (1024,) * 3, 2, 4)
         assert d.backend == "fused_direct"
-        assert d.candidates["fused_matmul_reuse"] * 2 < \
+        assert d.candidates["fused_matmul_reuse"] * 1.5 < \
             d.candidates["fused_direct"]
         assert d.scenario is pm.Scenario.MB_CB
         assert "strictly worse (Eq. 16)" in d.reason
-        assert "read_amp=3.00" in d.reason
+        assert "read_amp=4.000x, grid_steps=65536 (" in d.reason
 
     @pytest.mark.parametrize("grid", [(10240, 10240), (10248, 10248),
                                       (256, 256)])

@@ -253,23 +253,27 @@ class TestSubstrate3D:
             assert whole.read_amp == 9.0
 
     def test_subblocked_amp_strictly_below_wholeslab(self):
-        """Auto joint sizing always beats the whole-slab pin's 9x (read
-        amp of z_block = z_slab, h_block = strip_m), and by a wide margin
-        for shallow halos."""
+        """Auto joint sizing reads less than the whole-slab pin's 9x
+        (z_block = z_slab, h_block = strip_m), and its priced walk (ring
+        reads plus grid steps, DESIGN.md §9) costs less than that pin's
+        and no more than the thinnest-block ring of the same cells."""
+        grid = (64, 256, 512)
         for halo in (1, 2, 4):
-            zs, zb, sm, hb, wt, wb = choose_slab_blocks(64, 256, 512, halo)
+            zs, zb, sm, hb, wt, wb = choose_slab_blocks(*grid, halo)
             assert (wt, wb) == (0, 0)     # full width fits at this size
             g = SubstrateGeom(dim=3, strip_m=sm, h_block=hb,
                               z_slab=zs, z_block=zb)
             whole = SubstrateGeom(dim=3, strip_m=sm, h_block=sm,
                                   z_slab=zs, z_block=zs)
+            thin = SubstrateGeom(dim=3, strip_m=sm,
+                                 h_block=choose_hblock(sm, halo),
+                                 z_slab=zs,
+                                 z_block=choose_hblock(zs, halo, 1))
             assert whole.read_amp == 9.0
             assert g.read_amp < whole.read_amp
-            if halo <= 2:
-                # h_block is at least one 8-row sublane tile (the TPU
-                # tiling rule), so y costs 1.5x at the 32-row strips
-                # that fit the budget here
-                assert g.read_amp <= 2.25
+            cost = common.slab_walk_cost(grid, g, 4)
+            assert cost < common.slab_walk_cost(grid, whole, 4)
+            assert cost <= common.slab_walk_cost(grid, thin, 4)
 
     def test_band_sparsity_measures_every_rank(self):
         """The measured-S sanity helper covers the 1D/3D operands this PR

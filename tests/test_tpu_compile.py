@@ -266,11 +266,12 @@ def test_kernel_scopes_compile_in_only_when_on(one_chip, backend, spec,
 def test_star3d_f32_auto_plan_compiles_on_the_vpu(one_chip, grid):
     """Star-3D1R f32 at t=2 (the 3D step cell's shape): an f32 contraction
     takes six bf16 MXU passes, so the auto plan runs the VPU's
-    ``fused_direct`` -- by a margin, not a rounding tie -- and compiles."""
+    ``fused_direct`` -- by a margin, not a rounding tie -- and compiles
+    within the scoped VMEM limit at the priced ring's geometry."""
     plan, text = _compile(one_chip, StencilSpec("star", 3, 1), grid,
                           jnp.float32, 2)
     assert plan.backend == "fused_direct"
-    assert plan.decision.candidates["fused_matmul_reuse"] * 2 < \
+    assert plan.decision.candidates["fused_matmul_reuse"] * 1.5 < \
         plan.decision.candidates["fused_direct"]
     assert "fused_direct" in text
 
